@@ -27,7 +27,8 @@ def loss_fn(params, batch):
     pred = h @ params["w2"]
     return jnp.mean((pred - batch["y"]) ** 2), {}
 
-mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
 R, per, d, h = 2, 32, 256, 512
 key = jax.random.PRNGKey(0)
 params0 = {"w1": jax.random.normal(key, (d, h)) * 0.05,
@@ -173,7 +174,8 @@ for l in range(n_blocks):
 n_leaves = len(jax.tree.leaves(tree))
 n_params = sum(x.size for x in jax.tree.leaves(tree))
 
-mesh = jax.make_mesh((2,), ("pod",))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((2,), ("pod",))
 mesh_shape = {"pod": 2}
 sh = NamedSharding(mesh, P("pod"))
 params = jax.tree.map(lambda x: jax.device_put(x, sh),

@@ -645,7 +645,7 @@ class MacroCycleExecutor:
                     chunks.append(ms)
             metrics = jax.tree.map(
                 lambda *xs: jnp.concatenate(xs, axis=0), *chunks)
-            return carry, metrics
+            return self._constrain(carry), metrics
 
         # overlap forbids donation: the pending slot aliases the params
         # object in the carry (the snapshot is by-reference), and the
@@ -654,12 +654,28 @@ class MacroCycleExecutor:
         # needs it
         donate = ((0,) if self.donate
                   and not getattr(self.strategy, "overlap", False) else ())
-        return jax.jit(program, donate_argnums=donate)
+        # keep_unused: a cycle that starts with a send never reads the
+        # donated inflight buffer; kept as an argument it is aliased to
+        # the new inflight instead of staying allocated beside it
+        return jax.jit(program, donate_argnums=donate, keep_unused=True)
+
+    def _constrain(self, carry):
+        """A program's output carry, held on the placement's shardings
+        (unchanged without a placement)."""
+        if self.placement is None:
+            return carry
+        return self.placement.constrain_carry(carry)
 
     def _per_step_fn(self, mode: str, stale: int) -> Callable:
         key = (mode, stale)
         if key not in self._per_step:
-            self._per_step[key] = jax.jit(self.strategy.step_fn(mode, stale))
+            fn = self.strategy.step_fn(mode, stale)
+
+            def step(carry, batch, lr):
+                carry, metrics = fn(carry, batch, lr)
+                return self._constrain(carry), metrics
+
+            self._per_step[key] = jax.jit(step)
         return self._per_step[key]
 
     # -- execution ---------------------------------------------------------

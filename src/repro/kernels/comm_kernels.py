@@ -14,12 +14,20 @@ that arena:
                         (QSGD-style), optional stochastic rounding from
                         caller-supplied uint32 bits.
 
-All kernels view the arena as rows of `block` contiguous elements: the
-`repro.kernels.ops` wrappers flatten, pad the trailing axis to a block
-multiple, and run a (rows, blocks) grid. Blocks never span the leading
-batch (replica) axis, so int8 scales are always per-replica. On this CPU
-container they run with interpret=True; on TPU set interpret=False and
-size `block` to the dtype tile (int8 wants multiples of 32*128).
+All kernels view the arena as (lead, rows, block): one slab of rows of
+`block` contiguous elements per leading (replica) row. The
+`repro.kernels.ops` wrappers pad the trailing axis to a whole number of
+(rows_tile, block) tiles and split it, and the kernels run a
+(lead, rows / rows_tile) grid over such tiles. A tile is what the TPU
+compiler accepts: its second-to-last dimension is a multiple of the
+sublane tile (8 rows for 32-bit data, 16 for 16-bit, 32 for int8, see
+`sublane_rows`) and its last dimension spans the whole row. (Splitting the
+trailing axis keeps the view cheap: flattening a (R, N) arena into
+(R * N / block, block) rows is a relayout whose TPU compile time grows
+with N.) Blocks never span the leading batch (replica) axis, so int8
+scales are always per-replica; each tile carries a (rows_tile, 1) column
+of scales, one per row. On the CPU backend the kernels run with
+interpret=True; on the TPU they are compiled.
 
 Random bits for stochastic rounding are passed in as a uint32 arena
 (generated with jax.random.bits) rather than drawn via pltpu.prng_* so the
@@ -37,38 +45,53 @@ from jax.experimental import pallas as pl
 from repro.kernels.ref import INT8_SCALE_FLOOR
 
 
-def _row_specs(block: int):
-    """One (block,)-row of the (rows, block) arena view per grid cell."""
-    return pl.BlockSpec((None, block), lambda i: (i, 0))
+def sublane_rows(*dtypes) -> int:
+    """Sublane rows of one native TPU tile by element width: (8, 128) for
+    32-bit, (16, 128) for 16-bit and (32, 128) for 8-bit data. A rows_tile
+    that is a multiple of the largest among a kernel's operands is legal
+    for all of them."""
+    return max({1: 32, 2: 16}.get(jnp.dtype(d).itemsize, 8) for d in dtypes)
+
+
+def _tile_spec(rows_tile: int, cols: int):
+    """One (rows_tile, cols) tile of a (lead, rows, cols) array per grid
+    step; the lead axis is squeezed out of the kernel's view."""
+    return pl.BlockSpec((None, rows_tile, cols), lambda r, i: (r, i, 0))
+
+
+def _grid(shape, rows_tile: int):
+    lead, rows, _ = shape
+    assert rows % rows_tile == 0, (shape, rows_tile)
+    return (lead, rows // rows_tile)
 
 
 # -- Eq. (1) merge -------------------------------------------------------------
 
 def _eq1_kernel(local_ref, stale_ref, out_ref, *, s2, p):
-    inv = 1.0 / (s2 + p)
     x = local_ref[...].astype(jnp.float32)
     y = stale_ref[...].astype(jnp.float32)
-    out_ref[...] = ((s2 * x + p * y) * inv).astype(out_ref.dtype)
+    out_ref[...] = ((s2 * x + p * y) / (s2 + p)).astype(out_ref.dtype)
 
 
 def eq1_merge(local, stale, *, staleness: int, global_world: int,
-              extra_staleness: int = 0, block: int = 1024,
+              extra_staleness: int = 0, rows_tile: int = 8,
               interpret: bool = False):
-    """local, stale: (rows, block) arena views, same shape/dtype.
-    Returns the Eq. (1) merge in local's dtype. `extra_staleness` adds the
-    overlap executor's one-cycle buffer age to S (0 = the pre-overlap
-    kernel, bit-exact)."""
-    rows, bk = local.shape
-    assert bk == block, (local.shape, block)
+    """local, stale: (lead, rows, block) arena views, same shape/dtype,
+    rows a multiple of `rows_tile`. Returns the Eq. (1) merge in local's
+    dtype, computed exactly as `ref.eq1_merge_ref` computes it.
+    `extra_staleness` adds the overlap executor's one-cycle buffer age to S
+    (0 = the pre-overlap kernel, bit-exact)."""
+    block = local.shape[-1]
     kernel = functools.partial(_eq1_kernel,
                                s2=2.0 * (staleness + extra_staleness),
                                p=float(global_world))
+    spec = _tile_spec(rows_tile, block)
     return pl.pallas_call(
         kernel,
-        grid=(rows,),
-        in_specs=[_row_specs(block), _row_specs(block)],
-        out_specs=_row_specs(block),
-        out_shape=jax.ShapeDtypeStruct((rows, block), local.dtype),
+        grid=_grid(local.shape, rows_tile),
+        in_specs=[spec, spec],
+        out_specs=spec,
+        out_shape=jax.ShapeDtypeStruct(local.shape, local.dtype),
         interpret=interpret,
     )(local, stale)
 
@@ -79,89 +102,85 @@ def _cast_kernel(x_ref, out_ref):
     out_ref[...] = x_ref[...].astype(out_ref.dtype)
 
 
-def bf16_pack(x, *, block: int = 1024, interpret: bool = False):
-    """(rows, block) floating arena view -> bf16 wire buffer."""
-    rows, bk = x.shape
-    assert bk == block, (x.shape, block)
+def _cast(x, out_dtype, rows_tile: int, interpret: bool):
+    spec = _tile_spec(rows_tile, x.shape[-1])
     return pl.pallas_call(
         _cast_kernel,
-        grid=(rows,),
-        in_specs=[_row_specs(block)],
-        out_specs=_row_specs(block),
-        out_shape=jax.ShapeDtypeStruct((rows, block), jnp.bfloat16),
+        grid=_grid(x.shape, rows_tile),
+        in_specs=[spec],
+        out_specs=spec,
+        out_shape=jax.ShapeDtypeStruct(x.shape, out_dtype),
         interpret=interpret,
     )(x)
 
 
-def bf16_unpack(x, *, out_dtype=jnp.float32, block: int = 1024,
+def bf16_pack(x, *, rows_tile: int = 16, interpret: bool = False):
+    """(lead, rows, block) floating arena view -> bf16 wire buffer."""
+    return _cast(x, jnp.bfloat16, rows_tile, interpret)
+
+
+def bf16_unpack(x, *, out_dtype=jnp.float32, rows_tile: int = 16,
                 interpret: bool = False):
-    """bf16 wire buffer -> (rows, block) arena view in `out_dtype`."""
-    rows, bk = x.shape
-    assert bk == block, (x.shape, block)
-    return pl.pallas_call(
-        _cast_kernel,
-        grid=(rows,),
-        in_specs=[_row_specs(block)],
-        out_specs=_row_specs(block),
-        out_shape=jax.ShapeDtypeStruct((rows, block), out_dtype),
-        interpret=interpret,
-    )(x)
+    """bf16 wire buffer -> (lead, rows, block) arena view in
+    `out_dtype`."""
+    return _cast(x, out_dtype, rows_tile, interpret)
 
 
 # -- int8 block-scaled quantization --------------------------------------------
 
 def _quantize_kernel(x_ref, bits_ref, v_ref, s_ref, *, stochastic):
     x = x_ref[...].astype(jnp.float32)
-    scale = jnp.maximum(jnp.max(jnp.abs(x)), INT8_SCALE_FLOOR) / 127.0
+    scale = jnp.maximum(jnp.max(jnp.abs(x), axis=1, keepdims=True),
+                        INT8_SCALE_FLOOR) / 127.0
     v = x / scale
     if stochastic:
-        # floor(v + u), u ~ U[0,1) from the top 24 bits: E[q] = v exactly
-        u = (bits_ref[...] >> 8).astype(jnp.float32) * (1.0 / (1 << 24))
+        # floor(v + u), u ~ U[0,1) from the top 24 bits: E[q] = v exactly.
+        # The 24-bit value goes through int32 (exact), because the TPU
+        # compiler has no uint32 -> float32 cast
+        top = (bits_ref[...] >> 8).astype(jnp.int32)
+        u = top.astype(jnp.float32) * (1.0 / (1 << 24))
         q = jnp.floor(v + u)
     else:
         q = jnp.round(v)
     v_ref[...] = jnp.clip(q, -127.0, 127.0).astype(jnp.int8)
-    s_ref[0] = scale
+    s_ref[...] = scale
 
 
-def quantize_int8(x, bits, *, block: int = 256, interpret: bool = False):
-    """x: (blocks, block) arena view; bits: uint32 of the same shape or None
-    (deterministic round-to-nearest). Returns (int8 values (blocks, block),
-    f32 scales (blocks, 1)) with scale = absmax(block)/127."""
-    rows, bk = x.shape
-    assert bk == block, (x.shape, block)
+def quantize_int8(x, bits, *, rows_tile: int = 32, interpret: bool = False):
+    """x: (lead, blocks, block) arena view; bits: uint32 of the same shape
+    or None (deterministic round-to-nearest). Returns (int8 values like x,
+    f32 scales (lead, blocks, 1)) with scale = absmax(block)/127."""
+    lead, rows, block = x.shape
     stochastic = bits is not None
     if bits is None:
-        bits = jnp.zeros((rows, block), jnp.uint32)
+        bits = jnp.zeros(x.shape, jnp.uint32)
     kernel = functools.partial(_quantize_kernel, stochastic=stochastic)
+    spec = _tile_spec(rows_tile, block)
     return pl.pallas_call(
         kernel,
-        grid=(rows,),
-        in_specs=[_row_specs(block), _row_specs(block)],
-        out_specs=[_row_specs(block), pl.BlockSpec((None, 1),
-                                                   lambda i: (i, 0))],
-        out_shape=[jax.ShapeDtypeStruct((rows, block), jnp.int8),
-                   jax.ShapeDtypeStruct((rows, 1), jnp.float32)],
+        grid=_grid(x.shape, rows_tile),
+        in_specs=[spec, spec],
+        out_specs=[spec, _tile_spec(rows_tile, 1)],
+        out_shape=[jax.ShapeDtypeStruct(x.shape, jnp.int8),
+                   jax.ShapeDtypeStruct((lead, rows, 1), jnp.float32)],
         interpret=interpret,
     )(x, bits)
 
 
 def _dequantize_kernel(v_ref, s_ref, out_ref):
-    out_ref[...] = v_ref[...].astype(jnp.float32) * s_ref[0]
+    out_ref[...] = v_ref[...].astype(jnp.float32) * s_ref[...]
 
 
-def dequantize_int8(values, scales, *, block: int = 256,
+def dequantize_int8(values, scales, *, rows_tile: int = 32,
                     interpret: bool = False):
-    """Inverse of `quantize_int8`: (blocks, block) int8 + (blocks, 1) f32
-    scales -> f32 (blocks, block)."""
-    rows, bk = values.shape
-    assert bk == block, (values.shape, block)
+    """Inverse of `quantize_int8`: (lead, blocks, block) int8 +
+    (lead, blocks, 1) f32 scales -> f32 (lead, blocks, block)."""
+    spec = _tile_spec(rows_tile, values.shape[-1])
     return pl.pallas_call(
         _dequantize_kernel,
-        grid=(rows,),
-        in_specs=[_row_specs(block), pl.BlockSpec((None, 1),
-                                                  lambda i: (i, 0))],
-        out_specs=_row_specs(block),
-        out_shape=jax.ShapeDtypeStruct((rows, block), jnp.float32),
+        grid=_grid(values.shape, rows_tile),
+        in_specs=[spec, _tile_spec(rows_tile, 1)],
+        out_specs=spec,
+        out_shape=jax.ShapeDtypeStruct(values.shape, jnp.float32),
         interpret=interpret,
     )(values, scales)

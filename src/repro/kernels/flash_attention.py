@@ -57,8 +57,11 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, *, scale, causal, window,
         p = jnp.exp(s - m)
         alpha = jnp.exp(m_prev - m)
         l = l_prev * alpha + p.sum(axis=1, keepdims=True)
+        # f32 accumulator: the TPU compiler refuses a bf16 x bf16 matmul
+        # that accumulates in bf16
         acc = acc * alpha + jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ()))).astype(jnp.float32)
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
         return m, l, acc
 
     m0 = jnp.full((block_q, 1), NEG_INF, jnp.float32)

@@ -265,10 +265,13 @@ class MeshPlacement:
         n_procs = jax.process_count()
         if n_procs > 1:
             validate_process_topology(spec, n_procs)
-        if jax.device_count() != spec.world:
+        # a given mesh (e.g. of described devices, for a compile without
+        # the chip) brings its own devices
+        n_dev = mesh.devices.size if mesh is not None else jax.device_count()
+        if n_dev != spec.world:
             raise ValueError(
                 f"topology world {spec.world} ({spec.to_str()}) != global "
-                f"device count {jax.device_count()}; launch with "
+                f"device count {n_dev}; launch with "
                 f"world/num_processes devices per process "
                 f"(tools/launch_procs.py does this)")
         self.mesh = mesh if mesh is not None else make_topology_mesh(spec)
@@ -305,19 +308,28 @@ class MeshPlacement:
         return jax.make_array_from_callback(host.shape, sharding,
                                             lambda idx: host[idx])
 
-    def put_carry(self, carry):
-        """Place a strategy carry: every leaf with a leading replica axis
-        shards over the replica-level mesh axes; anything else (scalar
-        counters) replicates."""
+    def carry_shardings(self, carry):
+        """The sharding of each leaf of a strategy carry: every leaf with a
+        leading replica axis shards over the replica-level mesh axes;
+        anything else (scalar counters) replicates."""
         R = self.spec.n_replicas
+        return jax.tree.map(
+            lambda x: (self.carry_sharding
+                       if getattr(x, "ndim", 0) >= 1 and x.shape[0] == R
+                       else self.replicated), carry)
 
-        def one(x):
-            sh = (self.carry_sharding
-                  if getattr(x, "ndim", 0) >= 1 and x.shape[0] == R
-                  else self.replicated)
-            return self._put(x, sh)
+    def put_carry(self, carry):
+        """Place a strategy carry by `carry_shardings`."""
+        return jax.tree.map(self._put, carry, self.carry_shardings(carry))
 
-        return jax.tree.map(one, carry)
+    def constrain_carry(self, carry):
+        """Inside a traced program: keep the carry on `carry_shardings`.
+        Left to itself GSPMD may return a leaf replicated (the broadcast
+        global mean of a send), which takes R times its memory on each
+        device and makes the next program that takes the carry recompile
+        for the new input sharding."""
+        return jax.lax.with_sharding_constraint(carry,
+                                                self.carry_shardings(carry))
 
     def _batch_sharding(self, ndim: int, shape, lead: int):
         """Batch leaves are (R, per, ...) with `lead` extra leading axes

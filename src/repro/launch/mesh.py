@@ -7,24 +7,38 @@ syncs at level l produce collectives spanning exactly that level's axis
 (the per-level HLO contract, tests/test_topology.py).
 
 Functions, not module constants: importing this module must never touch
-jax device state (smoke tests see 1 CPU device)."""
+jax device state (smoke tests see 1 CPU device).
+
+`make_mesh` is the one mesh constructor of the repo: every mesh, in the
+program, the tests and the benchmarks, is built through it."""
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def make_mesh(shape, axes, *, devices=None):
+    """`jax.make_mesh` with every axis of type Auto. The DASO step code is
+    written for GSPMD propagation (vmap over a sharded replica axis,
+    `with_sharding_constraint`, dynamic-update-slice arena packing), which
+    `jax.make_mesh`'s default Explicit axes reject."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_host_mesh(n_pods: int = 2, data: int = 2, model: int = 2):
     """Small mesh for multi-device CPU tests (XLA host platform devices)."""
-    return jax.make_mesh((n_pods, data, model), ("pod", "data", "model"))
+    return make_mesh((n_pods, data, model), ("pod", "data", "model"))
 
 
-def make_topology_mesh(spec, model: int = 1):
+def make_topology_mesh(spec, model: int = 1, *, devices=None):
     """Lower a `repro.topo.TopologySpec` to a JAX mesh: one axis per
     topology level, outermost level first (major-to-minor device order
     matches the replica-index layout: inner levels vary fastest), plus a
@@ -39,10 +53,13 @@ def make_topology_mesh(spec, model: int = 1):
     *global* mesh: `jax.devices()` orders devices process-major, and the
     mesh axes are outermost-level-first, so each process's contiguous
     device block lands on a contiguous replica range — the subtree that
-    `process_node_paths` reports it as owning."""
+    `process_node_paths` reports it as owning.
+
+    `devices` defaults to all of JAX's devices; a test passes described
+    ones to compile for a chip that is not attached."""
     shape = spec.mesh_shape() + (model,)
     axes = spec.mesh_axis_names() + ("model",)
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes, devices=devices)
 
 
 # -- process <-> topology partitioning (multi-process runtime) ----------------

@@ -48,9 +48,10 @@ from repro.train.loop import TrainLoopConfig, run_training
 from repro.train.step import make_lm_loss
 from repro.optim.schedules import warmup_linear_scaled
 from repro.checkpoint.io import save_checkpoint
+from repro.launch.compile_cache import enable_compile_cache
 
 
-def main():
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="llama3.2-1b")
     ap.add_argument("--strategy", default="daso",
@@ -170,6 +171,91 @@ def main():
                     help="total process count (default $DASO_NUM_PROCS)")
     ap.add_argument("--proc-id", type=int, default=None,
                     help="this process's id (default $DASO_PROC_ID)")
+    return ap
+
+
+def arch_config(args):
+    """The architecture config `args` ask for: the reduced config, the
+    published one under --full, or the quickstart-scale LM under --tiny.
+    Raises ValueError on a contradictory request."""
+    cfg = get_config(args.arch) if args.full else get_reduced(args.arch)
+    if args.tiny:
+        if args.full:
+            raise ValueError("--tiny and --full are mutually exclusive")
+        for f in ("n_layers", "d_model", "n_heads", "d_ff", "vocab_size"):
+            if not hasattr(cfg, f):
+                raise ValueError(f"--tiny shrinks LM configs; {args.arch!r} "
+                                 f"has no {f!r}")
+        cfg = cfg.replace(n_layers=2, d_model=128, n_heads=4, n_kv_heads=2,
+                          head_dim=32, d_ff=256, vocab_size=256)
+    return cfg
+
+
+@dataclasses.dataclass
+class Job:
+    """Everything one training run needs, built from the launcher's
+    arguments and an architecture config: `run_training(job.loss_fn,
+    job.params0, job.data_fn, job.loop_cfg, lr_fn=job.lr_fn)` trains it."""
+    spec: object            # repro.topo.TopologySpec, or None
+    params0: dict
+    loss_fn: object
+    data_fn: object         # the strategy's batch stream
+    daso_data: object       # the replica-axis batch stream
+    loop_cfg: TrainLoopConfig
+    lr_fn: object
+
+
+def build_job(args, cfg) -> Job:
+    """Parameters, loss, data, loop config and LR schedule of the run that
+    `args` describe, for the architecture `cfg`. With --topology the
+    replica count and local world come from the spec (and are written
+    back to `args.nodes` / `args.local_world`)."""
+    spec = None
+    if args.topology:
+        from repro.topo import TopologySpec
+        spec = TopologySpec.load(args.topology)
+        args.nodes, args.local_world = spec.n_replicas, spec.local_world
+    # the initial parameters are kept on the host: the strategy builds its
+    # device-resident training carry from them, and a device copy held
+    # beside that carry for the whole run would only take its memory
+    params0 = jax.device_get(init_params(cfg, jax.random.PRNGKey(args.seed)))
+    src = SyntheticLM(vocab_size=cfg.vocab_size, seq_len=args.seq_len,
+                      seed=args.seed)
+    R, per = args.nodes, args.per_node_batch
+
+    def daso_data(step):
+        b = src.batch(R * per, step)
+        return {k: v.reshape((R, per) + v.shape[1:]) for k, v in b.items()}
+
+    def sync_data(step):
+        return src.batch(R * per, step)
+
+    loop_cfg = TrainLoopConfig(
+        strategy=args.strategy, n_steps=args.steps, n_replicas=R,
+        local_world=args.local_world, b_max=args.b_max,
+        # canonical string from the spec parsed above — the strategy must
+        # train on exactly the topology R/data shapes were derived from,
+        # even if --topology named a file that changes under us
+        topology=spec.to_str() if spec is not None else None, lr=args.lr,
+        executor=args.executor, max_cycle_len=args.max_cycle_len,
+        wire_format=args.wire_format, exchange_impl=args.exchange_impl,
+        overlap=args.overlap,
+        overlap_serial_exchange=args.overlap_serial_exchange,
+        ckpt_every=args.ckpt_every, ckpt_dir=args.ckpt,
+        resume_from=args.resume, distributed=args.distributed,
+        autotune=args.autotune, autotune_every=args.autotune_every)
+    lr_fn = warmup_linear_scaled(args.lr / (R * args.local_world),
+                                 R * args.local_world,
+                                 max(1, args.steps // 10))
+    return Job(spec=spec, params0=params0,
+               loss_fn=make_lm_loss(cfg),
+               data_fn=sync_data if args.strategy == "sync" else daso_data,
+               daso_data=daso_data, loop_cfg=loop_cfg, lr_fn=lr_fn)
+
+
+def main():
+    enable_compile_cache()
+    ap = build_parser()
     args = ap.parse_args()
 
     say = print
@@ -234,30 +320,22 @@ def main():
             f"({jax.local_device_count()} local of "
             f"{jax.device_count()} global devices)")
 
-    cfg = get_config(args.arch) if args.full else get_reduced(args.arch)
-    if args.tiny:
-        if args.full:
-            ap.error("--tiny and --full are mutually exclusive")
-        for f in ("n_layers", "d_model", "n_heads", "d_ff", "vocab_size"):
-            if not hasattr(cfg, f):
-                ap.error(f"--tiny shrinks LM configs; {args.arch!r} has no "
-                         f"{f!r}")
-        cfg = cfg.replace(n_layers=2, d_model=128, n_heads=4, n_kv_heads=2,
-                          head_dim=32, d_ff=256, vocab_size=256)
-    key = jax.random.PRNGKey(args.seed)
-    params0 = init_params(cfg, key)
-    loss_fn = make_lm_loss(cfg)
-    src = SyntheticLM(vocab_size=cfg.vocab_size, seq_len=args.seq_len,
-                      seed=args.seed)
-    spec = None
-    if args.topology:
-        if args.strategy not in ("daso", "hier_daso", "gossip", "easgd",
-                                 "downpour"):
-            ap.error("--topology drives the replica-axis strategies "
-                     "(daso / hier_daso / gossip / easgd / downpour)")
-        from repro.topo import TopologySpec, derive_inner_periods
-        spec = TopologySpec.load(args.topology)
-        args.nodes, args.local_world = spec.n_replicas, spec.local_world
+    if args.ckpt_every and not args.ckpt:
+        ap.error("--ckpt-every requires --ckpt")
+    if args.topology and args.strategy not in ("daso", "hier_daso", "gossip",
+                                               "easgd", "downpour"):
+        ap.error("--topology drives the replica-axis strategies "
+                 "(daso / hier_daso / gossip / easgd / downpour)")
+    try:
+        cfg = arch_config(args)
+    except ValueError as e:
+        ap.error(str(e))
+    job = build_job(args, cfg)
+    spec, params0, loss_fn = job.spec, job.params0, job.loss_fn
+    daso_data, loop_cfg = job.daso_data, job.loop_cfg
+    R = args.nodes
+    if spec is not None:
+        from repro.topo import derive_inner_periods
         # a %period on the outermost level overrides --b-max (exactly as
         # build_strategy's lowering does), so log the schedule that runs
         b_eff = (spec.outer.period if spec.outer.period is not None
@@ -270,35 +348,6 @@ def main():
         # they must be process-local or they'd race the in-flight exchange
         from repro.launch.distributed import check_overlap_topology
         check_overlap_topology(spec, dist.num_processes)
-    R, per = args.nodes, args.per_node_batch
-
-    def daso_data(step):
-        b = src.batch(R * per, step)
-        return {k: v.reshape((R, per) + v.shape[1:]) for k, v in b.items()}
-
-    def sync_data(step):
-        return src.batch(R * per, step)
-
-    if args.ckpt_every and not args.ckpt:
-        ap.error("--ckpt-every requires --ckpt")
-    loop_cfg = TrainLoopConfig(
-        strategy=args.strategy, n_steps=args.steps, n_replicas=R,
-        local_world=args.local_world, b_max=args.b_max,
-        # canonical string from the spec parsed above — the strategy must
-        # train on exactly the topology R/data shapes were derived from,
-        # even if --topology named a file that changes under us
-        topology=spec.to_str() if spec is not None else None, lr=args.lr,
-        executor=args.executor, max_cycle_len=args.max_cycle_len,
-        wire_format=args.wire_format, exchange_impl=args.exchange_impl,
-        overlap=args.overlap,
-        overlap_serial_exchange=args.overlap_serial_exchange,
-        ckpt_every=args.ckpt_every, ckpt_dir=args.ckpt,
-        resume_from=args.resume, distributed=args.distributed,
-        autotune=args.autotune, autotune_every=args.autotune_every)
-    lr_fn = warmup_linear_scaled(args.lr / (R * args.local_world),
-                                 R * args.local_world,
-                                 max(1, args.steps // 10))
-    data_fn = sync_data if args.strategy == "sync" else daso_data
 
     if args.trace_out and tracer is None:  # single-process run
         from repro.obs.trace import Tracer, stream_path
@@ -425,7 +474,7 @@ def main():
             health.phase("train")
         if tracer is not None and strategy.controller is not None:
             strategy.controller.tracer = tracer
-        report = run_with_faults(strategy, params0, daso_data, lr_fn,
+        report = run_with_faults(strategy, params0, daso_data, job.lr_fn,
                                  args.steps, plan,
                                  ckpt_every=args.ckpt_every,
                                  ckpt_cb=ckpt_cb, placement=placement,
@@ -452,8 +501,8 @@ def main():
     else:
         if health is not None:
             health.phase("train")
-        result = run_training(loss_fn, params0, data_fn, loop_cfg,
-                              lr_fn=lr_fn, log=say, health=health,
+        result = run_training(loss_fn, params0, job.data_fn, loop_cfg,
+                              lr_fn=job.lr_fn, log=say, health=health,
                               tracer=tracer)
     if health is not None:
         health.phase("finalize")

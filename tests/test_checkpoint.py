@@ -95,7 +95,8 @@ def test_sharded_restore_placement(tmp_path):
     requested NamedSharding."""
     from jax.sharding import NamedSharding, PartitionSpec as P
 
-    mesh = jax.make_mesh((1,), ("pod",))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((1,), ("pod",))
     tree = {"w": jnp.arange(8, dtype=jnp.float32).reshape(2, 4),
             "b": jnp.ones((4,))}
     save_checkpoint(str(tmp_path), tree)

@@ -48,7 +48,8 @@ def test_daso_mesh_step_matches_single_device_simulator():
         # single-device (simulator) run
         ref = run(lambda t: t)
         # mesh run: replica axis sharded over pod, batch over data
-        mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
         sh_p = NamedSharding(mesh, P("pod"))
         put = lambda t: jax.tree.map(
             lambda x: jax.device_put(x, sh_p), t)
@@ -73,7 +74,8 @@ def test_daso_cycle_collectives_touch_pod_axis_only_on_sync_steps():
             pred = batch["x"] @ params["w"]
             return jnp.mean((pred - batch["y"]) ** 2), {}
 
-        mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
         mesh_shape = dict(zip(mesh.axis_names, mesh.devices.shape))
         R, per, d = 2, 4, 128  # w is 128x4 f32 = 2 KiB > the 1 KiB threshold
         opt = sgd(momentum=0.0, weight_decay=0.0)
@@ -124,7 +126,8 @@ def test_sharded_lm_forward_matches_single_device():
         toks = jax.random.randint(key, (4, 32), 0, cfg.vocab_size)
         ref = forward(params, toks, cfg)["logits"]
 
-        mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
         policy = make_policy(mesh, fsdp=True)
         p_sh = make_param_shardings(cfg, params, policy)
         params_s = jax.tree.map(jax.device_put, params, p_sh)

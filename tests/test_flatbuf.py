@@ -109,8 +109,8 @@ def test_int8_quantize_error_bounds_property(block, n, stochastic):
     x = jax.random.normal(key, (n,)) * (1.0 + n % 7)
     bits = (jax.random.bits(jax.random.fold_in(key, 1), x.shape, jnp.uint32)
             if stochastic else None)
-    v, s = ops.quantize_int8(x, bits, block=block)
-    d = ops.dequantize_int8(v, s, block=block)
+    v, s = ops.quantize_int8(x, bits, block=block, interpret=True)
+    d = ops.dequantize_int8(v, s, block=block, interpret=True)
     # expand per-block scales to elementwise bounds
     nb = s.shape[-1]
     bound = np.repeat(np.asarray(s), block)[:n]
@@ -131,16 +131,18 @@ def test_int8_stochastic_rounding_is_unbiased():
     x[0] = 12.7  # pins the block scale to 12.7/127 = 0.1 exactly
     x = jnp.asarray(x)
     # deterministic: 0.325/0.1 = 3.25 rounds to 3 -> constant 0.025 bias
-    vd, sd = ops.quantize_int8(x, block=256)
-    det = np.asarray(ops.dequantize_int8(vd, sd, block=256))[1:]
+    vd, sd = ops.quantize_int8(x, block=256, interpret=True)
+    det = np.asarray(ops.dequantize_int8(vd, sd, block=256,
+                                         interpret=True))[1:]
     assert abs(det.mean() - 0.325) > 0.02
     acc = 0.0
     draws = 200
     for i in range(draws):
         bits = jax.random.bits(jax.random.fold_in(key, i),
                                x.shape, jnp.uint32)
-        vv, ss = ops.quantize_int8(x, bits, block=256)
-        acc += np.asarray(ops.dequantize_int8(vv, ss, block=256))[1:].mean()
+        vv, ss = ops.quantize_int8(x, bits, block=256, interpret=True)
+        acc += np.asarray(ops.dequantize_int8(vv, ss, block=256,
+                                              interpret=True))[1:].mean()
     assert abs(acc / draws - 0.325) < 0.005
 
 
@@ -151,7 +153,7 @@ def test_eq1_merge_kernel_matches_ref():
     local = jax.random.normal(key, (2, 999))
     stale = jax.random.normal(jax.random.fold_in(key, 1), (2, 999))
     out = ops.eq1_merge(local, stale, staleness=3, global_world=16,
-                        block=256)
+                        block=256, interpret=True)
     expect = ref.eq1_merge_ref(local, stale, staleness=3, global_world=16)
     np.testing.assert_allclose(np.asarray(out), np.asarray(expect),
                                atol=1e-6)
@@ -160,9 +162,9 @@ def test_eq1_merge_kernel_matches_ref():
 def test_bf16_pack_unpack_kernels():
     key = jax.random.PRNGKey(4)
     x = jax.random.normal(key, (3, 500))
-    b = ops.bf16_pack(x, block=128)
+    b = ops.bf16_pack(x, block=128, interpret=True)
     assert b.dtype == jnp.bfloat16 and b.shape == x.shape
-    u = ops.bf16_unpack(b, block=128)
+    u = ops.bf16_unpack(b, block=128, interpret=True)
     np.testing.assert_array_equal(
         np.asarray(u), np.asarray(x.astype(jnp.bfloat16)
                                   .astype(jnp.float32)))
@@ -172,14 +174,14 @@ def test_quantize_kernel_matches_ref():
     key = jax.random.PRNGKey(5)
     x = jax.random.normal(key, (2, 777)) * 4
     for bits in (None, jax.random.bits(key, x.shape, jnp.uint32)):
-        v, s = ops.quantize_int8(x, bits, block=128)
+        v, s = ops.quantize_int8(x, bits, block=128, interpret=True)
         vr, sr = ref.quantize_int8_block_ref(x, block=128, bits=bits)
         np.testing.assert_allclose(np.asarray(s), np.asarray(sr),
                                    rtol=1e-6)
         # a 1-ULP scale difference may flip a rounding boundary
         assert np.max(np.abs(np.asarray(v, np.int32)
                              - np.asarray(vr, np.int32))) <= 1
-        d = ops.dequantize_int8(v, s, block=128)
+        d = ops.dequantize_int8(v, s, block=128, interpret=True)
         dr = ref.dequantize_int8_block_ref(vr, sr, block=128)
         np.testing.assert_allclose(np.asarray(d), np.asarray(dr),
                                    atol=1e-4)
@@ -224,9 +226,11 @@ def test_int8_wire_halves_bf16_bytes():
 
 def test_one_exchange_is_one_all_reduce_any_leaf_count():
     """The fused exchange lowers to exactly ONE cross-replica all-reduce
-    independent of the number of parameter leaves; the legacy per-leaf
-    path lowers to one per leaf. Runs on a 2-virtual-device pod mesh in a
-    subprocess (the main pytest process keeps its single real device)."""
+    independent of the number of parameter leaves. (The legacy per-leaf
+    path is no longer held to one all-reduce per leaf: XLA's all-reduce
+    combiner may merge them, so it is only checked to lower to at least
+    one.) Runs on a 2-virtual-device pod mesh in a subprocess (the main
+    pytest process keeps its single real device)."""
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=2"
     env["PYTHONPATH"] = SRC + os.pathsep + env.get("PYTHONPATH", "")
@@ -236,7 +240,8 @@ def test_one_exchange_is_one_all_reduce_any_leaf_count():
         from repro.core.daso import blocking_sync, replica_mean_per_leaf
         from repro.launch.hlo_stats import collective_stats
 
-        mesh = jax.make_mesh((2,), ("pod",))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((2,), ("pod",))
         sh = NamedSharding(mesh, P("pod"))
 
         def n_all_reduce(fn, tree):
@@ -257,7 +262,7 @@ def test_one_exchange_is_one_all_reduce_any_leaf_count():
                 assert n == 1, (wf, n_leaves, n)
             n = n_all_reduce(
                 lambda t: replica_mean_per_leaf(t, jnp.bfloat16), tree)
-            assert n == n_leaves, (n_leaves, n)
+            assert 1 <= n <= n_leaves, (n_leaves, n)
         print("ONE COLLECTIVE OK")
     """
     r = subprocess.run([sys.executable, "-c", textwrap.dedent(script)],

@@ -1,4 +1,4 @@
-"""Pallas kernel validation (interpret=True on CPU): shape/dtype sweeps +
+"""Pallas kernel validation (interpret=True): shape/dtype sweeps +
 hypothesis properties, assert_allclose vs the pure-jnp oracles in ref.py."""
 import jax
 import jax.numpy as jnp
@@ -25,7 +25,8 @@ def test_flash_attention_sweep(B, Hq, Hk, Sq, Sk, D, dtype):
     q = jax.random.normal(ks[0], (B, Hq, Sq, D)).astype(dtype)
     k = jax.random.normal(ks[1], (B, Hk, Sk, D)).astype(dtype)
     v = jax.random.normal(ks[2], (B, Hk, Sk, D)).astype(dtype)
-    out = flash_attention(q, k, v, causal=True, block_q=64, block_k=64)
+    out = flash_attention(q, k, v, causal=True, block_q=64, block_k=64,
+                          interpret=True)
     ref = attention_ref(q, k, v, causal=True)
     atol = 2e-5 if dtype == jnp.float32 else 2e-2
     np.testing.assert_allclose(np.asarray(out, np.float32),
@@ -41,7 +42,7 @@ def test_flash_attention_sliding_window(window):
     k = jax.random.normal(ks[1], (B, H, S, D))
     v = jax.random.normal(ks[2], (B, H, S, D))
     out = flash_attention(q, k, v, causal=True, window=window,
-                          block_q=64, block_k=64)
+                          block_q=64, block_k=64, interpret=True)
     ref = attention_ref(q, k, v, causal=True, window=window)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
 
@@ -55,7 +56,8 @@ def test_flash_attention_block_size_invariance(bq, bk):
     q = jax.random.normal(ks[0], (B, H, S, D))
     k = jax.random.normal(ks[1], (B, H, S, D))
     v = jax.random.normal(ks[2], (B, H, S, D))
-    out = flash_attention(q, k, v, causal=True, block_q=bq, block_k=bk)
+    out = flash_attention(q, k, v, causal=True, block_q=bq, block_k=bk,
+                          interpret=True)
     ref = attention_ref(q, k, v, causal=True)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
 
@@ -78,7 +80,7 @@ def test_ssm_scan_sweep(B, S, Di, N, bd, dtype):
     Bm = jax.random.normal(ks[3], (B, S, N)).astype(dtype)
     Cm = jax.random.normal(ks[4], (B, S, N)).astype(dtype)
     h0 = jnp.zeros((B, Di, N), jnp.float32)
-    y, h = ssm_scan(x, dt, A, Bm, Cm, h0, block_d=bd)
+    y, h = ssm_scan(x, dt, A, Bm, Cm, h0, block_d=bd, interpret=True)
     yr, hr = ssm_scan_ref(x, dt, A, Bm, Cm, h0)
     atol = 1e-4 if dtype == jnp.float32 else 5e-2
     np.testing.assert_allclose(np.asarray(y), np.asarray(yr), atol=atol)
@@ -95,7 +97,7 @@ def test_ssm_scan_nonzero_initial_state():
     Bm = jax.random.normal(ks[3], (B, S, N))
     Cm = jax.random.normal(ks[4], (B, S, N))
     h0 = jax.random.normal(ks[5], (B, Di, N))
-    y, h = ssm_scan(x, dt, A, Bm, Cm, h0, block_d=16)
+    y, h = ssm_scan(x, dt, A, Bm, Cm, h0, block_d=16, interpret=True)
     yr, hr = ssm_scan_ref(x, dt, A, Bm, Cm, h0)
     np.testing.assert_allclose(np.asarray(y), np.asarray(yr), atol=1e-4)
 
@@ -109,7 +111,7 @@ def test_rglru_scan_sweep(B, S, W, bw):
     a = jax.nn.sigmoid(jax.random.normal(ks[0], (B, S, W)))
     gx = jax.random.normal(ks[1], (B, S, W))
     h0 = jax.random.normal(ks[2], (B, W))
-    hs, h = rglru_scan(a, gx, h0, block_w=bw)
+    hs, h = rglru_scan(a, gx, h0, block_w=bw, interpret=True)
     hsr, hr = rglru_scan_ref(a, gx, h0)
     np.testing.assert_allclose(np.asarray(hs), np.asarray(hsr), atol=1e-5)
     np.testing.assert_allclose(np.asarray(h), np.asarray(hr), atol=1e-5)
@@ -125,6 +127,6 @@ def test_rglru_decay_bound_property(seed):
     gx = jnp.clip(jax.random.normal(jax.random.fold_in(key, 1), (B, S, W)),
                   -1, 1)
     h0 = jnp.zeros((B, W))
-    hs, _ = rglru_scan(a, gx, h0, block_w=16)
+    hs, _ = rglru_scan(a, gx, h0, block_w=16, interpret=True)
     bound = 1.0 / (1.0 - 0.99) + 1.0
     assert float(jnp.max(jnp.abs(hs))) < bound

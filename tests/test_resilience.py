@@ -125,7 +125,8 @@ def test_one_collective_holds_under_membership_mask():
         from repro.core.daso import blocking_sync
         from repro.launch.hlo_stats import collective_stats
 
-        mesh = jax.make_mesh((2,), ("pod",))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((2,), ("pod",))
         sh = NamedSharding(mesh, P("pod"))
         tree = {f"w{i}": jax.ShapeDtypeStruct((2, 32, 3 + i), jnp.float32)
                 for i in range(6)}

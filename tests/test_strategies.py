@@ -238,7 +238,8 @@ def loss_fn(params, batch):
     pred = batch["x"] @ params["w"]
     return jnp.mean((pred - batch["y"]) ** 2), {}
 
-mesh = jax.make_mesh((2,), ("pod",))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((2,), ("pod",))
 mesh_shape = {"pod": 2}
 R, per, d = 2, 4, 256   # w: 256x4 f32 = 4 KiB >> the 1 KiB floor
 cfg = DasoConfig(n_replicas=R, global_world=4 * R, b_max=4,

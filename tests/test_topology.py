@@ -193,7 +193,7 @@ def _tree(key, R):
                   "n": jnp.arange(R * 4, dtype=jnp.int32).reshape(R, 4)}}
 
 
-@settings(max_examples=15)
+@settings(max_examples=15, deadline=None)
 @given(groups=st.integers(2, 4), per=st.integers(1, 3),
        seed=st.integers(0, 100))
 def test_level_group_mean_matches_per_group_oracle(groups, per, seed):
