@@ -19,7 +19,7 @@ def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", default=None,
                     help="comma list: fig6,fig7,fig8,fig9,micro,exchange,"
-                         "resilience,topology,overlap,obs,roofline,"
+                         "resilience,topology,overlap,roofline,"
                          "strategies,tuning")
     ap.add_argument("--quick", action="store_true",
                     help="shorter convergence runs")
@@ -29,7 +29,7 @@ def main() -> None:
     def want(tag):
         return only is None or tag in only
 
-    from benchmarks import (figures, microbench, obs, overlap, resilience,
+    from benchmarks import (figures, microbench, overlap, resilience,
                             roofline, strategies, topology, tuning)
 
     print("name,us_per_call,derived")
@@ -51,8 +51,6 @@ def main() -> None:
         topology.emit_rows(emit, quick=args.quick)
     if want("overlap"):
         overlap.emit_rows(emit, quick=args.quick)
-    if want("obs"):
-        obs.emit_rows(emit, quick=args.quick)
     if want("roofline"):
         roofline.emit_rows(emit)
     if want("strategies"):

@@ -36,6 +36,7 @@ from __future__ import annotations
 
 import difflib
 import time
+from contextlib import contextmanager
 from dataclasses import dataclass, field
 from typing import Callable, Dict, List, Optional, Tuple
 
@@ -52,7 +53,7 @@ from repro.core.daso import (DasoConfig, _cross_replica_loss,
                              replicate_params, sync_train_step)
 from repro.core.schedule import (DasoController, Mode, is_ov_mode, join_mode,
                                  split_mode, split_ov)
-from repro.obs.trace import NULL_TRACER
+from repro.obs.trace import NULL_TRACER, backend_compiles
 from repro.optim.optimizers import Optimizer
 
 # A cycle shape is the static fingerprint of a macro-cycle: one
@@ -505,12 +506,28 @@ class LocalSGDStrategy(DasoStrategy):
 
 # -- the executor --------------------------------------------------------------
 
+#: The host loop's phases of one cycle, in order (`dispatch_planned_cycle`,
+#: `run_compiled_training`, the resilience supervisor): each runs under a
+#: `repro.<phase>` span and is timed into `ExecutorStats.phase_s`.
+#:   stage            data_fn calls, batch stacking/placement, lr upload
+#:   dispatch         the cycle program's launch (a compile lands here)
+#:   wait             until the cycle's metrics are computed on the device
+#:   readback         their copy to the host and conversion to floats
+#:   control          plan_cycle, observe (and divergence when tracked)
+#:   checkpoint_save  the checkpoint callback
+LOOP_PHASES = ("stage", "dispatch", "wait", "readback", "control",
+               "checkpoint_save")
+
+
 @dataclass
 class ExecutorStats:
     dispatches: int = 0        # host->device program invocations
     steps: int = 0             # training steps covered by those dispatches
     cycles: int = 0            # macro-cycles executed compiled
     compiles: int = 0          # distinct cycle shapes compiled
+    # XLA backend compiles (or persistent-cache loads) during cycles: also
+    # counts a jit recompile of a built shape for a new input placement
+    backend_compiles: int = 0
     fallback_steps: int = 0    # steps run on the per-step fallback path
     invalidations: int = 0     # cache flushes (membership changes etc.)
     # overlap-dispatch timing (wall-clock, host-observed):
@@ -529,6 +546,13 @@ class ExecutorStats:
     # completion times, not async dispatch returns
     overlap_merge_s: float = 0.0
     overlap_wall_s: float = 0.0
+    # host seconds per loop phase (LOOP_PHASES), summed over every cycle
+    phase_s: Dict[str, float] = field(
+        default_factory=lambda: dict.fromkeys(LOOP_PHASES, 0.0))
+    # the phase split of the slowest cycle so far (by its phases' sum; a
+    # cycle runs from one stage phase to the next). A caller that wants
+    # the slowest of a later window resets it to {}
+    slowest_cycle_s: Dict[str, float] = field(default_factory=dict)
 
     def dispatches_per_step(self) -> float:
         total = self.steps + self.fallback_steps
@@ -582,6 +606,7 @@ class MacroCycleExecutor:
         # branch-free when tracing is off
         self.tracer = tracer if tracer is not None else NULL_TRACER
         self.stats = ExecutorStats()
+        self._cycle_s: Dict[str, float] = {}   # the running cycle's split
         self._programs: Dict[CycleShape, Callable] = {}
         self._per_step: Dict[Tuple[str, int], Callable] = {}
         # jitted overlap exchange/merge programs ("exchange", or
@@ -598,8 +623,8 @@ class MacroCycleExecutor:
             self._programs[shape] = self._build_program(shape)
             self.stats.compiles += 1
             # instant, not a span: jit is lazy, the XLA compile itself
-            # lands inside the first cycle span of this shape (which is
-            # why cycle spans carry a fresh_compile flag)
+            # lands inside the first cycle span of this shape (whose
+            # `compiles` arg counts it)
             self.tracer.instant("compile", cat="executor",
                                 shape_len=len(shape),
                                 modes=[m for m, _ in shape])
@@ -677,6 +702,29 @@ class MacroCycleExecutor:
 
             self._per_step[key] = jax.jit(step)
         return self._per_step[key]
+
+    # -- host-loop phases --------------------------------------------------
+    @contextmanager
+    def phase(self, name: str, cat: str = "executor", **args):
+        """One host-loop phase of a cycle (LOOP_PHASES): a span, hence a
+        `repro.<name>` profiler annotation, timed into `stats.phase_s` and
+        the running cycle's split. `stage` begins a new cycle."""
+        if name == "stage":
+            self.end_cycle()
+        t0 = time.perf_counter()
+        with self.tracer.span(name, cat=cat, **args) as sp:
+            yield sp
+        dt = time.perf_counter() - t0
+        self.stats.phase_s[name] += dt
+        self._cycle_s[name] = self._cycle_s.get(name, 0.0) + dt
+
+    def end_cycle(self) -> None:
+        """Close the running cycle's phase split, keeping it as
+        `stats.slowest_cycle_s` when it is the slowest so far."""
+        if (sum(self._cycle_s.values())
+                > sum(self.stats.slowest_cycle_s.values())):
+            self.stats.slowest_cycle_s = self._cycle_s
+        self._cycle_s = {}
 
     # -- execution ---------------------------------------------------------
     def run_cycle(self, carry, plan: CyclePlan, batches, lrs, *,
@@ -837,44 +885,56 @@ def dispatch_planned_cycle(ex: MacroCycleExecutor, carry, plan: CyclePlan,
     per_step_metrics). Shared by `run_compiled_training` and the resilience
     supervisor so the two dispatch loops cannot silently drift.
 
-    The whole staging -> dispatch -> host-fetch sequence is one "cycle"
-    trace span: the np.asarray conversion below forces device completion,
-    so the span duration is the cycle's true wall cost, not its async
-    dispatch cost. The span's args carry the per-level sync counts and a
-    fresh_compile flag (first execution of a shape pays its XLA
-    compilation inside this span) — everything the drift-table fit needs."""
-    compiles0, fallback0 = ex.stats.compiles, ex.stats.fallback_steps
-    with ex.tracer.span("cycle", cat="executor",
-                        start_step=plan.start_step, steps=len(plan),
-                        syncs=shape_sync_counts(plan.shape)) as sp:
-        steps = range(plan.start_step, plan.start_step + len(plan))
-        per_step = [data_fn(t) for t in steps]
-        lr_list = [lr_fn(t) for t in steps]
-        if ex.placement is not None:
-            batches, lrs = ex.placement.stage_cycle(per_step, lr_list)
-        else:
-            batches = jax.tree.map(lambda *xs: jnp.stack(xs), *per_step)
-            lrs = jnp.asarray(lr_list, jnp.float32)
-        carry, metrics = ex.run_cycle(
-            carry, plan, batches, lrs,
-            is_tail=plan.start_step + len(plan) >= n_steps)
+    The whole sequence is one "cycle" span over the loop phases stage ->
+    dispatch -> wait -> readback (`MacroCycleExecutor.phase`). `wait`
+    blocks until the cycle's metrics are computed, so the span covers the
+    cycle's device time and `readback` only the copy and conversion. The
+    cycle span's args carry the per-level sync counts (when the stream is
+    on: the drift-table fit reads them) and the backend compiles seen
+    during the cycle; `fresh_compile` is True when there was one (a new
+    shape, or a jit recompile of a built one for a new input placement)
+    — the fit leaves those cycles out."""
+    fallback0 = ex.stats.fallback_steps
+    compiles0 = backend_compiles()
+    args = {"start_step": plan.start_step, "steps": len(plan)}
+    if ex.tracer.enabled:
+        args["syncs"] = shape_sync_counts(plan.shape)
+    with ex.tracer.span("cycle", cat="executor", **args) as sp:
+        with ex.phase("stage", steps=len(plan)):
+            steps = range(plan.start_step, plan.start_step + len(plan))
+            per_step = [data_fn(t) for t in steps]
+            lr_list = [lr_fn(t) for t in steps]
+            if ex.placement is not None:
+                batches, lrs = ex.placement.stage_cycle(per_step, lr_list)
+            else:
+                batches = jax.tree.map(lambda *xs: jnp.stack(xs), *per_step)
+                lrs = jnp.asarray(lr_list, jnp.float32)
+        with ex.phase("dispatch") as dsp:
+            d0 = backend_compiles()
+            carry, metrics = ex.run_cycle(
+                carry, plan, batches, lrs,
+                is_tail=plan.start_step + len(plan) >= n_steps)
+            dsp.set_metadata(compiles=backend_compiles() - d0)
         # per-replica diagnostics may be sharded across processes in a
         # distributed run; only host-fetchable metrics (scalars are always
         # replicated) feed the loss trace
-        host = {k: np.asarray(v) for k, v in metrics.items()
-                if flatbuf.host_fetchable(v)}
-        if ex.tracer.enabled:
-            # span args serialize at __exit__, so outcome flags can land
-            # after the fact
-            sp.args["fresh_compile"] = ex.stats.compiles > compiles0
-            sp.args["fallback"] = ex.stats.fallback_steps > fallback0
-    cycle_losses = [float(host["loss"][j]) for j in range(len(plan))]
-    per_step_metrics = [{k: float(v[j]) for k, v in host.items()
-                         if v.ndim == 1} for j in range(len(plan))]
+        fetch = [(k, v) for k, v in metrics.items()
+                 if flatbuf.host_fetchable(v)]
+        with ex.phase("wait"):
+            jax.block_until_ready([v for _, v in fetch])
+        with ex.phase("readback", arrays=len(fetch)):
+            host = {k: np.asarray(v) for k, v in fetch}
+            cycle_losses = [float(host["loss"][j]) for j in range(len(plan))]
+            per_step_metrics = [{k: float(v[j]) for k, v in host.items()
+                                 if v.ndim == 1} for j in range(len(plan))]
+        compiles = backend_compiles() - compiles0
+        ex.stats.backend_compiles += compiles
+        sp.set_metadata(compiles=compiles, fresh_compile=compiles > 0,
+                        fallback=ex.stats.fallback_steps > fallback0)
     if ex.health is not None:
-        # progress report AFTER the host conversion above forced the
-        # cycle's collectives to complete: the watchdog deadline only
-        # moves when the group demonstrably made it through the exchange
+        # progress report AFTER the wait above forced the cycle's
+        # collectives to complete: the watchdog deadline only moves when
+        # the group demonstrably made it through the exchange
         ex.health.cycle_done(plan.start_step + len(plan))
     return carry, cycle_losses, per_step_metrics
 
@@ -926,23 +986,25 @@ def run_compiled_training(strategy: Strategy, params0, data_fn: Callable,
     next_ckpt = ((start_step // ckpt_every + 1) * ckpt_every
                  if ckpt_every else None)
     while step < n_steps:
-        plan = strategy.plan_cycle(step, min(ex.max_cycle_len,
-                                             n_steps - step))
+        with ex.phase("control"):
+            plan = strategy.plan_cycle(step, min(ex.max_cycle_len,
+                                                 n_steps - step))
         carry, cycle_losses, per_step_metrics = dispatch_planned_cycle(
             ex, carry, plan, data_fn, lr_fn, n_steps)
         losses.extend(cycle_losses)
         metrics_log.extend(per_step_metrics)
-        strategy.observe(cycle_losses)
-        if track_divergence:
-            d = strategy.divergence(carry)
-            if d is not None:
-                divs.extend([d] * len(plan))
+        with ex.phase("control"):
+            strategy.observe(cycle_losses)
+            if track_divergence:
+                d = strategy.divergence(carry)
+                if d is not None:
+                    divs.extend([d] * len(plan))
         step += len(plan)
         if next_ckpt is not None and ckpt_cb is not None and step >= next_ckpt:
-            with ex.tracer.span("checkpoint_save", cat="checkpoint",
-                                step=step):
+            with ex.phase("checkpoint_save", cat="checkpoint", step=step):
                 ckpt_cb(step, carry, losses)
             next_ckpt = (step // ckpt_every + 1) * ckpt_every
+    ex.end_cycle()
     params = (placement.finalize_params(strategy, carry)
               if placement is not None
               else strategy.finalize_params(carry))

@@ -253,6 +253,11 @@ def build_job(args, cfg) -> Job:
                daso_data=daso_data, loop_cfg=loop_cfg, lr_fn=lr_fn)
 
 
+def _phase_line(phase_s, scale: float) -> str:
+    """`stage=1.234 dispatch=...`: host ms per loop phase, times `scale`."""
+    return " ".join(f"{k}={v * 1e3 * scale:.3f}" for k, v in phase_s.items())
+
+
 def main():
     enable_compile_cache()
     ap = build_parser()
@@ -510,8 +515,13 @@ def main():
         s = result.executor_stats
         say(f"[train] executor: {s.dispatches} host dispatches for "
             f"{args.steps} steps ({s.compiles} compiled cycle shapes, "
+            f"{s.backend_compiles} backend compiles, "
             f"{s.fallback_steps} tail-fallback steps, "
             f"{s.invalidations} invalidations)")
+        if s.cycles:
+            say("[train] executor host phases, ms per cycle: "
+                + _phase_line(s.phase_s, 1.0 / s.cycles)
+                + "; slowest cycle: " + _phase_line(s.slowest_cycle_s, 1.0))
 
     comm_rows = None
     if tracer is not None and result.controller is not None:
@@ -563,8 +573,7 @@ def main():
             # the merge to tools/launch_procs.py after the group exits
             from repro.obs.trace import merge_streams
             merge_streams(args.trace_out, log=say)
-        say(f"[train] trace events={tracer.n_events} "
-            f"overhead={tracer.overhead_s * 1e3:.1f}ms -> {args.trace_out}")
+        say(f"[train] trace events={tracer.n_events} -> {args.trace_out}")
 
 
 if __name__ == "__main__":

@@ -1,4 +1,5 @@
-"""Low-overhead span/counter tracing: one JSONL stream per process.
+"""Low-overhead span/counter tracing: one JSONL stream per process, and
+every span on the profiler's clock.
 
 Every event is already shaped like a Chrome trace-event (the `ph`/`ts`/
 `dur`/`pid`/`tid` vocabulary of the trace-event format), so merging the
@@ -8,14 +9,20 @@ wrapping the lines in ``{"traceEvents": [...]}`` (tools/trace_report.py).
 
 Design constraints, in order:
 
+  * **on the profiler's clock** — every span, `NULL_TRACER`'s too, enters
+    a `jax.profiler.TraceAnnotation` named ``repro.<name>`` with the
+    span's args. When a profile is being recorded (`jax.profiler.trace`)
+    it lands on the profiler's host plane, on the same clock as the
+    device's `XLA Ops` and `XLA Modules` lines; when none is, it costs
+    about a microsecond. Readers match a span by its name before any
+    `#` (the profiler may encode args into the name).
   * **cheap when off** — callers hold a tracer unconditionally; the shared
-    `NULL_TRACER` makes every call a no-op (its `span` returns a reusable
-    do-nothing context manager, no allocation per call).
+    `NULL_TRACER` writes no stream, so its spans are bare annotations.
+    Span args are counts the caller already holds; anything costlier is
+    computed only when `tracer.enabled`.
   * **cheap when on** — events are appended to an in-memory list under a
     lock (the resilience heartbeat thread and the training thread both
-    write) and flushed to disk every `flush_every` events; the tracer
-    accounts its own cumulative cost in `overhead_s` so the tracing-
-    overhead claim in BENCH_obs.json is self-measured, not inferred.
+    write) and flushed to disk every `flush_every` events.
   * **merge-aligned timestamps** — `ts` is wall-clock microseconds
     (`time.time_ns() // 1000`): processes of one run share the host clock,
     so merged streams interleave correctly; `dur` comes from
@@ -23,14 +30,15 @@ Design constraints, in order:
 
 Span taxonomy (the `cat` field; docs/observability.md has the full table):
 
-  executor    compiled-cycle dispatch, compiles, overlap exchange legs,
-              tail-fallback steps (core/executor.py)
+  executor    compiled-cycle dispatch and its host-loop phases, compiles,
+              overlap exchange legs, tail-fallback steps
+              (core/executor.py)
   schedule    controller decision events: plateau-driven B/W changes,
               membership/DCN notifications, each with a `reason`
               (core/schedule.py)
   resilience  health-plane phase changes, fault events, regroup replay
               (resilience/runtime.py, resilience/supervisor.py)
-  checkpoint  TrainState saves (train/loop.py)
+  checkpoint  TrainState saves (core/executor.py, resilience/supervisor.py)
   meter       comm-accounting counter snapshots (obs/meters.py readings)
   meta        the run_metadata event: topology, wire format, parameter
               bytes — what tools/trace_report.py needs to price the model
@@ -45,6 +53,9 @@ import threading
 import time
 from typing import Callable, Dict, Iterable, List, Optional
 
+import jax
+from jax.profiler import TraceAnnotation
+
 # event phases we emit/accept: X = complete span (ts + dur), i = instant,
 # C = counter, M = metadata (process_name etc.)
 PHASES = ("X", "i", "C", "M")
@@ -52,6 +63,34 @@ PHASES = ("X", "i", "C", "M")
 #: the one metadata event every stream opens with — trace_report reads the
 #: run configuration (topology, param bytes, wire format) out of its args
 RUN_METADATA = "run_metadata"
+
+#: prefix of every span's name on the profiler's host plane
+PROFILER_PREFIX = "repro."
+
+#: JAX's monitoring event for one XLA backend compile (or one load of a
+#: compiled program from the persistent compilation cache)
+BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+
+_backend_compiles = 0
+_compiles_lock = threading.Lock()
+
+
+def _on_duration(event: str, _duration: float, **_kw) -> None:
+    global _backend_compiles
+    if event == BACKEND_COMPILE_EVENT:
+        with _compiles_lock:
+            _backend_compiles += 1
+
+
+jax.monitoring.register_event_duration_secs_listener(_on_duration)
+
+
+def backend_compiles() -> int:
+    """Backend compiles (or persistent-cache loads) this process has run
+    since `repro.obs` was imported. Unlike a count of the programs the
+    executor builds, it sees a jit recompile of a built program for a new
+    input placement."""
+    return _backend_compiles
 
 
 def stream_path(base: str, proc_id: int, epoch: int = 0) -> str:
@@ -62,30 +101,15 @@ def stream_path(base: str, proc_id: int, epoch: int = 0) -> str:
     return f"{base}.e{epoch}p{proc_id}.jsonl"
 
 
-class _NullSpan:
-    """Reusable no-op context manager (one shared instance, no per-call
-    allocation)."""
-    __slots__ = ()
-
-    def __enter__(self):
-        return self
-
-    def __exit__(self, *exc):
-        return False
-
-
-_NULL_SPAN = _NullSpan()
-
-
 class NullTracer:
-    """API-complete no-op tracer; the default everywhere a tracer can be
-    threaded so call sites never branch."""
+    """API-complete tracer that writes no stream; the default everywhere a
+    tracer can be threaded so call sites never branch. Its spans are
+    profiler annotations alone."""
     enabled = False
-    overhead_s = 0.0
     n_events = 0
 
     def span(self, name: str, cat: str = "executor", **args):
-        return _NULL_SPAN
+        return TraceAnnotation(PROFILER_PREFIX + name, **args)
 
     def instant(self, name: str, cat: str = "executor", **args) -> None:
         pass
@@ -108,8 +132,9 @@ NULL_TRACER = NullTracer()
 
 
 class _Span:
-    """Context manager recording one complete ("X") event on exit."""
-    __slots__ = ("tracer", "name", "cat", "args", "_ts_us", "_t0")
+    """Context manager recording one complete ("X") event on exit, inside
+    a profiler annotation of the same name."""
+    __slots__ = ("tracer", "name", "cat", "args", "_ann", "_ts_us", "_t0")
 
     def __init__(self, tracer: "Tracer", name: str, cat: str, args: dict):
         self.tracer = tracer
@@ -118,12 +143,22 @@ class _Span:
         self.args = args
 
     def __enter__(self):
+        self._ann = TraceAnnotation(PROFILER_PREFIX + self.name, **self.args)
+        self._ann.__enter__()
         self._ts_us = time.time_ns() // 1000
         self._t0 = time.perf_counter()
         return self
 
+    def set_metadata(self, **args) -> None:
+        """Args known only once the span has run (outcome flags, counts),
+        for the stream and for the annotation alike — the same call as on
+        a `NULL_TRACER` span."""
+        self.args.update(args)
+        self._ann.set_metadata(**args)
+
     def __exit__(self, *exc):
         dur_s = time.perf_counter() - self._t0
+        self._ann.__exit__(*exc)
         self.tracer._emit({"name": self.name, "cat": self.cat, "ph": "X",
                            "ts": self._ts_us,
                            "dur": int(dur_s * 1e6),
@@ -141,11 +176,7 @@ class Tracer:
 
     `path` is this process's stream file (use `stream_path` in
     multi-process runs so the launcher can merge). Events accumulate in
-    memory and hit the disk every `flush_every` events and on `close()`.
-    The tracer measures its own cost: `overhead_s` is the cumulative wall
-    time spent inside tracer calls (span bookkeeping + serialization +
-    writes), emitted as a final `tracer_self` counter so the overhead
-    claim in BENCH_obs.json is carried inside the trace itself."""
+    memory and hit the disk every `flush_every` events and on `close()`."""
     enabled = True
 
     def __init__(self, path: str, *, proc_id: int = 0,
@@ -153,7 +184,6 @@ class Tracer:
         self.path = path
         self.proc_id = proc_id
         self.flush_every = flush_every
-        self.overhead_s = 0.0
         self.n_events = 0
         self._buf: List[dict] = []
         self._lock = threading.Lock()
@@ -173,19 +203,15 @@ class Tracer:
         return _Span(self, name, cat, args)
 
     def instant(self, name: str, cat: str = "executor", **args) -> None:
-        t0 = time.perf_counter()
         self._emit({"name": name, "cat": cat, "ph": "i", "s": "p",
                     "ts": time.time_ns() // 1000,
-                    "pid": self.proc_id, "tid": _tid(), "args": args},
-                   t0=t0)
+                    "pid": self.proc_id, "tid": _tid(), "args": args})
 
     def counter(self, name: str, values: Dict[str, float],
                 cat: str = "meter") -> None:
-        t0 = time.perf_counter()
         self._emit({"name": name, "cat": cat, "ph": "C",
                     "ts": time.time_ns() // 1000,
-                    "pid": self.proc_id, "tid": _tid(), "args": values},
-                   t0=t0)
+                    "pid": self.proc_id, "tid": _tid(), "args": values})
 
     def metadata(self, **args) -> None:
         """The run_metadata instant: emitted once per stream by the entry
@@ -194,9 +220,7 @@ class Tracer:
         self.instant(RUN_METADATA, cat="meta", **args)
 
     # -- internals ---------------------------------------------------------
-    def _emit(self, ev: dict, *, t0: Optional[float] = None) -> None:
-        if t0 is None:
-            t0 = time.perf_counter()
+    def _emit(self, ev: dict) -> None:
         with self._lock:
             if self._closed:
                 return
@@ -207,7 +231,6 @@ class Tracer:
                 buf, self._buf = self._buf, []
         if buf is not None:
             self._write(buf)
-        self.overhead_s += time.perf_counter() - t0
 
     def _write(self, events: List[dict]) -> None:
         with open(self.path, "a") as f:
@@ -222,14 +245,9 @@ class Tracer:
             self._write(buf)
 
     def close(self) -> None:
-        """Final flush; appends the tracer's self-accounting counter so
-        the overhead is auditable from the trace alone."""
+        """Final flush; later events are dropped."""
         if self._closed:
             return
-        self.counter("tracer_self",
-                     {"events": self.n_events,
-                      "overhead_us": self.overhead_s * 1e6},
-                     cat="meta")
         with self._lock:
             self._closed = True
             buf, self._buf = self._buf, []

@@ -276,7 +276,7 @@ def run_with_faults(strategy: Strategy, params0, data_fn: Callable,
         for ev in plan.events_at(step):
             # the span covers membership surgery + cache invalidation; the
             # recompile it provokes lands in the NEXT cycle span (its
-            # fresh_compile flag — same attribution as first_cycle_s)
+            # compiles arg — same attribution as first_cycle_s)
             with ex.tracer.span("fault_event", cat="resilience",
                                 kind=ev.kind, step=step,
                                 replica=ev.replica, factor=ev.factor):
@@ -289,7 +289,8 @@ def run_with_faults(strategy: Strategy, params0, data_fn: Callable,
         boundary = plan.next_boundary_after(step)
         if boundary is not None:
             max_len = min(max_len, boundary - step)
-        cycle_plan = strategy.plan_cycle(step, max_len)
+        with ex.phase("control"):
+            cycle_plan = strategy.plan_cycle(step, max_len)
         t0 = time.perf_counter()
         carry, cycle_losses, per_step_metrics = dispatch_planned_cycle(
             ex, carry, cycle_plan, data_fn, lr_fn, n_steps)
@@ -314,14 +315,15 @@ def run_with_faults(strategy: Strategy, params0, data_fn: Callable,
             getattr(strategy, "group_perm", None), t_compute_s)
         losses.extend(cycle_losses)
         metrics_log.extend(per_step_metrics)
-        strategy.observe(cycle_losses)
+        with ex.phase("control"):
+            strategy.observe(cycle_losses)
         step += len(cycle_plan)
         cycle_idx += 1
         if next_ckpt is not None and ckpt_cb is not None and step >= next_ckpt:
-            with ex.tracer.span("checkpoint_save", cat="checkpoint",
-                                step=step):
+            with ex.phase("checkpoint_save", cat="checkpoint", step=step):
                 ckpt_cb(step, carry, losses)
             next_ckpt = (step // ckpt_every + 1) * ckpt_every
+    ex.end_cycle()
 
     final = (placement.finalize_params(strategy, carry)
              if placement is not None else strategy.finalize_params(carry))

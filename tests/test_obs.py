@@ -1,10 +1,10 @@
 """The observability tier (ISSUE 8):
 
   * `obs.trace` unit contract: span/instant/counter events are valid
-    JSONL trace events, the null tracer is free, per-process streams
-    merge timestamp-sorted, Chrome export wraps without loss.
+    JSONL trace events, the null tracer writes nothing, per-process
+    streams merge timestamp-sorted, Chrome export wraps without loss.
   * Executor integration: every dispatched cycle gets a span carrying
-    (steps, per-level sync counts, fresh_compile/fallback flags);
+    (steps, per-level sync counts, compiles/fresh_compile/fallback);
     checkpoint saves get spans; the overlap legs get their own spans.
   * Controller decision events: plateau-driven B/W changes, membership
     flushes, and DCN rescales land in the trace with a `reason` —
@@ -64,20 +64,15 @@ def test_tracer_events_are_valid_jsonl(tmp_path):
     tr.metadata(arch="mlp", param_bytes=123)
     tr.close()
     evs = _events(p)
-    # process_name + 4 events + tracer_self
-    assert len(evs) == 6
+    # process_name + 4 events, and nothing appended by close()
+    assert len(evs) == 5 == tr.n_events
     for ev in evs:
         assert validate_event(ev) is None, ev
     names = [ev["name"] for ev in evs]
-    assert names[0] == "process_name" and names[-1] == "tracer_self"
-    assert RUN_METADATA in names
+    assert names[0] == "process_name" and names[-1] == RUN_METADATA
     span = next(ev for ev in evs if ev["name"] == "cycle")
     assert span["ph"] == "X" and span["dur"] >= 0
     assert span["args"]["steps"] == 3
-    self_acct = evs[-1]["args"]
-    # the self-accounting counter snapshots the count before itself
-    assert self_acct["events"] == tr.n_events - 1
-    assert tr.overhead_s > 0.0
 
 
 def test_tracer_close_is_idempotent_and_final(tmp_path):
@@ -92,8 +87,12 @@ def test_tracer_close_is_idempotent_and_final(tmp_path):
 
 
 def test_null_tracer_is_api_complete_noop():
+    # no stream, but its spans are usable as context managers (profiler
+    # annotations) and take args after the fact like a Tracer's
     with NULL_TRACER.span("cycle", steps=1) as sp:
-        assert sp is NULL_TRACER.span("again")  # shared instance
+        sp.set_metadata(compiles=0)
+        with NULL_TRACER.span("stage"):
+            pass
     NULL_TRACER.instant("x")
     NULL_TRACER.counter("c", {"v": 1.0})
     NULL_TRACER.metadata(a=1)
@@ -507,6 +506,6 @@ def test_build_report_end_to_end(tmp_path):
     rep = tr.build_report(evs)
     assert rep["schema_errors"] == []
     assert rep["summary"]["executor"]["spans"] > 0
-    assert rep["summary"]["_tracer"]["events"] > 0
+    assert "_tracer" not in rep["summary"]
     assert rep["cycle_fit"]["samples"] >= 0
     json.dumps(rep)  # --json output contract

@@ -94,19 +94,6 @@ POLICIES = {
         # analytic model: overlap never prices above the blocking schedule
         "model_step_ratio_overlap_vs_blocking": ("bounds_strict", (None, 1.0)),
     },
-    "BENCH_obs.json": {
-        # the ISSUE 8 headline: tracing costs <= 3% of the tracing-off
-        # wall time (tracer self-accounted overhead vs the untraced leg)
-        "trace_overhead_frac": ("bounds", (None, 0.03)),
-        # the merged 2-process trace is schema-valid Chrome trace JSON
-        "trace_valid": ("exact", 1.0),
-        "trace_events": ("bounds_strict", (0, None)),
-        # spans/events from every layer: executor, schedule, resilience,
-        # checkpoint, comm meters, run metadata
-        "trace_has_required_cats": ("exact", 1.0),
-        # the drift table prices every sync level of the 3-level topology
-        "drift_levels_covered": ("bounds", (2, None)),
-    },
     "BENCH_strategies.json": {
         # the whole registered family ran, stayed finite, and trained
         "n_strategies": ("exact", 6.0),

@@ -7,8 +7,7 @@ Input is a merged run trace written by ``--trace-out`` (or the un-merged
 memory). Three outputs:
 
   * **summary** — event counts and total span time per category
-    (executor / schedule / resilience / checkpoint), plus the tracer's own
-    self-accounted overhead.
+    (executor / schedule / resilience / checkpoint).
   * **Chrome export** (``--chrome out.json``) — wraps the events in a
     ``{"traceEvents": [...]}`` document that chrome://tracing and
     https://ui.perfetto.dev load directly (Open trace file).
@@ -70,15 +69,9 @@ def run_metadata(events: List[dict]) -> Optional[dict]:
 
 
 def summarize(events: List[dict]) -> Dict[str, dict]:
-    """Per-category event counts and total span seconds, plus the
-    tracer_self overhead under the "_tracer" key."""
+    """Per-category event counts and total span seconds."""
     out: Dict[str, dict] = {}
     for ev in events:
-        if ev.get("name") == "tracer_self":
-            agg = out.setdefault("_tracer", {"events": 0, "overhead_s": 0.0})
-            agg["events"] += int(ev["args"].get("events", 0))
-            agg["overhead_s"] += ev["args"].get("overhead_us", 0.0) / 1e6
-            continue
         cat = ev.get("cat", "?")
         agg = out.setdefault(cat, {"events": 0, "spans": 0, "span_s": 0.0})
         agg["events"] += 1
@@ -223,12 +216,8 @@ def print_report(rep: dict, *, out=sys.stdout) -> None:
           f"topology={meta.get('topology') or 'implicit'}")
     p("\nper-category:")
     for cat, agg in sorted(rep["summary"].items()):
-        if cat == "_tracer":
-            p(f"  tracer self-overhead: {agg['overhead_s'] * 1e3:.1f} ms "
-              f"over {agg['events']} events")
-        else:
-            p(f"  {cat:<11} {agg['events']:>5} events  "
-              f"{agg['spans']:>4} spans  {agg['span_s']:8.3f} s")
+        p(f"  {cat:<11} {agg['events']:>5} events  "
+          f"{agg['spans']:>4} spans  {agg['span_s']:8.3f} s")
     fit = rep["cycle_fit"]
     if fit:
         p(f"\ncycle fit: {fit['samples']} clean cycles "
