@@ -192,7 +192,8 @@ def _aux_metrics(metrics, aux_r, mask, n_replicas: int, n_active: int):
 
 
 def gossip_train_step(loss_fn, optimizer, cfg, *, mode: str, shift: int = 1,
-                      n_micro: int = 1, membership=None):
+                      n_micro: int = 1, membership=None,
+                      device_local: bool = False):
     """step(params_R, opt_R, batch_R, lr) -> (params_R, opt_R, metrics).
     `mode` is local | blocking | gossip (shift decoded by the caller)."""
     assert mode in (Mode.LOCAL, Mode.BLOCKING, Mode.GOSSIP), mode
@@ -218,7 +219,7 @@ def gossip_train_step(loss_fn, optimizer, cfg, *, mode: str, shift: int = 1,
             params = blocking_sync(
                 params, wire_format=cfg.wire_format_for(blocking=True),
                 impl=impl, int8_block=blk, use_kernels=kern, mask=mask,
-                deterministic=det)
+                deterministic=det, device_local=device_local)
         loss = _cross_replica_loss(cfg, mask, n_active, loss_r)
         metrics = {"loss": loss, "loss_per_replica": loss_r}
         return params, opt_state, _aux_metrics(
@@ -228,7 +229,8 @@ def gossip_train_step(loss_fn, optimizer, cfg, *, mode: str, shift: int = 1,
 
 
 def easgd_train_step(loss_fn, optimizer, cfg, *, mode: str, alpha: float,
-                     n_micro: int = 1, membership=None):
+                     n_micro: int = 1, membership=None,
+                     device_local: bool = False):
     """step(params_R, opt_R, center_R, batch_R, lr)
         -> (params_R, opt_R, center_R, metrics).
 
@@ -271,7 +273,7 @@ def easgd_train_step(loss_fn, optimizer, cfg, *, mode: str, alpha: float,
             m = replica_mean(
                 params, wire_format=cfg.wire_format_for(blocking=False),
                 impl=impl, int8_block=blk, use_kernels=kern, mask=mask,
-                deterministic=det)
+                deterministic=det, device_local=device_local)
             params = freeze_inactive(lerp(params, center, alpha),
                                      params, mask)
             center = lerp(center, m, beta)
@@ -279,7 +281,7 @@ def easgd_train_step(loss_fn, optimizer, cfg, *, mode: str, alpha: float,
             params = blocking_sync(
                 params, wire_format=cfg.wire_format_for(blocking=True),
                 impl=impl, int8_block=blk, use_kernels=kern, mask=mask,
-                deterministic=det)
+                deterministic=det, device_local=device_local)
             center = jax.tree.map(jnp.array, params)
         loss = _cross_replica_loss(cfg, mask, n_active, loss_r)
         metrics = {"loss": loss, "loss_per_replica": loss_r}
@@ -291,7 +293,7 @@ def easgd_train_step(loss_fn, optimizer, cfg, *, mode: str, alpha: float,
 
 def downpour_train_step(loss_fn, optimizer, cfg, *, mode: str,
                         push_scale: float = 1.0, n_micro: int = 1,
-                        membership=None):
+                        membership=None, device_local: bool = False):
     """step(params_R, opt_R, anchor_R, batch_R, lr)
         -> (params_R, opt_R, anchor_R, metrics).
 
@@ -325,7 +327,7 @@ def downpour_train_step(loss_fn, optimizer, cfg, *, mode: str,
             dmean = replica_mean(
                 delta, wire_format=cfg.wire_format_for(blocking=False),
                 impl=impl, int8_block=blk, use_kernels=kern, mask=mask,
-                deterministic=det)
+                deterministic=det, device_local=device_local)
 
             def apply(a, d):
                 out = (a.astype(jnp.float32)
@@ -341,7 +343,7 @@ def downpour_train_step(loss_fn, optimizer, cfg, *, mode: str,
             params = blocking_sync(
                 params, wire_format=cfg.wire_format_for(blocking=True),
                 impl=impl, int8_block=blk, use_kernels=kern, mask=mask,
-                deterministic=det)
+                deterministic=det, device_local=device_local)
             anchor = jax.tree.map(jnp.array, params)
         loss = _cross_replica_loss(cfg, mask, n_active, loss_r)
         metrics = {"loss": loss, "loss_per_replica": loss_r}
@@ -405,7 +407,8 @@ class GossipStrategy(PeriodicStrategy):
         raw = gossip_train_step(self.loss_fn, self.optimizer, self.cfg,
                                 mode=base, shift=max(shift, 1),
                                 n_micro=self.n_micro,
-                                membership=self._membership)
+                                membership=self._membership,
+                                device_local=self._device_local)
 
         def step(carry, batch, lr):
             params, opt_state = carry
@@ -449,7 +452,8 @@ class EasgdStrategy(PeriodicStrategy):
         raw = easgd_train_step(self.loss_fn, self.optimizer, self.cfg,
                                mode=base, alpha=self.alpha,
                                n_micro=self.n_micro,
-                               membership=self._membership)
+                               membership=self._membership,
+                               device_local=self._device_local)
 
         def step(carry, batch, lr):
             params, opt_state, center = carry
@@ -487,7 +491,8 @@ class DownpourStrategy(PeriodicStrategy):
         raw = downpour_train_step(self.loss_fn, self.optimizer, self.cfg,
                                   mode=base, push_scale=self.push_scale,
                                   n_micro=self.n_micro,
-                                  membership=self._membership)
+                                  membership=self._membership,
+                                  device_local=self._device_local)
 
         def step(carry, batch, lr):
             params, opt_state, anchor = carry

@@ -26,7 +26,10 @@ vmap, and syncs hit the levels like this:
     buffer per dtype, so a sync at any level is ONE collective per arena
     regardless of leaf count (Horovod-style tensor fusion), with the wire
     tier (f32 | bf16 | int8 block-scaled) applied to the whole arena at
-    once (kernels/comm_kernels.py).
+    once (kernels/comm_kernels.py). Where the replica axis never leaves
+    one device there is no collective to coalesce, and the outermost mean
+    is taken leaf by leaf with the same arithmetic (`replica_mean`'s
+    `device_local`).
 
 Step variants (selected by the host-side controllers in core/schedule.py,
 mirroring the MPI process flow of paper Fig. 5; static per-variant
@@ -230,23 +233,49 @@ def _arena_mean(arena, wire_format: str, *, int8_block: int,
                                      deterministic).astype(arena.dtype)
 
 
+def _leaf_mean(x, wire_dtype, mask, deterministic: bool):
+    """Mean over the leading replica axis of one leaf, broadcast back to
+    its shape: `_arena_mean`'s arithmetic on one leaf. A floating leaf is
+    reduced in `wire_dtype` (None: its own dtype) with the scale applied
+    in that dtype; any other leaf in f32 and rounded back, as the arena
+    rounds it (an int-dtype reduce would truncate the 1/R scale to 0)."""
+    if jnp.issubdtype(x.dtype, jnp.floating):
+        m = flatbuf.masked_axis0_mean(x.astype(wire_dtype or x.dtype), mask,
+                                      deterministic)
+    else:
+        m = jnp.round(flatbuf.masked_axis0_mean(x.astype(jnp.float32), mask,
+                                                deterministic))
+    return jnp.broadcast_to(m.astype(x.dtype), x.shape)
+
+
 def replica_mean_per_leaf(tree, wire_dtype=None, mask=None,
                           deterministic: bool = False):
-    """Legacy per-leaf exchange: one cross-pod all-reduce PER LEAF. Kept as
-    the equivalence oracle and microbenchmark baseline for the fused arena
-    path (`replica_mean`); f32/bf16 wire only. `mask` applies the same
-    membership weighting as the fused path."""
-    def leaf(x):
-        wd = jnp.dtype(wire_dtype or x.dtype)
-        m = flatbuf.masked_axis0_mean(x.astype(wd), mask, deterministic)
-        return jnp.broadcast_to(m, x.shape).astype(x.dtype)
-    return jax.tree.map(leaf, tree)
+    """Per-leaf exchange: one axis-0 reduction PER LEAF, bit-identical to
+    the arena mean for the f32/bf16 wire. `replica_mean` takes it where the
+    replica axis never leaves the device (no collective to coalesce, so
+    packing the arena is pure copying) and for `impl="per_leaf"`, the
+    equivalence oracle and microbenchmark baseline of the fused path.
+    `mask` applies the same membership weighting as the fused path."""
+    return jax.tree.map(
+        lambda x: _leaf_mean(x, wire_dtype, mask, deterministic), tree)
+
+
+def leafwise_exchange(wire_format, *, impl: str, use_kernels: bool,
+                      device_local: bool) -> bool:
+    """Whether `replica_mean` takes the mean leaf by leaf rather than over
+    the packed arena: for `impl="per_leaf"`, and where the replica axis
+    lies in one program on one device (`device_local`), except for the
+    int8 wire (its per-block scales cross leaf boundaries) and the Pallas
+    exchange kernels (which run over the arena). `wire_format` None is
+    the per-phase f32/bf16 choice."""
+    return impl == "per_leaf" or (device_local and wire_format != "int8"
+                                  and not use_kernels)
 
 
 def replica_mean(tree, wire_dtype=None, *, wire_format=None,
                  impl: str = "fused", int8_block: int = 256,
                  use_kernels: bool = False, mask=None,
-                 deterministic: bool = False):
+                 deterministic: bool = False, device_local: bool = False):
     """Mean over the leading replica axis, broadcast back.
 
     Default path packs the pytree into one contiguous arena per dtype
@@ -256,12 +285,18 @@ def replica_mean(tree, wire_dtype=None, *, wire_format=None,
     one-collective-per-leaf reference path. `wire_dtype` is the legacy
     spelling (None = uncompressed, jnp.bfloat16 = 16-bit packaging).
     `mask` (normalized membership tuple, or None = all active) restricts
-    the mean to active replicas — the elastic-membership exchange."""
+    the mean to active replicas — the elastic-membership exchange.
+
+    `device_local=True` says the replica axis lies in one program on one
+    device, so no collective can be coalesced: the mean is then taken
+    leaf by leaf (`leafwise_exchange`), bit-identical to the arena's,
+    without the arena's pack, unpack and zero-filled (R, N) buffer."""
     wf = _wire_format_from(wire_dtype, wire_format)
-    if impl == "per_leaf":
-        if wf == "int8":
-            raise ValueError("int8 wire format requires the fused arena "
-                             "exchange (impl='fused')")
+    if impl == "per_leaf" and wf == "int8":
+        raise ValueError("int8 wire format requires the fused arena "
+                         "exchange (impl='fused')")
+    if leafwise_exchange(wf, impl=impl, use_kernels=use_kernels,
+                         device_local=device_local):
         return replica_mean_per_leaf(
             tree, jnp.bfloat16 if wf == "bf16" else None, mask=mask,
             deterministic=deterministic)
@@ -437,16 +472,18 @@ def freeze_inactive(new_tree, old_tree, mask):
 def global_send(params, *, compress: bool = False, wire_format=None,
                 impl: str = "fused", int8_block: int = 256,
                 use_kernels: bool = False, mask=None,
-                deterministic: bool = False):
+                deterministic: bool = False, device_local: bool = False):
     """Snapshot + start global exchange: returns the in-flight buffer
     (replica mean of current params, one copy per replica). The wire tier
     comes from `wire_format` (or legacy compress=True -> bf16,
     beyond-paper for the non-blocking path, see DasoConfig). `mask`
-    restricts the mean to active replicas (elastic membership)."""
+    restricts the mean to active replicas (elastic membership);
+    `device_local` as in `replica_mean`."""
     wf = wire_format or ("bf16" if compress else "f32")
     return replica_mean(params, wire_format=wf, impl=impl,
                         int8_block=int8_block, use_kernels=use_kernels,
-                        mask=mask, deterministic=deterministic)
+                        mask=mask, deterministic=deterministic,
+                        device_local=device_local)
 
 
 def global_receive_per_leaf(params, inflight, *, staleness: int,
@@ -518,14 +555,16 @@ def global_receive(params, inflight, *, staleness: int, global_world,
 def blocking_sync(params, *, compress: bool = True, wire_format=None,
                   impl: str = "fused", int8_block: int = 256,
                   use_kernels: bool = False, mask=None,
-                  deterministic: bool = False):
+                  deterministic: bool = False, device_local: bool = False):
     """Synchronous global average (warm-up / cool-down), with the paper's
     16-bit transfer compression (or the tier in `wire_format`). `mask`
-    restricts the average to active replicas and freezes dropped rows."""
+    restricts the average to active replicas and freezes dropped rows;
+    `device_local` as in `replica_mean`."""
     wf = wire_format or ("bf16" if compress else "f32")
     synced = replica_mean(params, wire_format=wf, impl=impl,
                           int8_block=int8_block, use_kernels=use_kernels,
-                          mask=mask, deterministic=deterministic)
+                          mask=mask, deterministic=deterministic,
+                          device_local=device_local)
     return freeze_inactive(synced, params, mask)
 
 
@@ -634,7 +673,7 @@ def daso_train_step(loss_fn: Callable, optimizer: Optimizer, cfg: DasoConfig,
                     spmd_axis_name: Optional[str] = None, n_micro: int = 1,
                     membership=None,
                     inner_syncs: Tuple[Tuple[str, int], ...] = (),
-                    group_perm=None):
+                    group_perm=None, device_local: bool = False):
     """Build one statically-specialized DASO step function.
 
     step(params_R, opt_R, inflight, batch_R, lr)
@@ -663,7 +702,12 @@ def daso_train_step(loss_fn: Callable, optimizer: Optimizer, cfg: DasoConfig,
     `group_perm` (normalize_group_perm) statically regroups the replicas
     for every inner-level sync — the straggler-aware reshuffle. Like the
     membership mask it is baked into the compiled step; changing it means
-    new variants (DasoStrategy.set_group_permutation)."""
+    new variants (DasoStrategy.set_group_permutation).
+
+    `device_local` (static, `replica_mean`) says the replica axis lies in
+    this program on one device: the outermost replica means (send,
+    blocking, hard_avg) are then taken leaf by leaf instead of over the
+    packed arena (DasoStrategy.set_device_local)."""
     assert mode in MODES, mode
     lstep = local_step(loss_fn, optimizer, spmd_axis_name=spmd_axis_name,
                        n_micro=n_micro)
@@ -701,17 +745,18 @@ def daso_train_step(loss_fn: Callable, optimizer: Optimizer, cfg: DasoConfig,
             inflight = global_send(
                 params, wire_format=cfg.wire_format_for(blocking=False),
                 impl=impl, int8_block=blk, use_kernels=kern, mask=mask,
-                deterministic=det)
+                deterministic=det, device_local=device_local)
         elif mode == "blocking":
             params = blocking_sync(
                 params, wire_format=cfg.wire_format_for(blocking=True),
                 impl=impl, int8_block=blk, use_kernels=kern, mask=mask,
-                deterministic=det)
+                deterministic=det, device_local=device_local)
         elif mode == "hard_avg":
             with jax.named_scope("repro.exchange.blocking"):
                 params = freeze_inactive(
                     replica_mean(params, impl=impl, mask=mask,
-                                 deterministic=det), params, mask)
+                                 deterministic=det,
+                                 device_local=device_local), params, mask)
         # the reported loss feeds the plateau controller on the host, so
         # it needs the same transport invariance as the exchanges
         loss = _cross_replica_loss(cfg, mask, n_active, loss_r)
@@ -735,7 +780,7 @@ def daso_overlap_step(loss_fn: Callable, optimizer: Optimizer,
                       spmd_axis_name: Optional[str] = None, n_micro: int = 1,
                       membership=None,
                       inner_syncs: Tuple[Tuple[str, int], ...] = (),
-                      group_perm=None):
+                      group_perm=None, device_local: bool = False):
     """Build one step variant of the double-buffered overlap schedule
     (DasoConfig.overlap == "one_cycle"). The carry grows a fourth slot —
     the `pending` snapshot arena awaiting its exchange:
@@ -764,7 +809,8 @@ def daso_overlap_step(loss_fn: Callable, optimizer: Optimizer,
     The merge lands AFTER the step's local update (off-mode `receive`
     merges before it): the exchange result arrives at the cycle boundary,
     which is exactly when the macro executor joins the in-flight
-    collective with the computed params."""
+    collective with the computed params. `device_local` as in
+    `daso_train_step`."""
     assert mode in OV_MODES, mode
     lstep = local_step(loss_fn, optimizer, spmd_axis_name=spmd_axis_name,
                        n_micro=n_micro)
@@ -798,7 +844,7 @@ def daso_overlap_step(loss_fn: Callable, optimizer: Optimizer,
             inflight = global_send(
                 pending, wire_format=cfg.wire_format_for(blocking=False),
                 impl=impl, int8_block=blk, use_kernels=kern, mask=mask,
-                deterministic=det)
+                deterministic=det, device_local=device_local)
             params = global_receive(params, inflight, staleness=staleness,
                                     extra_staleness=extra_staleness,
                                     global_world=p_eff, impl=impl,
@@ -808,7 +854,7 @@ def daso_overlap_step(loss_fn: Callable, optimizer: Optimizer,
             params = blocking_sync(
                 params, wire_format=cfg.wire_format_for(blocking=True),
                 impl=impl, int8_block=blk, use_kernels=kern, mask=mask,
-                deterministic=det)
+                deterministic=det, device_local=device_local)
         loss = _cross_replica_loss(cfg, mask, n_active, loss_r)
         metrics = {"loss": loss, "loss_per_replica": loss_r}
         for k, v in aux_r.items():

@@ -48,7 +48,7 @@ from repro.core import flatbuf
 from repro.core.daso import (DasoConfig, _cross_replica_loss,
                              daso_overlap_compute_step, daso_overlap_step,
                              daso_train_step, dereplicate_params,
-                             global_receive, global_send,
+                             global_receive, global_send, leafwise_exchange,
                              normalize_group_perm, replica_divergence,
                              replicate_params, sync_train_step)
 from repro.core.schedule import (DasoController, Mode, is_ov_mode, join_mode,
@@ -133,6 +133,10 @@ class Strategy:
     reference) drive strategies only through this interface.
     """
     name = "?"
+    #: how the step variants take the replica mean ("leafwise" | "arena"),
+    #: for the executor's compile trace and the run summary; None for a
+    #: strategy without a replica exchange
+    exchange_layout: Optional[str] = None
 
     def __init__(self, loss_fn: Callable, optimizer: Optimizer,
                  cfg: Optional[DasoConfig] = None, *,
@@ -215,6 +219,7 @@ class DasoStrategy(Strategy):
         self._membership = flatbuf.normalize_membership(
             membership, cfg.n_replicas)
         self._group_perm = None
+        self._device_local = False
 
     # -- elastic membership ------------------------------------------------
     @property
@@ -260,6 +265,31 @@ class DasoStrategy(Strategy):
         delays only its own group's inner syncs."""
         self._group_perm = normalize_group_perm(perm, self.cfg.n_replicas)
         self._steps.clear()
+
+    # -- where the replica axis lives --------------------------------------
+    def set_device_local(self, local: bool) -> bool:
+        """Say whether the replica axis lies in one program on one device.
+        The executor says it, since it holds the placement: without one
+        there is no collective to coalesce, and the replica means are taken
+        leaf by leaf (core/daso.py `replica_mean`); a strategy never told
+        keeps the packed arena, whose one collective per sync a replica
+        axis across devices needs. Same contract as `set_membership`: this
+        drops the step-fn cache. Returns whether the setting changed, so
+        the caller knows to `invalidate()` cycles compiled before."""
+        local = bool(local)
+        if local == self._device_local:
+            return False
+        self._device_local = local
+        self._steps.clear()
+        return True
+
+    @property
+    def exchange_layout(self) -> str:
+        cfg = self.cfg
+        return "leafwise" if leafwise_exchange(
+            cfg.wire_format, impl=cfg.exchange_impl,
+            use_kernels=cfg.exchange_kernels,
+            device_local=self._device_local) else "arena"
 
     @property
     def overlap(self) -> bool:
@@ -309,7 +339,8 @@ class DasoStrategy(Strategy):
                                n_micro=self.n_micro,
                                membership=self._membership,
                                inner_syncs=self._inner_syncs_of(inner),
-                               group_perm=self._group_perm)
+                               group_perm=self._group_perm,
+                               device_local=self._device_local)
 
     def _build_raw_overlap(self, mode, staleness):
         """Overlap counterpart of `_build_raw`: 4-slot carry, OV_* tokens,
@@ -322,7 +353,8 @@ class DasoStrategy(Strategy):
                                  n_micro=self.n_micro,
                                  membership=self._membership,
                                  inner_syncs=self._inner_syncs_of(inner),
-                                 group_perm=self._group_perm)
+                                 group_perm=self._group_perm,
+                                 device_local=self._device_local)
 
     def build_step(self, mode, staleness):
         if mode.startswith(OVERLAP_COMPUTE_PREFIX):
@@ -392,14 +424,14 @@ class DasoStrategy(Strategy):
         """pending -> inflight: the ONE outer-level collective of an
         overlap cycle, compiled as its own program so the executor can put
         it in flight before the compute program."""
-        cfg, mask = self.cfg, self._membership
+        cfg, mask, local = self.cfg, self._membership, self._device_local
 
         def exchange(pending):
             return global_send(
                 pending, wire_format=cfg.wire_format_for(blocking=False),
                 impl=cfg.exchange_impl, int8_block=cfg.int8_block,
                 use_kernels=cfg.exchange_kernels, mask=mask,
-                deterministic=cfg.deterministic_reduce)
+                deterministic=cfg.deterministic_reduce, device_local=local)
 
         return exchange
 
@@ -589,9 +621,6 @@ class MacroCycleExecutor:
         self.max_cycle_len = max_cycle_len
         self.donate = donate
         self.tail_fallback = tail_fallback
-        # optional launch.distributed.MeshPlacement: batches staged onto
-        # the global topology mesh instead of the local default device
-        self.placement = placement
         # optional resilience.runtime.HealthMonitor: every completed cycle
         # is a progress report (heartbeat step + watchdog deadline push) —
         # the hook that lets a supervised run detect a peer death wedging
@@ -612,6 +641,24 @@ class MacroCycleExecutor:
         # jitted overlap exchange/merge programs ("exchange", or
         # ("merge", S, E)); dropped by invalidate() with everything else
         self._ov_fns: Dict[object, Callable] = {}
+        self.placement = placement
+
+    @property
+    def placement(self):
+        """Optional launch.distributed.MeshPlacement: carry and batches on
+        the global topology mesh instead of the local default device."""
+        return self._placement
+
+    @placement.setter
+    def placement(self, placement) -> None:
+        # a placement may spread the replica axis over devices, which the
+        # arena exchange's one collective per sync is for; without one the
+        # replica axis stays in this program on one device
+        self._placement = placement
+        tell = getattr(self.strategy, "set_device_local", None)
+        if (tell is not None and tell(placement is None)
+                and (self._programs or self._per_step or self._ov_fns)):
+            self.invalidate()
 
     # -- compilation -------------------------------------------------------
     @property
@@ -627,7 +674,8 @@ class MacroCycleExecutor:
             # `compiles` arg counts it)
             self.tracer.instant("compile", cat="executor",
                                 shape_len=len(shape),
-                                modes=[m for m, _ in shape])
+                                modes=[m for m, _ in shape],
+                                exchange=self.strategy.exchange_layout)
         return self._programs[shape]
 
     def invalidate(self) -> int:

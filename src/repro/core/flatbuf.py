@@ -101,7 +101,13 @@ def pack(tree, layout: ArenaLayout) -> Dict[str, jnp.ndarray]:
     bit-identical to the source leaves. (DUS instead of concatenate: XLA
     CPU lowers a concatenate of reshaped operands to a pathological
     per-element fusion, measured 4-30x slower than the same copies as
-    slice updates; on TPU both are plain DMA.)"""
+    slice updates.) On the TPU the copies are not free either: they run at
+    HBM speed. For the replica mean of two 1-layer Mistral-7B replicas on
+    one v5e, the compiler counts 29.6 GB of traffic with the arena against
+    7.9 GB leaf by leaf, and a chip profile put 20 of the arena send's
+    39 ms in the pack and unpack. So `core/daso.replica_mean` packs only
+    where the replica axis may span devices (one collective per sync), for
+    the int8 wire and for the exchange kernels."""
     leaves = jax.tree.leaves(tree)
     nb = len(layout.batch_shape)
     single = {slot.arena: layout.arena_sizes[slot.arena] == slot.size

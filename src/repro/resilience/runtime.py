@@ -149,14 +149,16 @@ class HealthMonitor:
     def close(self) -> None:
         """Normal shutdown: disarm the watchdog, write a final beat."""
         self._stop.set()
+        # the beat thread first: a beat of its own in flight would land
+        # after the final one and leave a stale phase behind
+        if self._thread is not None:
+            self._thread.join(timeout=2 * self.cfg.hb_interval + 1)
         with self._lock:
             self._phase = "done"
         self._write()
         if self.tracer is not None:
             self.tracer.instant("phase", cat="resilience", phase="done",
                                 epoch=self.cfg.epoch)
-        if self._thread is not None:
-            self._thread.join(timeout=2 * self.cfg.hb_interval + 1)
 
     # -- internals ---------------------------------------------------------
     def _write(self) -> None:

@@ -323,6 +323,7 @@ def run_training(loss_fn: Callable, params0, data_fn: Callable,
         disp = (f" dispatches={stats.dispatches}/{cfg.n_steps}"
                 if stats is not None else "")
         wire = (f" wire={cfg.wire_format or 'auto'}/{cfg.exchange_impl}"
+                f" exchange={strategy.exchange_layout}"
                 if cfg.strategy != "sync" else "")
         log(f"[train] strategy={cfg.strategy} steps={cfg.n_steps} "
             f"final_loss={result.final_loss:.4f} "

@@ -105,8 +105,14 @@ def test_fused_arena_training_matches_per_leaf(wire_format):
         strat = make_strategy("daso", loss_fn, opt, dcfg,
                               controller=DasoController(dcfg,
                                                         loss_window=10))
+        ex = MacroCycleExecutor(strat)
+        # without a placement the executor makes the exchange leaf-wise;
+        # the fused side is the packed arena, as a mesh placement has it
+        strat.set_device_local(False)
+        assert strat.exchange_layout == ("arena" if exchange_impl == "fused"
+                                         else "leafwise")
         return run_compiled_training(strat, params0, daso_data,
-                                     constant_lr(0.1), n_steps)
+                                     constant_lr(0.1), n_steps, executor=ex)
 
     fused, per_leaf = run("fused"), run("per_leaf")
     np.testing.assert_allclose(np.asarray(fused.losses, np.float32),
@@ -116,6 +122,71 @@ def test_fused_arena_training_matches_per_leaf(wire_format):
                     jax.tree.leaves(per_leaf.params)):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    rtol=2e-5, atol=1e-6)
+
+
+@pytest.mark.parametrize("wire_format", ["f32", "bf16"])
+def test_leafwise_training_is_bit_identical_to_arena(wire_format):
+    """An executor without a placement (the replica axis on one device)
+    trains with the leaf-wise replica mean, and every loss and parameter
+    is the same bits as with the packed arena."""
+    key = jax.random.PRNGKey(7)
+    params0, loss_fn, daso_data = _multi_leaf_problem(key)
+    opt = sgd(momentum=0.9, weight_decay=1e-4)
+    n_steps = 24
+
+    def run(layout):
+        dcfg = DasoConfig(n_replicas=2, global_world=8, b_max=4,
+                          warmup_steps=4, cooldown_steps=4,
+                          total_steps=n_steps, wire_format=wire_format)
+        strat = make_strategy("daso", loss_fn, opt, dcfg,
+                              controller=DasoController(dcfg,
+                                                        loss_window=10))
+        ex = MacroCycleExecutor(strat)
+        if layout == "arena":
+            strat.set_device_local(False)
+        assert strat.exchange_layout == layout
+        return run_compiled_training(strat, params0, daso_data,
+                                     constant_lr(0.1), n_steps, executor=ex)
+
+    arena, leafwise = run("arena"), run("leafwise")
+    assert arena.losses == leafwise.losses
+    for a, b in zip(jax.tree.leaves(arena.params),
+                    jax.tree.leaves(leafwise.params)):
+        assert np.asarray(a).tobytes() == np.asarray(b).tobytes()
+
+
+def _packs_an_arena(strat) -> bool:
+    """Whether the strategy's send step packs the flat-buffer arena (its
+    `dynamic_update_slice` writes) on the multi-leaf problem."""
+    params0, _, daso_data = _multi_leaf_problem(jax.random.PRNGKey(0))
+    jaxpr = jax.make_jaxpr(strat.step_fn(Mode.SEND, 1))(
+        strat.init_carry(params0), daso_data(0), jnp.float32(0.1))
+    return "dynamic_update_slice" in str(jaxpr)
+
+
+@pytest.mark.parametrize("knobs,told,layout", [
+    ({}, False, "arena"),
+    ({}, True, "leafwise"),
+    ({"wire_format": "int8"}, True, "arena"),
+    ({"exchange_kernels": True}, True, "arena"),
+])
+def test_exchange_layout_follows_the_placement(knobs, told, layout):
+    """An executor without a placement makes the replica mean leaf-wise;
+    a strategy never told keeps the arena, and so do the int8 wire and
+    the exchange kernels. Giving the executor a placement later restores
+    the arena and drops what was compiled before."""
+    _, loss_fn, _ = _multi_leaf_problem(jax.random.PRNGKey(0))
+    dcfg = DasoConfig(n_replicas=2, global_world=8, b_max=4, **knobs)
+    strat = make_strategy("daso", loss_fn, sgd(momentum=0.9), dcfg)
+    ex = MacroCycleExecutor(strat) if told else None
+    assert strat.exchange_layout == layout
+    assert _packs_an_arena(strat) == (layout == "arena")
+    if ex is not None:
+        ex.program_for(((Mode.SEND, 1),))
+        ex.placement = object()   # stands for a MeshPlacement
+        assert strat.exchange_layout == "arena"
+        assert ex.cached_shapes == [] and ex.stats.invalidations == 1
+        assert _packs_an_arena(strat)
 
 
 def test_int8_wire_training_converges():

@@ -64,6 +64,37 @@ def test_pack_unpack_roundtrip_property(specs, batch_dims):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
+_MEAN_LEAF_SPECS = [("float32", (3, 4)), ("float32", (7,)),
+                    ("bfloat16", (5, 3)), ("bfloat16", (8,))]
+
+
+@given(st.lists(st.sampled_from(_MEAN_LEAF_SPECS), min_size=1, max_size=5),
+       st.sampled_from([2, 3, 4]), st.sampled_from(["f32", "bf16"]),
+       st.integers(0, 14), st.booleans())
+@settings(max_examples=20, deadline=None)
+def test_leafwise_mean_is_bit_identical_to_arena_property(
+        specs, r, wire_format, mask_bits, deterministic):
+    """The replica mean taken leaf by leaf (`device_local`, the one-device
+    program) gives the same bits as the packed-arena mean: f32 and bf16
+    leaves plus an int32 leaf, f32 and bf16 wire, with and without a
+    membership mask, both reduction tiers."""
+    from repro.core.daso import replica_mean
+    tree = _make_tree(list(specs) + [("int32", (6,))], batch_shape=(r,))
+    mask = tuple(float(mask_bits >> i & 1) for i in range(r))
+    mask = mask if 0 < sum(mask) < r else None
+
+    def mean(device_local):
+        return jax.jit(lambda t: replica_mean(
+            t, wire_format=wire_format, mask=mask,
+            deterministic=deterministic, device_local=device_local))(tree)
+
+    arena, leafwise = mean(False), mean(True)
+    for k, x in tree.items():
+        a, b = np.asarray(arena[k]), np.asarray(leafwise[k])
+        assert a.dtype == b.dtype == x.dtype and a.shape == b.shape, k
+        assert a.tobytes() == b.tobytes(), (k, wire_format, mask)
+
+
 def test_layout_static_offsets():
     tree = {"a": jnp.zeros((2, 3)), "b": jnp.zeros((5,)),
             "c": jnp.zeros((4,), jnp.int32)}

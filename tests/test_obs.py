@@ -189,6 +189,8 @@ def test_executor_cycle_spans_carry_sync_counts(tmp_path):
     compiles = [ev for ev in evs if ev["name"] == "compile"]
     assert len(compiles) == result.executor_stats.compiles
     assert 1 <= fresh <= len(compiles)
+    # no placement: the replica axis stays on one device, mean leaf-wise
+    assert {ev["args"]["exchange"] for ev in compiles} == {"leafwise"}
     for ev in evs:
         assert validate_event(ev) is None, ev
 

@@ -1,5 +1,6 @@
-"""The main-path Pallas kernels compile for a TPU v5e at real widths, and
-the four-chip DASO cycle keeps its carry's shardings.
+"""The main-path Pallas kernels compile for a TPU v5e at real widths, the
+one-chip global send skips the packed arena's traffic, and the four-chip
+DASO cycle keeps its carry's shardings.
 
 Nothing runs: each kernel is lowered and compiled for a v5e chip that is
 described, not attached, which is what catches tiling and VMEM refusals
@@ -104,6 +105,33 @@ def test_flash_attention_compiles_for_v5e(one_chip, model):
                               sharding=one_chip)
     fn = functools.partial(ops.flash_attention, interpret=False)
     assert "tpu_custom_call" in _compile_text(fn, q, kv, kv)
+
+
+def test_leafwise_send_reads_half_the_arena_bytes(one_chip, model):
+    """The global send of 2 replicas on one v5e, compiled both ways: taken
+    leaf by leaf it accesses at most half the bytes of the packed-arena
+    send, and holds no temporary of the (R, N) arena's size."""
+    from repro.core.daso import global_send
+    shapes = jax.eval_shape(lambda: init_params(model,
+                                                jax.random.PRNGKey(0)))
+    tree = jax.tree.map(lambda s: jax.ShapeDtypeStruct(
+        (N_REPLICAS,) + s.shape, s.dtype, sharding=one_chip), shapes)
+    arena_bytes = sum(x.size * x.dtype.itemsize
+                      for x in jax.tree.leaves(tree))
+
+    def compiled(device_local):
+        return jax.jit(functools.partial(
+            global_send, device_local=device_local)).lower(tree).compile()
+
+    def bytes_accessed(c):
+        cost = c.cost_analysis()
+        return (cost[0] if isinstance(cost, list) else cost)[
+            "bytes accessed"]
+
+    arena, leafwise = compiled(False), compiled(True)
+    assert bytes_accessed(leafwise) <= bytes_accessed(arena) / 2
+    assert arena.memory_analysis().temp_size_in_bytes >= arena_bytes
+    assert leafwise.memory_analysis().temp_size_in_bytes < arena_bytes
 
 
 def test_mesh_cycle_keeps_the_carry_sharding(topo):
