@@ -30,7 +30,10 @@ class MoEConfig:
     top_k: int
     d_ff: int                     # per-expert hidden size
     n_shared_experts: int = 0     # dense "shared expert" branch (DeepSeek-style)
-    capacity_factor: float = 1.25
+    # per-expert capacity = group tokens x top_k x capacity_factor / experts;
+    # None, or a factor at which capacity cannot bind (see `dropless`),
+    # routes every (token, slot) pair: the dropless sorted path
+    capacity_factor: Optional[float] = 1.25
     # routing group length (GShard "groups"): capacity is allocated per
     # group of this many tokens, bounding the (G, E, C) dispatch tensor to
     # O(group_size^2 * top_k / n_experts) instead of O(seq_len^2 ...).
@@ -41,6 +44,15 @@ class MoEConfig:
     sharding: str = "expert"
     router_z_loss: float = 1e-3
     load_balance_loss: float = 1e-2
+
+    @property
+    def dropless(self) -> bool:
+        """True when capacity cannot bind: no factor, or one at which each
+        expert's capacity holds every token of the group (the top-k
+        experts of a token are distinct, so an expert gets at most one
+        pair per token)."""
+        return (self.capacity_factor is None
+                or self.capacity_factor * self.top_k >= self.n_experts)
 
 
 @dataclass(frozen=True)
@@ -88,6 +100,14 @@ class ArchConfig:
     param_dtype: str = "bfloat16"
     compute_dtype: str = "bfloat16"
     source: str = ""                # citation bracket from the assignment
+    # Granite-style scalar multipliers; None leaves the path as it is:
+    # token embeddings x embedding_multiplier, attention scores x
+    # attention_multiplier (in place of head_dim ** -0.5), each residual
+    # branch x residual_multiplier, logits / logits_scaling
+    embedding_multiplier: Optional[float] = None
+    attention_multiplier: Optional[float] = None
+    residual_multiplier: Optional[float] = None
+    logits_scaling: Optional[float] = None
 
     # -- derived ----------------------------------------------------------
     @property

@@ -233,14 +233,29 @@ def _arena_mean(arena, wire_format: str, *, int8_block: int,
                                      deterministic).astype(arena.dtype)
 
 
+def _stored_f32(x):
+    """A bf16 array's values in f32, read from its bits: the values as
+    stored. Where the TPU compiler fuses a leaf's optimizer update with
+    the replica mean, a plain `astype` lets it sum the update before its
+    rounding to bf16 (the convert pair is elided as excess precision, and
+    `reduce_precision` is simplified away with it): on Granite-3.0's first
+    step 5% of the K/V projections' in-flight elements differed from the
+    mean of the replicas' stored parameters. The bits cannot be elided."""
+    bits = jax.lax.bitcast_convert_type(x, jnp.uint16).astype(jnp.uint32)
+    return jax.lax.bitcast_convert_type(bits << 16, jnp.float32)
+
+
 def _leaf_mean(x, wire_dtype, mask, deterministic: bool):
     """Mean over the leading replica axis of one leaf, broadcast back to
     its shape: `_arena_mean`'s arithmetic on one leaf. A floating leaf is
     reduced in `wire_dtype` (None: its own dtype) with the scale applied
     in that dtype; any other leaf in f32 and rounded back, as the arena
-    rounds it (an int-dtype reduce would truncate the 1/R scale to 0)."""
+    rounds it (an int-dtype reduce would truncate the 1/R scale to 0).
+
+    A bf16 leaf enters the reduction through its bits (`_stored_f32`)."""
     if jnp.issubdtype(x.dtype, jnp.floating):
-        m = flatbuf.masked_axis0_mean(x.astype(wire_dtype or x.dtype), mask,
+        w = _stored_f32(x) if x.dtype == jnp.bfloat16 else x
+        m = flatbuf.masked_axis0_mean(w.astype(wire_dtype or x.dtype), mask,
                                       deterministic)
     else:
         m = jnp.round(flatbuf.masked_axis0_mean(x.astype(jnp.float32), mask,

@@ -258,6 +258,19 @@ def _phase_line(phase_s, scale: float) -> str:
     return " ".join(f"{k}={v * 1e3 * scale:.3f}" for k, v in phase_s.items())
 
 
+# step metrics of the expert layer (models/moe.py), taken at their largest
+# over the run: the share of (token, slot) pairs dropped and the busiest
+# expert's rows over the mean
+EXPERT_METRICS = ("moe_drop_frac", "moe_load_max")
+
+
+def expert_counters(metrics) -> dict:
+    """{name: largest value over the steps} of the expert layer's step
+    metrics; empty for a model without experts."""
+    return {k: max(m[k] for m in metrics) for k in EXPERT_METRICS
+            if metrics and k in metrics[0]}
+
+
 def main():
     enable_compile_cache()
     ap = build_parser()
@@ -522,6 +535,13 @@ def main():
             say("[train] executor host phases, ms per cycle: "
                 + _phase_line(s.phase_s, 1.0 / s.cycles)
                 + "; slowest cycle: " + _phase_line(s.slowest_cycle_s, 1.0))
+
+    moe = expert_counters(result.metrics)
+    if moe:
+        say("[train] expert layer, largest over steps: "
+            + " ".join(f"{k}={v:.4g}" for k, v in moe.items()))
+        if tracer is not None:
+            tracer.counter("expert_layer", moe)
 
     comm_rows = None
     if tracer is not None and result.controller is not None:

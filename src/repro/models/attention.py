@@ -28,18 +28,19 @@ def _pick_chunk(seq: int, target: int) -> int:
 
 
 def multihead_attention(q, k, v, *, causal: bool = True, window: int = 0,
-                        q_chunk: int = 1024):
+                        q_chunk: int = 1024, scale=None):
     """q (B,Sq,Hq,D); k,v (B,Sk,K,D); GQA via grouped einsum. Returns (B,Sq,Hq,D).
 
     Assumes q and k cover the same token range starting at position 0
     (training / prefill). window > 0 restricts attention to the last `window`
-    positions (inclusive of self).
+    positions (inclusive of self). scale: of the scores (None: D ** -0.5).
     """
     B, Sq, Hq, D = q.shape
     K = k.shape[2]
     G = Hq // K
     qg = q.reshape(B, Sq, K, G, D)
-    scale = D ** -0.5
+    if scale is None:
+        scale = D ** -0.5
     C = _pick_chunk(Sq, q_chunk)
     outs = []
     for qc in range(0, Sq, C):
@@ -68,7 +69,8 @@ def multihead_attention(q, k, v, *, causal: bool = True, window: int = 0,
     return out.reshape(B, Sq, Hq, D)
 
 
-def decode_attention(q, k_cache, v_cache, pos, *, window: int = 0):
+def decode_attention(q, k_cache, v_cache, pos, *, window: int = 0,
+                     scale=None):
     """One-token decode. q (B,1,Hq,D); caches:
       full:  (B,S_max,K,D), valid slots are indices <= pos
       ring:  (B,W,K,D) with W == window; slot i holds absolute position
@@ -79,7 +81,8 @@ def decode_attention(q, k_cache, v_cache, pos, *, window: int = 0):
     K = k_cache.shape[2]
     G = Hq // K
     qg = q.reshape(B, 1, K, G, D)
-    scale = D ** -0.5
+    if scale is None:
+        scale = D ** -0.5
     scores = jnp.einsum("bckgd,blkd->bkgcl", qg, k_cache,
                         preferred_element_type=jnp.float32) * scale
     S = k_cache.shape[1]
@@ -113,12 +116,15 @@ def init_attn(key, cfg, dtype):
     return p
 
 
-def pallas_attention(q, k, v, *, causal: bool = True, window: int = 0):
+def pallas_attention(q, k, v, *, causal: bool = True, window: int = 0,
+                     scale=None):
     """Route (B,S,H,D)-layout attention through the Pallas flash kernel
     (repro.kernels). Per-device execution: use on single-device paths or
     inside shard_map; the GSPMD dry-run path uses the jnp implementation
     (identical math, freely partitionable)."""
     from repro.kernels.ops import flash_attention as _fa
+    if scale is not None:  # the kernel scales by head_dim ** -0.5
+        q = q * (scale * q.shape[-1] ** 0.5)
     out = _fa(q.swapaxes(1, 2), k.swapaxes(1, 2), v.swapaxes(1, 2),
               causal=causal, window=window)
     return out.swapaxes(1, 2)
@@ -156,14 +162,17 @@ def attn_apply(p, x, positions, cfg, *, window: int = 0,
         iota = jnp.arange(S_c)[None, :, None, None]
         k_cache = jnp.where(iota == slot, k, cache["k"])
         v_cache = jnp.where(iota == slot, v, cache["v"])
-        out = decode_attention(q, k_cache, v_cache, pos, window=window)
+        out = decode_attention(q, k_cache, v_cache, pos, window=window,
+                               scale=cfg.attention_multiplier)
         new_cache = {"k": k_cache, "v": v_cache}
     else:
         if impl == "pallas":
-            out = pallas_attention(q, k, v, causal=True, window=window)
+            out = pallas_attention(q, k, v, causal=True, window=window,
+                                   scale=cfg.attention_multiplier)
         else:
             out = multihead_attention(q, k, v, causal=True, window=window,
-                                      q_chunk=q_chunk)
+                                      q_chunk=q_chunk,
+                                      scale=cfg.attention_multiplier)
         new_cache = None
         if cache is not None:  # prefill: populate cache
             S_c = cache["k"].shape[1]

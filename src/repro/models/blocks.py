@@ -15,6 +15,16 @@ from repro.models.rglru import init_rglru, init_rglru_cache, rglru_apply
 from repro.sharding import constrain
 
 ZERO_AUX = {"moe_lb_loss": 0.0, "moe_z_loss": 0.0, "moe_drop_frac": 0.0}
+# aux entries taken as the largest over layers; the others are summed
+MAX_AUX = ("moe_load_max",)
+
+
+def zero_aux(cfg) -> dict:
+    """The aux entries every block of `cfg` reports: the expert layer's
+    load peak only where the model has experts."""
+    if cfg.moe is None:
+        return dict(ZERO_AUX)
+    return dict(ZERO_AUX, moe_load_max=0.0)
 
 
 def _init_ffn(key, cfg, dtype):
@@ -65,6 +75,12 @@ def init_block_cache(cfg, kind, batch, cache_len, dtype):
     raise ValueError(kind)
 
 
+def _residual(x, delta, cfg):
+    if cfg.residual_multiplier is not None:
+        delta = delta * cfg.residual_multiplier
+    return x + delta
+
+
 def block_window(cfg, kind, window_override: int) -> int:
     """Effective attention window for this block kind (0 = unbounded)."""
     if kind in (ATTN_SWA, ATTN_LOCAL):
@@ -79,34 +95,35 @@ def apply_block(kind, p, x, positions, cfg, *, cache: Optional[dict] = None,
                 mamba_chunk: int = 64, unroll_inner: bool = False,
                 attn_impl: str = "jnp"):
     """x (B,S,D) -> (x, new_cache, aux)."""
-    aux = dict(ZERO_AUX)
+    aux = zero_aux(cfg)
     new_cache = {}
     if kind in (ATTN, ATTN_SWA, ATTN_LOCAL):
         win = block_window(cfg, kind, window_override)
         delta, nc = attn_apply(p["attn"], x, positions, cfg, window=win,
                                cache=None if cache is None else cache,
                                pos=pos, q_chunk=q_chunk, impl=attn_impl)
-        x = x + delta
+        x = _residual(x, delta, cfg)
         new_cache = nc
     elif kind == MAMBA:
         h = rms_norm(x, p["mamba"]["norm"], cfg.norm_eps)
         delta, nc = mamba_apply(p["mamba"], h, cfg, cache=cache,
                                 chunk=mamba_chunk, unroll=unroll_inner)
-        x = x + delta
+        x = _residual(x, delta, cfg)
         new_cache = nc
     elif kind == RGLRU:
         h = rms_norm(x, p["rec"]["norm"], cfg.norm_eps)
         delta, nc = rglru_apply(p["rec"], h, cfg, cache=cache,
                                 unroll=unroll_inner)
-        x = x + delta
+        x = _residual(x, delta, cfg)
         new_cache = nc
     if _has_ffn(cfg, kind):
         if cfg.moe is not None:
             h = rms_norm(x, p["moe_norm"], cfg.norm_eps)
             delta, aux = moe_apply(p["moe"], h, cfg)
-            x = x + delta
+            x = _residual(x, delta, cfg)
         else:
             h = rms_norm(x, p["ffn"]["norm"], cfg.norm_eps)
-            x = x + silu_mlp(h, p["ffn"]["w1"], p["ffn"]["w3"], p["ffn"]["w2"])
+            x = _residual(x, silu_mlp(h, p["ffn"]["w1"], p["ffn"]["w3"],
+                                      p["ffn"]["w2"]), cfg)
     x = constrain(x, "batch", None, None)
     return x, new_cache, aux

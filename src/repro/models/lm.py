@@ -19,11 +19,27 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ArchConfig
-from repro.models.blocks import (ZERO_AUX, apply_block, block_window,
-                                 init_block, init_block_cache)
+from repro.models.blocks import (MAX_AUX, apply_block, block_window,
+                                 init_block, init_block_cache, zero_aux)
 from repro.models.common import dense_init, embed_init, rms_norm, \
     sinusoidal_positions
+from repro.models.moe import SAVED_NAMES
 from repro.sharding import constrain
+
+
+# What the backward pass of a model with experts keeps of each layer: the
+# outputs of the matrix products without batch dimensions (projections,
+# router) and the expert layer's grouped products (moe.SAVED_NAMES). The
+# rest (norms, RoPE, each attention chunk's float32 scores and
+# probabilities, the expert gathers and gating) is recomputed: such a
+# model's step is bound by bytes rather than FLOPs, so the recomputation
+# is cheap, and a scan of L layers would otherwise hold L layers' of those
+# at once (at 4 layers of 2 x 4096 tokens of Granite-3.0-3B-A800M, 11.25
+# GB of attention scores and probabilities alone, more than the chip has
+# beside the training state). A dense model keeps everything.
+SCAN_SAVE = jax.checkpoint_policies.save_from_both_policies(
+    jax.checkpoint_policies.dots_with_no_batch_dims_saveable,
+    jax.checkpoint_policies.save_only_these_names(*SAVED_NAMES))
 
 
 def _pattern_counts(cfg: ArchConfig):
@@ -77,7 +93,8 @@ def init_cache(cfg: ArchConfig, batch: int, cache_len: int, dtype=None,
 
 
 def _acc_aux(acc, aux):
-    return {k: acc[k] + aux[k] for k in acc}
+    return {k: jnp.maximum(acc[k], aux[k]) if k in MAX_AUX else acc[k] + aux[k]
+            for k in acc}
 
 
 def forward(params, tokens, cfg: ArchConfig, *,
@@ -95,6 +112,8 @@ def forward(params, tokens, cfg: ArchConfig, *,
     decode: tokens (B,1), cache + pos given.
     """
     x = jnp.take(params["embed"]["tok"], tokens, axis=0)
+    if cfg.embedding_multiplier is not None:
+        x = x * cfg.embedding_multiplier
     if prefix_embeds is not None:
         x = jnp.concatenate([prefix_embeds.astype(x.dtype), x], axis=1)
     B, S, D = x.shape
@@ -128,8 +147,13 @@ def forward(params, tokens, cfg: ArchConfig, *,
             new_caches.append(nc)
         return (x, aux), (tuple(new_caches) if cache is not None else None)
 
-    body = jax.checkpoint(group_body) if remat else group_body
-    aux0 = {k: jnp.zeros((), jnp.float32) for k in ZERO_AUX}
+    if remat:
+        body = jax.checkpoint(group_body)
+    elif cfg.moe is not None:
+        body = jax.checkpoint(group_body, policy=SCAN_SAVE, prevent_cse=False)
+    else:
+        body = group_body
+    aux0 = {k: jnp.zeros((), jnp.float32) for k in zero_aux(cfg)}
     cache_groups = tuple(cache["groups"]) if cache is not None else None
     if n_full > 0 and unroll_layers:
         # python-loop over repeats: larger HLO, but XLA cost_analysis counts
@@ -163,6 +187,8 @@ def forward(params, tokens, cfg: ArchConfig, *,
         logits = x @ params["embed"]["tok"].T
     else:
         logits = x @ params["unembed"]["w"]
+    if cfg.logits_scaling is not None:
+        logits = logits / cfg.logits_scaling
     if logits_f32:
         logits = logits.astype(jnp.float32)
     logits = constrain(logits, "batch", None, "model")

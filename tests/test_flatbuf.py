@@ -95,6 +95,21 @@ def test_leafwise_mean_is_bit_identical_to_arena_property(
         assert a.tobytes() == b.tobytes(), (k, wire_format, mask)
 
 
+def test_stored_f32_reads_every_bf16_value_exactly():
+    """The leaf-wise send widens a bf16 leaf through its bits: for every
+    one of the 65536 bf16 bit patterns it gives the value a plain cast
+    gives (NaN for NaN)."""
+    from repro.core.daso import _stored_f32
+    bits = np.arange(1 << 16, dtype=np.uint32).astype(np.uint16)
+    x = jax.lax.bitcast_convert_type(jnp.asarray(bits), jnp.bfloat16)
+    got = np.asarray(jax.jit(_stored_f32)(x))
+    want = np.asarray(x.astype(jnp.float32))
+    assert got.dtype == np.float32
+    nan = np.isnan(want)
+    assert np.array_equal(np.isnan(got), nan)
+    assert got[~nan].tobytes() == want[~nan].tobytes()
+
+
 def test_layout_static_offsets():
     tree = {"a": jnp.zeros((2, 3)), "b": jnp.zeros((5,)),
             "c": jnp.zeros((4,), jnp.int32)}

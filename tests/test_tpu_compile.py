@@ -134,6 +134,25 @@ def test_leafwise_send_reads_half_the_arena_bytes(one_chip, model):
     assert leafwise.memory_analysis().temp_size_in_bytes < arena_bytes
 
 
+def test_leafwise_send_sums_the_stored_bf16_values(one_chip):
+    """A bf16 leaf updated and averaged over replicas in one program, as
+    the send step does, compiled for a v5e: the reduction reads the
+    updated leaf through its bits. With a plain cast (or a
+    `reduce_precision` after it) the compiler fuses the update with the
+    reduction, the bit-level read is gone from the optimized HLO, and the
+    sum may see the update before its rounding to bf16."""
+    from repro.core.daso import replica_mean
+    shape = (N_REPLICAS, 1536, 512)
+    p = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+    g = jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+
+    def send(p, g):
+        new = (p.astype(jnp.float32) - 0.003 * g).astype(jnp.bfloat16)
+        return new, replica_mean({"w": new}, jnp.float32, device_local=True)
+
+    assert "bitcast-convert(" in _compile_text(send, p, g)
+
+
 def test_mesh_cycle_keeps_the_carry_sharding(topo):
     """The send/receive macro-cycle of the `chip:2 x host:2` DASO job,
     compiled for a described v5e:2x2 mesh, returns every carry leaf on the
