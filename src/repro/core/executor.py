@@ -35,6 +35,7 @@ per-step reference path that the equivalence tests compare against
 from __future__ import annotations
 
 import difflib
+import functools
 import time
 from contextlib import contextmanager
 from dataclasses import dataclass, field
@@ -55,6 +56,7 @@ from repro.core.schedule import (DasoController, Mode, is_ov_mode, join_mode,
                                  split_mode, split_ov)
 from repro.obs.trace import NULL_TRACER, backend_compiles
 from repro.optim.optimizers import Optimizer
+from repro.sharding import trace_device_local
 
 # A cycle shape is the static fingerprint of a macro-cycle: one
 # (mode, staleness) pair per step. Distinct shapes compile distinct programs.
@@ -730,7 +732,19 @@ class MacroCycleExecutor:
         # keep_unused: a cycle that starts with a send never reads the
         # donated inflight buffer; kept as an argument it is aliased to
         # the new inflight instead of staying allocated beside it
-        return jax.jit(program, donate_argnums=donate, keep_unused=True)
+        return jax.jit(self._traced_on_placement(program),
+                       donate_argnums=donate, keep_unused=True)
+
+    def _traced_on_placement(self, fn: Callable) -> Callable:
+        """`fn`, traced knowing whether its program stays on one device
+        (no placement): the model's attention reads it to choose its
+        fused kernel (`repro.sharding.device_local`)."""
+        @functools.wraps(fn)   # the program keeps its name (jit_program)
+        def traced(*args):
+            with trace_device_local(self.placement is None):
+                return fn(*args)
+
+        return traced
 
     def _constrain(self, carry):
         """A program's output carry, held on the placement's shardings
@@ -748,7 +762,7 @@ class MacroCycleExecutor:
                 carry, metrics = fn(carry, batch, lr)
                 return self._constrain(carry), metrics
 
-            self._per_step[key] = jax.jit(step)
+            self._per_step[key] = jax.jit(self._traced_on_placement(step))
         return self._per_step[key]
 
     # -- host-loop phases --------------------------------------------------

@@ -51,13 +51,19 @@ def _unpad_rows(view, meta):
     return view.reshape((-1, npad))[:, :n].reshape(lead + (n,))
 
 
-@functools.partial(jax.jit, static_argnames=("causal", "window", "block_q",
-                                             "block_k", "interpret"))
+@functools.partial(jax.jit, static_argnames=("causal", "window", "scale",
+                                             "block_q", "block_k",
+                                             "bwd_blocks", "interpret"))
 def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
-                    block_q: int = 128, block_k: int = 128,
+                    scale: float | None = None, block_q: int = 512,
+                    block_k: int = 512,
+                    bwd_blocks: tuple[int, int] | None = None,
                     interpret: bool | None = None):
-    return _flash(q, k, v, causal=causal, window=window, block_q=block_q,
-                  block_k=block_k, interpret=_interpret(interpret))
+    """Differentiable flash attention, (B, H, S, D) layout
+    (kernels/flash_attention.py)."""
+    return _flash(q, k, v, causal=causal, window=window, scale=scale,
+                  block_q=block_q, block_k=block_k, bwd_blocks=bwd_blocks,
+                  interpret=_interpret(interpret))
 
 
 @functools.partial(jax.jit, static_argnames=("block_d", "interpret"))

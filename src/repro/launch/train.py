@@ -43,6 +43,7 @@ import jax
 from repro.configs import get_config, get_reduced
 from repro.core.executor import list_strategies
 from repro.data.synthetic import SyntheticLM
+from repro.models import attention
 from repro.models.lm import init_params
 from repro.train.loop import TrainLoopConfig, run_training
 from repro.train.step import make_lm_loss
@@ -492,15 +493,14 @@ def main():
             health.phase("train")
         if tracer is not None and strategy.controller is not None:
             strategy.controller.tracer = tracer
-        report = run_with_faults(strategy, params0, daso_data, job.lr_fn,
-                                 args.steps, plan,
-                                 ckpt_every=args.ckpt_every,
-                                 ckpt_cb=ckpt_cb, placement=placement,
-                                 start_step=start_step, carry=carry,
-                                 membership=membership, health=health,
-                                 tracer=tracer,
-                                 autotune_every=(args.autotune_every
-                                                 if args.autotune else 0))
+        with attention.counting_paths() as attn_paths:
+            report = run_with_faults(
+                strategy, params0, daso_data, job.lr_fn, args.steps, plan,
+                ckpt_every=args.ckpt_every, ckpt_cb=ckpt_cb,
+                placement=placement, start_step=start_step, carry=carry,
+                membership=membership, health=health, tracer=tracer,
+                autotune_every=(args.autotune_every
+                                if args.autotune else 0))
         result = report.result
         if prior_losses:
             result.losses = prior_losses + result.losses
@@ -519,9 +519,10 @@ def main():
     else:
         if health is not None:
             health.phase("train")
-        result = run_training(loss_fn, params0, job.data_fn, loop_cfg,
-                              lr_fn=job.lr_fn, log=say, health=health,
-                              tracer=tracer)
+        with attention.counting_paths() as attn_paths:
+            result = run_training(loss_fn, params0, job.data_fn, loop_cfg,
+                                  lr_fn=job.lr_fn, log=say, health=health,
+                                  tracer=tracer)
     if health is not None:
         health.phase("finalize")
     if result.executor_stats is not None:
@@ -535,6 +536,11 @@ def main():
             say("[train] executor host phases, ms per cycle: "
                 + _phase_line(s.phase_s, 1.0 / s.cycles)
                 + "; slowest cycle: " + _phase_line(s.slowest_cycle_s, 1.0))
+
+    say("[train] attention calls traced: "
+        + " ".join(f"{k}={v}" for k, v in attn_paths.items()))
+    if tracer is not None:
+        tracer.counter("attention_path", attn_paths)
 
     moe = expert_counters(result.metrics)
     if moe:

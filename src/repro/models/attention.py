@@ -8,16 +8,47 @@ bodies are counted once — see EXPERIMENTS.md roofline methodology).
 """
 from __future__ import annotations
 
+import contextlib
+import contextvars
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.flash_attention import SEQ_MULTIPLE, TUNED_BLOCKS
 from repro.models.common import head_rms_norm, rms_norm
 from repro.models.rope import apply_mrope, apply_rope
-from repro.sharding import constrain
+from repro.sharding import constrain, current_policy, device_local
 
 NEG_INF = -1e30
+
+_PATHS: contextvars.ContextVar[Optional[dict]] = contextvars.ContextVar(
+    "attention_paths", default=None)
+
+
+@contextlib.contextmanager
+def counting_paths():
+    """Count the training and prefill attention calls traced inside the
+    block by the path they took: yields {"kernel": n, "jnp": m}, updated
+    as programs are traced."""
+    counts = {"kernel": 0, "jnp": 0}
+    tok = _PATHS.set(counts)
+    try:
+        yield counts
+    finally:
+        _PATHS.reset(tok)
+
+
+def fused_kernel_applies(seq: int, head_dim: int) -> bool:
+    """Whether the default path takes the fused flash kernel: on a TPU,
+    in a program that stays on one device (`sharding.device_local`) under
+    no mesh policy (the GSPMD dry run), for a head_dim the kernel is tuned
+    for and a length its blocks divide (a multiple of SEQ_MULTIPLE).
+    Everything else runs `multihead_attention`: the same mathematics,
+    which GSPMD partitions."""
+    return (head_dim in TUNED_BLOCKS and seq % SEQ_MULTIPLE == 0
+            and jax.default_backend() == "tpu"
+            and current_policy() is None and device_local())
 
 
 def _pick_chunk(seq: int, target: int) -> int:
@@ -119,24 +150,27 @@ def init_attn(key, cfg, dtype):
 def pallas_attention(q, k, v, *, causal: bool = True, window: int = 0,
                      scale=None):
     """Route (B,S,H,D)-layout attention through the Pallas flash kernel
-    (repro.kernels). Per-device execution: use on single-device paths or
-    inside shard_map; the GSPMD dry-run path uses the jnp implementation
-    (identical math, freely partitionable)."""
+    (repro.kernels), forward and backward. Per-device execution: use on
+    single-device paths or inside shard_map; the GSPMD dry-run path uses
+    the jnp implementation (identical math, freely partitionable)."""
     from repro.kernels.ops import flash_attention as _fa
-    if scale is not None:  # the kernel scales by head_dim ** -0.5
-        q = q * (scale * q.shape[-1] ** 0.5)
+    fwd, bwd = TUNED_BLOCKS.get(q.shape[-1], ((512, 512), (512, 512)))
     out = _fa(q.swapaxes(1, 2), k.swapaxes(1, 2), v.swapaxes(1, 2),
-              causal=causal, window=window)
+              causal=causal, window=window, scale=scale, block_q=fwd[0],
+              block_k=fwd[1], bwd_blocks=bwd)
     return out.swapaxes(1, 2)
 
 
 def attn_apply(p, x, positions, cfg, *, window: int = 0,
                cache: Optional[dict] = None, pos=None, q_chunk: int = 1024,
-               impl: str = "jnp"):
+               impl: str = "auto"):
     """Pre-norm attention sub-block. Returns (residual_delta, new_cache).
 
     Training/prefill: cache is None or an empty cache dict to fill.
     Decode: x is (B,1,D), cache holds K/V, pos is the absolute position.
+    impl: "auto" takes the fused kernel where no cache is given (training)
+    and `fused_kernel_applies`, else the jnp path; "pallas" and "jnp"
+    force one of them. Decode runs `decode_attention` whatever impl says.
     """
     B, S, D = x.shape
     Hq, K, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
@@ -166,13 +200,21 @@ def attn_apply(p, x, positions, cfg, *, window: int = 0,
                                scale=cfg.attention_multiplier)
         new_cache = {"k": k_cache, "v": v_cache}
     else:
-        if impl == "pallas":
-            out = pallas_attention(q, k, v, causal=True, window=window,
-                                   scale=cfg.attention_multiplier)
-        else:
-            out = multihead_attention(q, k, v, causal=True, window=window,
-                                      q_chunk=q_chunk,
-                                      scale=cfg.attention_multiplier)
+        if impl == "auto":
+            impl = ("pallas" if cache is None
+                    and fused_kernel_applies(S, hd) else "jnp")
+        counts = _PATHS.get()
+        if counts is not None:
+            counts["kernel" if impl == "pallas" else "jnp"] += 1
+        # one scope over either path, so a chip profile splits its time
+        with jax.named_scope("repro.attention"):
+            if impl == "pallas":
+                out = pallas_attention(q, k, v, causal=True, window=window,
+                                       scale=cfg.attention_multiplier)
+            else:
+                out = multihead_attention(q, k, v, causal=True,
+                                          window=window, q_chunk=q_chunk,
+                                          scale=cfg.attention_multiplier)
         new_cache = None
         if cache is not None:  # prefill: populate cache
             S_c = cache["k"].shape[1]

@@ -93,7 +93,7 @@ def block_window(cfg, kind, window_override: int) -> int:
 def apply_block(kind, p, x, positions, cfg, *, cache: Optional[dict] = None,
                 pos=None, window_override: int = 0, q_chunk: int = 1024,
                 mamba_chunk: int = 64, unroll_inner: bool = False,
-                attn_impl: str = "jnp"):
+                attn_impl: str = "auto"):
     """x (B,S,D) -> (x, new_cache, aux)."""
     aux = zero_aux(cfg)
     new_cache = {}

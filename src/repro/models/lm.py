@@ -104,7 +104,7 @@ def forward(params, tokens, cfg: ArchConfig, *,
             window_override: int = 0, q_chunk: int = 1024,
             mamba_chunk: int = 64, remat: bool = False,
             logits_f32: bool = False, unroll_layers: bool = False,
-            attn_impl: str = "jnp"):
+            attn_impl: str = "auto"):
     """tokens (B, S_tok) int32. Returns {"logits" (B,S,V), "aux", "cache"}.
 
     prefix_embeds (B, P, D): frontend stub embeddings (vlm/audio) spliced

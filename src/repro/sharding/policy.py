@@ -70,6 +70,27 @@ def use_policy(policy: Optional[MeshPolicy]):
         _POLICY.reset(tok)
 
 
+_DEVICE_LOCAL: contextvars.ContextVar[bool] = contextvars.ContextVar(
+    "device_local", default=False)
+
+
+def device_local() -> bool:
+    """True while a program is traced whose arrays all lie on one device:
+    no placement spreads a replica's batch (or the replica axis) over
+    devices. The executor says so (`trace_device_local`) as it traces a
+    program without a placement; anything else reads False."""
+    return _DEVICE_LOCAL.get()
+
+
+@contextlib.contextmanager
+def trace_device_local(local: bool):
+    tok = _DEVICE_LOCAL.set(bool(local))
+    try:
+        yield
+    finally:
+        _DEVICE_LOCAL.reset(tok)
+
+
 def constrain(x, *logical):
     """Annotate activation x with logical axis names (None = unsharded dim).
 

@@ -174,3 +174,123 @@ def test_pallas_attention_impl_matches_jnp(arch):
     ref = forward(params, toks, cfg, attn_impl="jnp")["logits"]
     got = forward(params, toks, cfg, attn_impl="pallas")["logits"]
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref), atol=5e-3)
+
+
+def test_kernel_gradients_match_jnp_in_the_checkpointed_layer_scan():
+    """Granite (reduced; experts, so the layer scan runs under
+    SCAN_SAVE, and its attention multiplier as the scale): the loss and
+    every parameter gradient through the fused kernel (interpret mode)
+    equal the jnp path's."""
+    from repro.configs import get_reduced
+    from repro.models.lm import forward, init_params
+    cfg = get_reduced("granite-moe-3b-a800m")
+    assert cfg.moe is not None and cfg.attention_multiplier
+    key = jax.random.PRNGKey(1)
+    params = init_params(cfg, key)
+    toks = jax.random.randint(key, (2, 128), 0, cfg.vocab_size)
+
+    def loss(p, impl):
+        logits = forward(p, toks, cfg, attn_impl=impl)["logits"]
+        return cross_entropy_loss(logits, toks)
+
+    (l_ref, g_ref), (l_got, g_got) = (
+        jax.value_and_grad(loss)(params, impl) for impl in ("jnp", "pallas"))
+    np.testing.assert_allclose(float(l_got), float(l_ref), rtol=1e-5)
+    for got, want in zip(jax.tree.leaves(g_got), jax.tree.leaves(g_ref)):
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   rtol=2e-4, atol=2e-6)
+
+
+def _kernel_calls(jaxpr) -> int:
+    n = 0
+    for e in jaxpr.eqns:
+        n += str(e.params.get("name", "")).startswith("flash_attn")
+        for p in e.params.values():
+            for sub in (p if isinstance(p, (list, tuple)) else [p]):
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    n += _kernel_calls(inner)
+    return n
+
+
+@pytest.fixture
+def on_tpu(monkeypatch):
+    """The dispatch rule as it reads on a TPU backend; nothing is
+    lowered, so the jaxprs below trace on the CPU."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+
+@pytest.mark.parametrize("case,kernels", [
+    ("one_device", True), ("placement", False), ("mesh_policy", False),
+    ("odd_length", False), ("head_dim_32", False), ("decode", False),
+    ("prefill", False)])
+def test_default_attention_path_follows_the_dispatch_rule(on_tpu, case,
+                                                          kernels):
+    """The default ("auto") training path takes the fused kernel only on
+    a TPU, in a program traced as device-local, under no mesh policy, at
+    a length its blocks divide and a head_dim it is tuned for; decode and
+    prefill with a cache keep the jnp path."""
+    import contextlib
+    from jax.sharding import Mesh
+    from repro.configs import get_reduced
+    from repro.models import attention
+    from repro.models.lm import forward, init_cache, init_params
+    from repro.sharding import MeshPolicy, trace_device_local, use_policy
+    cfg = get_reduced("llama3.2-1b")
+    if case == "head_dim_32":
+        cfg = cfg.replace(head_dim=32, n_heads=8, n_kv_heads=8)
+    params = jax.eval_shape(lambda: init_params(cfg, jax.random.PRNGKey(0)))
+    S = 384 if case == "odd_length" else 512
+    toks = jax.ShapeDtypeStruct((2, 1 if case == "decode" else S), jnp.int32)
+    policy = None
+    if case == "mesh_policy":
+        policy = MeshPolicy(Mesh(np.array(jax.devices()[:1]).reshape(1, 1),
+                                 ("data", "model")))
+
+    def loss(p, t):
+        return jnp.sum(forward(p, t, cfg)["logits"])
+
+    def run(p, t):
+        if case == "decode":
+            cache = init_cache(cfg, 2, 64)
+            return forward(p, t, cfg, cache=cache, pos=3)["logits"]
+        if case == "prefill":
+            return forward(p, t, cfg, cache=init_cache(cfg, 2, S))["logits"]
+        return jax.grad(loss)(p, t)
+
+    with (use_policy(policy) if policy else contextlib.nullcontext()), \
+            trace_device_local(case != "placement"), \
+            attention.counting_paths() as traced:
+        jaxpr = jax.make_jaxpr(run)(params, toks).jaxpr
+    assert (_kernel_calls(jaxpr) > 0) == kernels
+    if case == "decode":
+        assert traced == {"kernel": 0, "jnp": 0}
+    else:
+        assert traced == ({"kernel": 1, "jnp": 0} if kernels
+                          else {"kernel": 0, "jnp": 1})
+
+
+def test_macro_executor_traces_its_program_as_device_local(on_tpu):
+    """The launcher's DASO job on the macro-cycle executor without a
+    placement: its cycle program holds the fused kernel's forward and
+    both backward kernels."""
+    from repro.core.executor import MacroCycleExecutor
+    from repro.launch import train
+    from repro.optim.optimizers import sgd
+    from repro.train.loop import build_strategy
+    args = train.build_parser().parse_args(
+        ["--arch", "llama3.2-1b", "--seq-len", "512", "--per-node-batch",
+         "1", "--nodes", "2", "--steps", "8", "--b-max", "2"])
+    job = train.build_job(args, train.arch_config(args))
+    strat = build_strategy(job.loss_fn, job.loop_cfg, sgd(momentum=0.9))
+    shape = strat.plan_cycle(0, 8).shape
+    ex = MacroCycleExecutor(strat)
+    carry = jax.eval_shape(strat.init_carry, job.params0)
+    tokens = jax.ShapeDtypeStruct((len(shape), 2, 1, 512), jnp.int32)
+    lrs = jax.ShapeDtypeStruct((len(shape),), jnp.float32)
+    program = ex.program_for(shape)
+    jaxpr = jax.make_jaxpr(program)(
+        carry, {"tokens": tokens, "labels": tokens}, lrs).jaxpr
+    assert _kernel_calls(jaxpr) >= 3
+    # a chip trace finds the cycle programs by their name, jit_program
+    assert program.__name__ == "program"

@@ -359,6 +359,12 @@ def test_launcher_trains_granite_dropless_and_counts_its_routing(tmp_path):
     # the reduced Granite routes top-2 of 4 experts: the load peak lies
     # between 1 (even) and E / k (every token on the same k experts)
     assert 1.0 <= counter["moe_load_max"] <= 4 / 2
+    # on the CPU every attention call keeps the jnp path, and the
+    # launcher says so beside the expert layer's counters
+    (paths,) = [e["args"] for e in events if e["name"] == "attention_path"]
+    assert paths["kernel"] == 0 and paths["jnp"] > 0
+    assert (f"[train] attention calls traced: kernel=0 jnp={paths['jnp']}"
+            in r.stdout)
 
 
 def test_expert_counters_take_the_largest_step():
