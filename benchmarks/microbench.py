@@ -142,8 +142,8 @@ import os
 import time
 import jax, jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
-from repro.core.daso import (replica_mean, replica_mean_per_leaf,
-                             replicate_params)
+from repro.core.daso import (DasoConfig, Exchange, replica_mean,
+                             replica_mean_per_leaf, replicate_params)
 from repro.core.compression import transfer_bytes
 from repro.launch.hlo_stats import collective_stats
 
@@ -209,13 +209,14 @@ def bench(name, fn, *args, wire_format=None, impl=None):
     return us
 
 results = []
-for wf, wd in (("f32", None), ("bf16", jnp.bfloat16)):
+for wf in ("f32", "bf16"):
     bench(f"per_leaf_{wf}",
-          lambda p, wd=wd: replica_mean_per_leaf(p, wd), params,
+          lambda p, wf=wf: replica_mean_per_leaf(p, wf), params,
           wire_format=wf, impl="per_leaf")
+ex = Exchange.of(DasoConfig(n_replicas=R, global_world=R))
 for wf in ("f32", "bf16", "int8"):
     bench(f"fused_{wf}",
-          lambda p, wf=wf: replica_mean(p, wire_format=wf), params,
+          lambda p, wf=wf: replica_mean(p, ex=ex, wire=wf), params,
           wire_format=wf, impl="fused")
 
 by = {r["name"]: r for r in results}

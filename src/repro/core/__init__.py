@@ -2,6 +2,7 @@
 # selective data-parallel optimization (Coquelin et al. 2021).
 from repro.core.daso import (  # noqa: F401
     DasoConfig,
+    Exchange,
     blocking_sync,
     daso_train_step,
     dereplicate_params,

@@ -52,8 +52,8 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import flatbuf
-from repro.core.daso import (_cross_replica_loss, blocking_sync,
-                             freeze_inactive, local_step, replica_mean,
+from repro.core.daso import (Exchange, _member_step, _step_metrics,
+                             blocking_sync, freeze_inactive, replica_mean,
                              replicate_params)
 from repro.core.executor import DasoStrategy, register_strategy
 from repro.core.schedule import DasoController, Mode, split_mode, split_ov
@@ -125,25 +125,26 @@ class DownpourController(PeriodicController):
 
 # -- gossip exchange primitive -------------------------------------------------
 
-def gossip_mix(tree, *, shift: int, wire_format: str = "f32",
-               int8_block: int = 256, use_kernels: bool = False, mask=None):
+def gossip_mix(tree, *, ex: Exchange, shift: int):
     """One pairwise gossip exchange over the leading replica axis:
     ``row_i ← (row_i + row_{(i+shift) mod R}) / 2``.
 
     Runs on the packed flat-buffer arenas (one permutation per dtype arena
-    regardless of leaf count). Only the PARTNER copy is wire-encoded —
-    the wire format models what crosses the network, and a replica's own
-    row never leaves the chip. There is no reduction anywhere, so the
-    result is bit-identical for any device layout (the 2-proc == 1-proc
-    contract holds without a deterministic-reduce fallback), and on a
-    replica-sharded mesh the ring shift lowers to data movement
-    (collective-permute family), never an all-reduce.
+    regardless of leaf count), whatever `ex.leafwise` says. Only the
+    PARTNER copy is encoded at the cycling tier `ex.send_wire` — the wire
+    format models what crosses the network, and a replica's own row never
+    leaves the chip. There is no reduction anywhere (`ex.deterministic`
+    does not apply), so the result is bit-identical for any device layout
+    (the 2-proc == 1-proc contract holds without a deterministic-reduce
+    fallback), and on a replica-sharded mesh the ring shift lowers to data
+    movement (collective-permute family), never an all-reduce.
 
-    `mask`: rows mix only when BOTH endpoints are active; a pair with a
+    `ex.mask`: rows mix only when BOTH endpoints are active; a pair with a
     dead endpoint keeps its own row (dead rows stay frozen ghosts). Under
     partial membership the exchange is therefore mass-preserving only
     pairwise, not globally — the property-test guarantee (mean
     preservation for any shift schedule) is stated for full membership."""
+    wire_format, use_kernels, mask = ex.send_wire, ex.kernels, ex.mask
     layout = flatbuf.build_layout(tree, batch_dims=1)
     arenas = flatbuf.pack(tree, layout)
     r = layout.batch_shape[0]
@@ -163,7 +164,6 @@ def gossip_mix(tree, *, shift: int, wire_format: str = "f32",
         else:
             if wire_format == "int8":
                 partner = flatbuf.wire_roundtrip(partner, "int8",
-                                                 int8_block=int8_block,
                                                  use_kernels=use_kernels)
             elif wire_format == "bf16":
                 partner = flatbuf.encode_wire(partner, "bf16",
@@ -178,79 +178,46 @@ def gossip_mix(tree, *, shift: int, wire_format: str = "f32",
 
 # -- assembled train steps -----------------------------------------------------
 
-def _aux_metrics(metrics, aux_r, mask, n_replicas: int, n_active: int):
-    """Masked aux-metric reduction, same contract as daso_train_step."""
-    for k, v in aux_r.items():
-        if isinstance(v, jnp.ndarray) and v.ndim <= 1:
-            if (mask is not None and v.ndim == 1
-                    and v.shape[0] == n_replicas):
-                metrics[k] = jnp.sum(
-                    v * jnp.asarray(mask, v.dtype)) / n_active
-            else:
-                metrics[k] = jnp.mean(v)
-    return metrics
-
-
 def gossip_train_step(loss_fn, optimizer, cfg, *, mode: str, shift: int = 1,
-                      n_micro: int = 1, membership=None,
-                      device_local: bool = False):
+                      n_micro: int = 1, ex: Optional[Exchange] = None):
     """step(params_R, opt_R, batch_R, lr) -> (params_R, opt_R, metrics).
-    `mode` is local | blocking | gossip (shift decoded by the caller)."""
+    `mode` is local | blocking | gossip (shift decoded by the caller);
+    `ex` (default `Exchange.of(cfg)`) as in `daso_train_step`."""
     assert mode in (Mode.LOCAL, Mode.BLOCKING, Mode.GOSSIP), mode
-    lstep = local_step(loss_fn, optimizer, n_micro=n_micro)
-    impl, kern, blk = (cfg.exchange_impl, cfg.exchange_kernels,
-                       cfg.int8_block)
-    det = cfg.deterministic_reduce
-    mask = flatbuf.normalize_membership(membership, cfg.n_replicas)
-    n_active = cfg.n_replicas if mask is None else int(sum(mask))
+    ex = Exchange.of(cfg) if ex is None else ex
+    lstep = _member_step(loss_fn, optimizer, ex, n_micro=n_micro)
 
     def step(params, opt_state, batch, lr):
-        new_p, new_o, loss_r, aux_r = lstep(params, opt_state, batch, lr)
-        if mask is not None:
-            new_p = freeze_inactive(new_p, params, mask)
-            new_o = freeze_inactive(new_o, opt_state, mask)
-        params, opt_state = new_p, new_o
+        params, opt_state, loss_r, aux_r = lstep(params, opt_state, batch,
+                                                 lr)
         if mode == Mode.GOSSIP:
-            params = gossip_mix(
-                params, shift=shift,
-                wire_format=cfg.wire_format_for(blocking=False),
-                int8_block=blk, use_kernels=kern, mask=mask)
+            params = gossip_mix(params, ex=ex, shift=shift)
         elif mode == Mode.BLOCKING:
-            params = blocking_sync(
-                params, wire_format=cfg.wire_format_for(blocking=True),
-                impl=impl, int8_block=blk, use_kernels=kern, mask=mask,
-                deterministic=det, device_local=device_local)
-        loss = _cross_replica_loss(cfg, mask, n_active, loss_r)
-        metrics = {"loss": loss, "loss_per_replica": loss_r}
-        return params, opt_state, _aux_metrics(
-            metrics, aux_r, mask, cfg.n_replicas, n_active)
+            params = blocking_sync(params, ex=ex)
+        return params, opt_state, _step_metrics(ex, loss_r, aux_r)
 
     return step
 
 
 def easgd_train_step(loss_fn, optimizer, cfg, *, mode: str, alpha: float,
-                     n_micro: int = 1, membership=None,
-                     device_local: bool = False):
+                     n_micro: int = 1, ex: Optional[Exchange] = None):
     """step(params_R, opt_R, center_R, batch_R, lr)
         -> (params_R, opt_R, center_R, metrics).
 
     `mode` elastic: the ONE outer collective is the masked replica mean m
-    of the post-step params; then the elastic pull `params ← (1-α)params
-    + α·center` and the center update `center ← (1-β)center + β·m` with
-    β = α·n_active (the symmetric coupling of Zhang et al. §2: the center
-    moves by α per attached replica). `mode` blocking resets the center
-    to the freshly synced params — a full average IS the consensus, so
-    warm-up/cool-down leave nothing elastic to track. The center rows are
-    global state (identical across replicas by construction) and are
-    never membership-frozen; dead PARAM rows stay frozen ghosts."""
+    of the post-step params, at the cycling tier; then the elastic pull
+    `params ← (1-α)params + α·center` and the center update `center ←
+    (1-β)center + β·m` with β = α·n_active (the symmetric coupling of
+    Zhang et al. §2: the center moves by α per attached replica). `mode`
+    blocking resets the center to the freshly synced params — a full
+    average IS the consensus, so warm-up/cool-down leave nothing elastic
+    to track. The center rows are global state (identical across replicas
+    by construction) and are never membership-frozen; dead PARAM rows
+    stay frozen ghosts."""
     assert mode in (Mode.LOCAL, Mode.BLOCKING, Mode.ELASTIC), mode
-    lstep = local_step(loss_fn, optimizer, n_micro=n_micro)
-    impl, kern, blk = (cfg.exchange_impl, cfg.exchange_kernels,
-                       cfg.int8_block)
-    det = cfg.deterministic_reduce
-    mask = flatbuf.normalize_membership(membership, cfg.n_replicas)
-    n_active = cfg.n_replicas if mask is None else int(sum(mask))
-    beta = alpha * n_active
+    ex = Exchange.of(cfg) if ex is None else ex
+    lstep = _member_step(loss_fn, optimizer, ex, n_micro=n_micro)
+    beta = alpha * ex.n_active
 
     def lerp(a_tree, b_tree, t):
         # (1-t)·a + t·b in f32; integer leaves round back (same treatment
@@ -264,36 +231,24 @@ def easgd_train_step(loss_fn, optimizer, cfg, *, mode: str, alpha: float,
         return jax.tree.map(leaf, a_tree, b_tree)
 
     def step(params, opt_state, center, batch, lr):
-        new_p, new_o, loss_r, aux_r = lstep(params, opt_state, batch, lr)
-        if mask is not None:
-            new_p = freeze_inactive(new_p, params, mask)
-            new_o = freeze_inactive(new_o, opt_state, mask)
-        params, opt_state = new_p, new_o
+        params, opt_state, loss_r, aux_r = lstep(params, opt_state, batch,
+                                                 lr)
         if mode == Mode.ELASTIC:
-            m = replica_mean(
-                params, wire_format=cfg.wire_format_for(blocking=False),
-                impl=impl, int8_block=blk, use_kernels=kern, mask=mask,
-                deterministic=det, device_local=device_local)
+            m = replica_mean(params, ex=ex, wire=ex.send_wire)
             params = freeze_inactive(lerp(params, center, alpha),
-                                     params, mask)
+                                     params, ex.mask)
             center = lerp(center, m, beta)
         elif mode == Mode.BLOCKING:
-            params = blocking_sync(
-                params, wire_format=cfg.wire_format_for(blocking=True),
-                impl=impl, int8_block=blk, use_kernels=kern, mask=mask,
-                deterministic=det, device_local=device_local)
+            params = blocking_sync(params, ex=ex)
             center = jax.tree.map(jnp.array, params)
-        loss = _cross_replica_loss(cfg, mask, n_active, loss_r)
-        metrics = {"loss": loss, "loss_per_replica": loss_r}
-        return params, opt_state, center, _aux_metrics(
-            metrics, aux_r, mask, cfg.n_replicas, n_active)
+        return params, opt_state, center, _step_metrics(ex, loss_r, aux_r)
 
     return step
 
 
 def downpour_train_step(loss_fn, optimizer, cfg, *, mode: str,
                         push_scale: float = 1.0, n_micro: int = 1,
-                        membership=None, device_local: bool = False):
+                        ex: Optional[Exchange] = None):
     """step(params_R, opt_R, anchor_R, batch_R, lr)
         -> (params_R, opt_R, anchor_R, metrics).
 
@@ -301,54 +256,39 @@ def downpour_train_step(loss_fn, optimizer, cfg, *, mode: str,
     across replicas). A push applies the SUM of the active replicas'
     accumulated deltas to the server — computed as
     ``n_active · masked_mean(params - anchor)``, which is one masked
-    all-reduce, the SPMD rendering of DOWNPOUR's server addition — then
-    redistributes: ``params = anchor = server``. `push_scale` is the
-    server-side learning rate on the delta sum (1.0 = apply verbatim).
-    Dead rows contribute zero delta (masked out) and keep their frozen
-    ghost params; the anchor rows update everywhere (server state)."""
+    all-reduce at the cycling tier, the SPMD rendering of DOWNPOUR's
+    server addition — then redistributes: ``params = anchor = server``.
+    `push_scale` is the server-side learning rate on the delta sum (1.0 =
+    apply verbatim). Dead rows contribute zero delta (masked out) and keep
+    their frozen ghost params; the anchor rows update everywhere (server
+    state)."""
     assert mode in (Mode.LOCAL, Mode.BLOCKING, Mode.PUSH), mode
-    lstep = local_step(loss_fn, optimizer, n_micro=n_micro)
-    impl, kern, blk = (cfg.exchange_impl, cfg.exchange_kernels,
-                       cfg.int8_block)
-    det = cfg.deterministic_reduce
-    mask = flatbuf.normalize_membership(membership, cfg.n_replicas)
-    n_active = cfg.n_replicas if mask is None else int(sum(mask))
+    ex = Exchange.of(cfg) if ex is None else ex
+    lstep = _member_step(loss_fn, optimizer, ex, n_micro=n_micro)
 
     def step(params, opt_state, anchor, batch, lr):
-        new_p, new_o, loss_r, aux_r = lstep(params, opt_state, batch, lr)
-        if mask is not None:
-            new_p = freeze_inactive(new_p, params, mask)
-            new_o = freeze_inactive(new_o, opt_state, mask)
-        params, opt_state = new_p, new_o
+        params, opt_state, loss_r, aux_r = lstep(params, opt_state, batch,
+                                                 lr)
         if mode == Mode.PUSH:
             delta = jax.tree.map(
                 lambda p, a: p.astype(jnp.float32) - a.astype(jnp.float32),
                 params, anchor)
-            dmean = replica_mean(
-                delta, wire_format=cfg.wire_format_for(blocking=False),
-                impl=impl, int8_block=blk, use_kernels=kern, mask=mask,
-                deterministic=det, device_local=device_local)
+            dmean = replica_mean(delta, ex=ex, wire=ex.send_wire)
 
             def apply(a, d):
                 out = (a.astype(jnp.float32)
-                       + push_scale * n_active * d.astype(jnp.float32))
+                       + push_scale * ex.n_active * d.astype(jnp.float32))
                 if not jnp.issubdtype(a.dtype, jnp.floating):
                     out = jnp.round(out)
                 return out.astype(a.dtype)
 
             server = jax.tree.map(apply, anchor, dmean)
-            params = freeze_inactive(server, params, mask)
+            params = freeze_inactive(server, params, ex.mask)
             anchor = jax.tree.map(jnp.array, server)
         elif mode == Mode.BLOCKING:
-            params = blocking_sync(
-                params, wire_format=cfg.wire_format_for(blocking=True),
-                impl=impl, int8_block=blk, use_kernels=kern, mask=mask,
-                deterministic=det, device_local=device_local)
+            params = blocking_sync(params, ex=ex)
             anchor = jax.tree.map(jnp.array, params)
-        loss = _cross_replica_loss(cfg, mask, n_active, loss_r)
-        metrics = {"loss": loss, "loss_per_replica": loss_r}
-        return params, opt_state, anchor, _aux_metrics(
-            metrics, aux_r, mask, cfg.n_replicas, n_active)
+        return params, opt_state, anchor, _step_metrics(ex, loss_r, aux_r)
 
     return step
 
@@ -407,8 +347,7 @@ class GossipStrategy(PeriodicStrategy):
         raw = gossip_train_step(self.loss_fn, self.optimizer, self.cfg,
                                 mode=base, shift=max(shift, 1),
                                 n_micro=self.n_micro,
-                                membership=self._membership,
-                                device_local=self._device_local)
+                                ex=self.exchange)
 
         def step(carry, batch, lr):
             params, opt_state = carry
@@ -452,8 +391,7 @@ class EasgdStrategy(PeriodicStrategy):
         raw = easgd_train_step(self.loss_fn, self.optimizer, self.cfg,
                                mode=base, alpha=self.alpha,
                                n_micro=self.n_micro,
-                               membership=self._membership,
-                               device_local=self._device_local)
+                               ex=self.exchange)
 
         def step(carry, batch, lr):
             params, opt_state, center = carry
@@ -491,8 +429,7 @@ class DownpourStrategy(PeriodicStrategy):
         raw = downpour_train_step(self.loss_fn, self.optimizer, self.cfg,
                                   mode=base, push_scale=self.push_scale,
                                   n_micro=self.n_micro,
-                                  membership=self._membership,
-                                  device_local=self._device_local)
+                                  ex=self.exchange)
 
         def step(carry, batch, lr):
             params, opt_state, anchor = carry

@@ -28,8 +28,7 @@ vmap, and syncs hit the levels like this:
     tier (f32 | bf16 | int8 block-scaled) applied to the whole arena at
     once (kernels/comm_kernels.py). Where the replica axis never leaves
     one device there is no collective to coalesce, and the outermost mean
-    is taken leaf by leaf with the same arithmetic (`replica_mean`'s
-    `device_local`).
+    is taken leaf by leaf with the same arithmetic (`Exchange.leafwise`).
 
 Step variants (selected by the host-side controllers in core/schedule.py,
 mirroring the MPI process flow of paper Fig. 5; static per-variant
@@ -52,8 +51,10 @@ of whichever intermediate levels tick that step — empty for the paper's
 two-level layout, which keeps that case's compiled step graph identical to
 the pre-topology build.
 
-Every variant optionally bakes a static elastic-membership mask
-(`membership=` on `daso_train_step`): exchanges at every level become
+How every exchange of a step runs — wire tier per phase, layout, kernel
+tier, reduce order, membership and regrouping — is one static value,
+`Exchange`, decided once by `Exchange.of` and passed to every primitive.
+Its elastic-membership mask makes exchanges at every level
 membership-weighted means over the active replicas of each group (still one
 collective per sync per level), Eq. (1) runs with the effective world size,
 and dropped replicas' rows are frozen ghosts until a rejoin re-seeds them
@@ -62,7 +63,7 @@ and dropped replicas' rows are frozen ghosts until a rejoin re-seeds them
 from __future__ import annotations
 
 import functools
-from dataclasses import dataclass
+from dataclasses import dataclass, replace
 from typing import Callable, Optional, Tuple
 
 import jax
@@ -70,8 +71,6 @@ import jax.numpy as jnp
 
 from repro.core import flatbuf
 from repro.optim.optimizers import Optimizer
-
-EXCHANGE_IMPLS = ("fused", "per_leaf")
 
 # Compute/communication overlap of the outermost exchange (DasoConfig
 # .overlap). "off" keeps the paper-faithful in-cycle dataflow — the step
@@ -91,23 +90,14 @@ class DasoConfig:
     warmup_steps: int = 0
     cooldown_steps: int = 0
     total_steps: int = 0
-    compress_blocking: bool = True
-    # BEYOND-PAPER: the paper skips 16-bit packaging for non-blocking sends
-    # (MPI packaging delays the Isend). In SPMD/XLA the cast fuses into the
-    # collective with no launch delay, so compressing the cycling-phase
-    # exchange halves DCN bytes for free. Default False = paper-faithful.
-    compress_nonblocking: bool = False
     plateau_patience: int = 5
     plateau_threshold: float = 1e-3
-    # Wire format of the global exchange: None derives it from the
-    # compress_* flags per phase (bf16 or f32); "f32" | "bf16" | "int8"
-    # forces one tier for both phases. int8 is the beyond-paper
-    # block-scaled tier (QSGD-style, see core/flatbuf.py).
+    # Wire format of the global exchange: None is the paper's choice per
+    # phase (bf16 blocking, f32 cycling: the paper skips 16-bit packaging
+    # for non-blocking sends); "f32" | "bf16" | "int8" forces one tier for
+    # both phases. int8 is the beyond-paper block-scaled tier (QSGD-style,
+    # see core/flatbuf.py).
     wire_format: Optional[str] = None
-    # "fused" = flat-buffer arena exchange (one cross-replica reduction per
-    # global sync regardless of leaf count); "per_leaf" = the legacy
-    # one-collective-per-leaf reference path (equivalence oracle).
-    exchange_impl: str = "fused"
     # Transport-invariant exchanges: every cross-replica mean runs as an
     # explicitly associated chain of adds (flatbuf.chain_axis0_sum) instead
     # of one lax.reduce, so results are bit-identical for ANY process
@@ -122,7 +112,6 @@ class DasoConfig:
     # the cross-pod traffic audit (tests/test_distributed.py) relies on;
     # flip on for single-device arenas and compiled TPU kernels.
     exchange_kernels: bool = False
-    int8_block: int = 256        # elements per int8 scale block
     # True asynchronous overlap of the outermost exchange ("off" |
     # "one_cycle", see OVERLAP_MODES above). With "one_cycle" the strategy
     # carry grows a fourth slot (the `pending` snapshot arena) and the
@@ -135,22 +124,65 @@ class DasoConfig:
         if self.overlap not in OVERLAP_MODES:
             raise ValueError(f"unknown overlap mode {self.overlap!r}; "
                              f"expected one of {OVERLAP_MODES}")
-        if self.exchange_impl not in EXCHANGE_IMPLS:
-            raise ValueError(f"unknown exchange_impl "
-                             f"{self.exchange_impl!r}; "
-                             f"expected one of {EXCHANGE_IMPLS}")
-        if self.wire_format == "int8" and self.exchange_impl == "per_leaf":
-            raise ValueError("int8 wire format requires the fused arena "
-                             "exchange (exchange_impl='fused')")
 
-    def wire_format_for(self, *, blocking: bool) -> str:
-        """Resolve the wire tier of a global exchange: the explicit
-        `wire_format` if set, else bf16/f32 from the per-phase flag."""
-        if self.wire_format is not None:
-            return self.wire_format
-        flag = self.compress_blocking if blocking \
-            else self.compress_nonblocking
-        return "bf16" if flag else "f32"
+
+@dataclass(frozen=True)
+class Exchange:
+    """How every replica exchange of a step runs, decided once: the wire
+    tier of each phase, the layout, the kernel tier, the reduce order, and
+    the membership and regrouping baked into the step. Static and
+    hashable, like everything a compiled step variant closes over; built
+    only by `Exchange.of`."""
+    send_wire: str        # cycling phase: send, gossip, EASGD, DOWNPOUR
+    blocking_wire: str    # warm-up / cool-down blocking average
+    # the replica axis lies in one program on one device (no placement)
+    device_local: bool
+    kernels: bool         # Pallas exchange kernels (DasoConfig)
+    deterministic: bool   # order-fixed chain adds (DasoConfig)
+    # normalized membership mask (`flatbuf.normalize_membership`), None
+    # when every replica is active
+    mask: Optional[Tuple[float, ...]]
+    # normalized regrouping of the inner levels (`normalize_group_perm`),
+    # None for the contiguous grouping
+    perm: Optional[Tuple[int, ...]]
+    n_active: int         # active replicas
+    p_eff: float          # Eq. (1)'s P: global_world * n_active / R
+
+    @classmethod
+    def of(cls, cfg: DasoConfig, *, membership=None, group_perm=None,
+           device_local: bool = False) -> "Exchange":
+        """The exchange of `cfg` under a membership mask, a regrouping
+        permutation and a placement (`device_local`: the replica axis
+        never leaves one device)."""
+        r = cfg.n_replicas
+        mask = flatbuf.normalize_membership(membership, r)
+        n_active = r if mask is None else int(sum(mask))
+        return cls(send_wire=cfg.wire_format or "f32",
+                   blocking_wire=cfg.wire_format or "bf16",
+                   device_local=bool(device_local),
+                   kernels=cfg.exchange_kernels,
+                   deterministic=cfg.deterministic_reduce,
+                   mask=mask, perm=normalize_group_perm(group_perm, r),
+                   n_active=n_active,
+                   p_eff=(cfg.global_world if mask is None
+                          else cfg.global_world * n_active / r))
+
+    @property
+    def leafwise(self) -> bool:
+        """Whether the outermost replica means are taken leaf by leaf
+        rather than over the packed arena: where the replica axis never
+        leaves one device (no collective to coalesce, so packing is pure
+        copying), except for the int8 wire (its per-block scales cross
+        leaf boundaries) and the Pallas kernels (which run over the
+        arena)."""
+        return (self.device_local and self.send_wire != "int8"
+                and not self.kernels)
+
+    def plain(self) -> "Exchange":
+        """This exchange on the f32 wire without the kernels: the naive
+        average of `hard_avg`, laid out by the same rule."""
+        return replace(self, send_wire="f32", blocking_wire="f32",
+                       kernels=False)
 
 
 # -- replica-axis helpers ----------------------------------------------------
@@ -178,23 +210,8 @@ def dereplicate_params(params, index: int = 0):
     return jax.tree.map(lambda p: p[index], params)
 
 
-def _wire_format_from(wire_dtype, wire_format) -> str:
-    """Back-compat shim: map the legacy `wire_dtype` argument (None /
-    jnp.bfloat16) onto the wire-format tiers."""
-    if wire_format is not None:
-        return flatbuf._check_wire_format(wire_format)
-    if wire_dtype is None:
-        return "f32"
-    if jnp.dtype(wire_dtype) == jnp.dtype(jnp.bfloat16):
-        return "bf16"
-    if jnp.dtype(wire_dtype) == jnp.dtype(jnp.float32):
-        return "f32"
-    raise ValueError(f"unsupported wire_dtype {wire_dtype!r}; use "
-                     f"wire_format={flatbuf.WIRE_FORMATS}")
-
-
-def _arena_mean(arena, wire_format: str, *, int8_block: int,
-                use_kernels: bool, mask=None, deterministic: bool = False):
+def _arena_mean(arena, wire_format: str, *, use_kernels: bool, mask=None,
+                deterministic: bool = False):
     """Mean over the leading replica axis of one arena, kept as a (1, N)
     buffer (the caller broadcasts per leaf after unpacking — one full-size
     materialization instead of two). Exactly one axis-0 reduction per
@@ -219,8 +236,7 @@ def _arena_mean(arena, wire_format: str, *, int8_block: int,
         # dequantized values in f32. Round-to-nearest (no rng_key): the
         # step variants are statically specialized and take no RNG, so the
         # unbiased stochastic tier stays a codec/kernel-API option.
-        deq = flatbuf.wire_roundtrip(arena, "int8", int8_block=int8_block,
-                                     use_kernels=use_kernels)
+        deq = flatbuf.wire_roundtrip(arena, "int8", use_kernels=use_kernels)
         return flatbuf.masked_axis0_mean(
             deq, mask, deterministic).astype(arena.dtype)
     # Pin the reduction computation dtype by reducing the wire-cast arena
@@ -245,81 +261,58 @@ def _stored_f32(x):
     return jax.lax.bitcast_convert_type(bits << 16, jnp.float32)
 
 
-def _leaf_mean(x, wire_dtype, mask, deterministic: bool):
+def _leaf_mean(x, wire: str, mask, deterministic: bool):
     """Mean over the leading replica axis of one leaf, broadcast back to
     its shape: `_arena_mean`'s arithmetic on one leaf. A floating leaf is
-    reduced in `wire_dtype` (None: its own dtype) with the scale applied
-    in that dtype; any other leaf in f32 and rounded back, as the arena
-    rounds it (an int-dtype reduce would truncate the 1/R scale to 0).
+    reduced in bf16 on the bf16 wire, else in its own dtype, with the
+    scale applied in that dtype; any other leaf in f32 and rounded back,
+    as the arena rounds it (an int-dtype reduce would truncate the 1/R
+    scale to 0).
 
     A bf16 leaf enters the reduction through its bits (`_stored_f32`)."""
     if jnp.issubdtype(x.dtype, jnp.floating):
         w = _stored_f32(x) if x.dtype == jnp.bfloat16 else x
-        m = flatbuf.masked_axis0_mean(w.astype(wire_dtype or x.dtype), mask,
-                                      deterministic)
+        dtype = jnp.bfloat16 if wire == "bf16" else x.dtype
+        m = flatbuf.masked_axis0_mean(w.astype(dtype), mask, deterministic)
     else:
         m = jnp.round(flatbuf.masked_axis0_mean(x.astype(jnp.float32), mask,
                                                 deterministic))
     return jnp.broadcast_to(m.astype(x.dtype), x.shape)
 
 
-def replica_mean_per_leaf(tree, wire_dtype=None, mask=None,
+def replica_mean_per_leaf(tree, wire: str = "f32", mask=None,
                           deterministic: bool = False):
     """Per-leaf exchange: one axis-0 reduction PER LEAF, bit-identical to
     the arena mean for the f32/bf16 wire. `replica_mean` takes it where the
-    replica axis never leaves the device (no collective to coalesce, so
-    packing the arena is pure copying) and for `impl="per_leaf"`, the
-    equivalence oracle and microbenchmark baseline of the fused path.
-    `mask` applies the same membership weighting as the fused path."""
-    return jax.tree.map(
-        lambda x: _leaf_mean(x, wire_dtype, mask, deterministic), tree)
+    replica axis never leaves the device (`Exchange.leafwise`: no
+    collective to coalesce, so packing the arena is pure copying); it is
+    also the equivalence oracle and microbenchmark baseline of the arena
+    path. `mask` applies the same membership weighting as the arena."""
+    if wire not in ("f32", "bf16"):
+        raise ValueError(f"the per-leaf mean takes the f32 or bf16 wire, "
+                         f"got {wire!r} (int8 scales cross leaf "
+                         f"boundaries)")
+    return jax.tree.map(lambda x: _leaf_mean(x, wire, mask, deterministic),
+                        tree)
 
 
-def leafwise_exchange(wire_format, *, impl: str, use_kernels: bool,
-                      device_local: bool) -> bool:
-    """Whether `replica_mean` takes the mean leaf by leaf rather than over
-    the packed arena: for `impl="per_leaf"`, and where the replica axis
-    lies in one program on one device (`device_local`), except for the
-    int8 wire (its per-block scales cross leaf boundaries) and the Pallas
-    exchange kernels (which run over the arena). `wire_format` None is
-    the per-phase f32/bf16 choice."""
-    return impl == "per_leaf" or (device_local and wire_format != "int8"
-                                  and not use_kernels)
+def replica_mean(tree, *, ex: Exchange, wire: str):
+    """Mean over the leading replica axis at the `wire` tier ("f32" |
+    "bf16" | "int8"), broadcast back; `ex.mask` restricts it to the active
+    replicas, the elastic-membership exchange.
 
-
-def replica_mean(tree, wire_dtype=None, *, wire_format=None,
-                 impl: str = "fused", int8_block: int = 256,
-                 use_kernels: bool = False, mask=None,
-                 deterministic: bool = False, device_local: bool = False):
-    """Mean over the leading replica axis, broadcast back.
-
-    Default path packs the pytree into one contiguous arena per dtype
-    (core/flatbuf.py) so the whole exchange is ONE cross-replica reduction
-    regardless of leaf count; `wire_format` ("f32" | "bf16" | "int8")
-    selects the transfer tier. `impl="per_leaf"` restores the legacy
-    one-collective-per-leaf reference path. `wire_dtype` is the legacy
-    spelling (None = uncompressed, jnp.bfloat16 = 16-bit packaging).
-    `mask` (normalized membership tuple, or None = all active) restricts
-    the mean to active replicas — the elastic-membership exchange.
-
-    `device_local=True` says the replica axis lies in one program on one
-    device, so no collective can be coalesced: the mean is then taken
-    leaf by leaf (`leafwise_exchange`), bit-identical to the arena's,
-    without the arena's pack, unpack and zero-filled (R, N) buffer."""
-    wf = _wire_format_from(wire_dtype, wire_format)
-    if impl == "per_leaf" and wf == "int8":
-        raise ValueError("int8 wire format requires the fused arena "
-                         "exchange (impl='fused')")
-    if leafwise_exchange(wf, impl=impl, use_kernels=use_kernels,
-                         device_local=device_local):
-        return replica_mean_per_leaf(
-            tree, jnp.bfloat16 if wf == "bf16" else None, mask=mask,
-            deterministic=deterministic)
+    Laid out as `ex.leafwise` says: leaf by leaf, bit-identical to the
+    arena's, without the arena's pack, unpack and zero-filled (R, N)
+    buffer; else over one contiguous arena per dtype (core/flatbuf.py), so
+    the whole exchange is ONE cross-replica reduction regardless of leaf
+    count."""
+    if ex.leafwise:
+        return replica_mean_per_leaf(tree, wire, mask=ex.mask,
+                                     deterministic=ex.deterministic)
     layout = flatbuf.build_layout(tree, batch_dims=1)
     arenas = flatbuf.pack(tree, layout)
-    out = {k: _arena_mean(a, wf, int8_block=int8_block,
-                          use_kernels=use_kernels, mask=mask,
-                          deterministic=deterministic)
+    out = {k: _arena_mean(a, wire, use_kernels=ex.kernels, mask=ex.mask,
+                          deterministic=ex.deterministic)
            for k, a in arenas.items()}
     # unpack the (1, N) means, then broadcast per leaf: the broadcast fuses
     # into each leaf's consumer instead of materializing a second full-size
@@ -410,9 +403,7 @@ def _permuted_group_mean(arena, group_size: int, mask, deterministic: bool,
 
 
 @_scope("repro.exchange.level")
-def level_group_mean(tree, group_size: int, *, wire_format: str = "f32",
-                     use_kernels: bool = False, mask=None,
-                     deterministic: bool = False, perm=None):
+def level_group_mean(tree, group_size: int, *, ex: Exchange):
     """Synchronous parameter average over contiguous replica groups of
     `group_size` — the sync primitive of one intermediate topology level
     (repro/topo: group_size = prod of replica-level fanouts up to the
@@ -420,36 +411,30 @@ def level_group_mean(tree, group_size: int, *, wire_format: str = "f32",
     that level; inner levels vary fastest in the replica index).
 
     Runs on the fused flat-buffer arenas, one group reduction per arena
-    regardless of leaf count. `wire_format` selects the tier-l transfer
-    dtype ("f32" default — intermediate links are fast; "bf16" for the
-    paper-style 16-bit packaging; int8 is outermost-only). `group_size ==
-    R` degenerates to the full replica mean (= `replica_mean`).
+    regardless of leaf count, in f32: intermediate links are fast, so
+    neither the wire tiers nor the kernels of `ex` apply, and it packs the
+    arena whatever `ex.leafwise` says. `group_size == R` degenerates to
+    the full replica mean.
 
-    `perm` (see `normalize_group_perm`) regroups the replicas before the
-    mean: slot order replaces replica order, so which replicas share a
+    `ex.perm` (see `normalize_group_perm`) regroups the replicas before
+    the mean: slot order replaces replica order, so which replicas share a
     group becomes a static schedule choice — the straggler-aware
     reshuffle knob (repro.topo.probe.skew_permutation). Every group mean
     preserves its group's sum and the groups partition the rows, so the
     exact global mean is invariant under ANY permutation
     (tests/test_tuning.py pins this as a hypothesis property)."""
-    if wire_format not in ("f32", "bf16"):
-        raise ValueError("level_group_mean supports wire_format 'f32' | "
-                         f"'bf16', got {wire_format!r} (the int8 tier is "
-                         "for the outermost exchange)")
     layout = flatbuf.build_layout(tree, batch_dims=1)
     arenas = flatbuf.pack(tree, layout)
-    perm = normalize_group_perm(perm, layout.batch_shape[0])
+    mask, det, perm = ex.mask, ex.deterministic, ex.perm
     out = {}
     for k, a in arenas.items():
         if not jnp.issubdtype(a.dtype, jnp.floating):
             w = a.astype(jnp.float32)
             out[k] = jnp.round(_permuted_group_mean(
-                w, group_size, mask, deterministic, perm)).astype(a.dtype)
+                w, group_size, mask, det, perm)).astype(a.dtype)
             continue
-        w = (flatbuf.encode_wire(a, "bf16", use_kernels=use_kernels)
-             if wire_format == "bf16" else a)
-        out[k] = _permuted_group_mean(w, group_size, mask,
-                                      deterministic, perm).astype(a.dtype)
+        out[k] = _permuted_group_mean(a, group_size, mask, det,
+                                      perm).astype(a.dtype)
     return flatbuf.unpack(out, layout)
 
 
@@ -484,21 +469,11 @@ def freeze_inactive(new_tree, old_tree, mask):
 # -- DASO primitive operations ------------------------------------------------
 
 @_scope("repro.exchange.send")
-def global_send(params, *, compress: bool = False, wire_format=None,
-                impl: str = "fused", int8_block: int = 256,
-                use_kernels: bool = False, mask=None,
-                deterministic: bool = False, device_local: bool = False):
+def global_send(params, *, ex: Exchange):
     """Snapshot + start global exchange: returns the in-flight buffer
-    (replica mean of current params, one copy per replica). The wire tier
-    comes from `wire_format` (or legacy compress=True -> bf16,
-    beyond-paper for the non-blocking path, see DasoConfig). `mask`
-    restricts the mean to active replicas (elastic membership);
-    `device_local` as in `replica_mean`."""
-    wf = wire_format or ("bf16" if compress else "f32")
-    return replica_mean(params, wire_format=wf, impl=impl,
-                        int8_block=int8_block, use_kernels=use_kernels,
-                        mask=mask, deterministic=deterministic,
-                        device_local=device_local)
+    (replica mean of current params, one copy per replica) at the cycling
+    tier `ex.send_wire`, over the active replicas only."""
+    return replica_mean(params, ex=ex, wire=ex.send_wire)
 
 
 def global_receive_per_leaf(params, inflight, *, staleness: int,
@@ -520,34 +495,29 @@ def global_receive_per_leaf(params, inflight, *, staleness: int,
 
 
 @_scope("repro.exchange.receive")
-def global_receive(params, inflight, *, staleness: int, global_world,
-                   impl: str = "fused", use_kernels: bool = False,
-                   mask=None, extra_staleness: int = 0):
+def global_receive(params, inflight, *, ex: Exchange, staleness: int,
+                   extra_staleness: int = 0):
     """Paper Eq. (1): weighted merge of stale global average with current
-    local params. staleness S = batches waited; global_world P — a float
-    under elastic membership (the effective P of the surviving world,
-    `global_world * n_active / n_replicas`), so the merge weighting tracks
-    dynamic membership. Dropped replicas' rows stay frozen (`mask`).
-    `extra_staleness` is the overlap executor's one-cycle buffer age — it
-    adds to S in the weighting (the stale buffer really is that much
-    older); 0 keeps the pre-overlap merge bit-exact.
+    local params. staleness S = batches waited; P is `ex.p_eff`, the
+    effective world of the surviving replicas under elastic membership
+    (`global_world * n_active / n_replicas`), so the merge weighting
+    tracks dynamic membership. Dropped replicas' rows stay frozen
+    (`ex.mask`). `extra_staleness` is the overlap executor's one-cycle
+    buffer age — it adds to S in the weighting (the stale buffer really is
+    that much older); 0 keeps the pre-overlap merge bit-exact.
 
     The merge has no collective, so in jnp-land XLA already fuses the
     leaf-wise multiply-add chains into one elementwise pass — packing an
-    arena would only add two copies. With `use_kernels=True` the merge
-    runs as ONE Pallas `eq1_merge` program over the packed arena (the
-    TPU-kernel tier, where a single contiguous launch is the point)."""
-    if impl == "per_leaf":
-        merged = global_receive_per_leaf(params, inflight,
-                                         staleness=staleness,
-                                         global_world=global_world,
-                                         extra_staleness=extra_staleness)
-        return freeze_inactive(merged, params, mask)
+    arena would only add two copies; `ex.leafwise` and the wire tiers do
+    not apply. With `ex.kernels` the merge runs as ONE Pallas `eq1_merge`
+    program over the packed arena (the TPU-kernel tier, where a single
+    contiguous launch is the point)."""
     from repro.kernels.ref import eq1_merge_ref
-    if not use_kernels:
+    p_eff, mask = ex.p_eff, ex.mask
+    if not ex.kernels:
         merged = jax.tree.map(
             lambda a, b: eq1_merge_ref(a, b, staleness=staleness,
-                                       global_world=global_world,
+                                       global_world=p_eff,
                                        extra_staleness=extra_staleness),
             params, inflight)
         return freeze_inactive(merged, params, mask)
@@ -556,31 +526,23 @@ def global_receive(params, inflight, *, staleness: int, global_world,
     locals_ = flatbuf.pack(params, layout)
     stales = flatbuf.pack(inflight, layout)
     out = {k: (eq1_merge(a, stales[k], staleness=staleness,
-                         global_world=global_world,
+                         global_world=p_eff,
                          extra_staleness=extra_staleness)
                if jnp.issubdtype(a.dtype, jnp.floating) else
                eq1_merge_ref(a, stales[k], staleness=staleness,
-                             global_world=global_world,
+                             global_world=p_eff,
                              extra_staleness=extra_staleness))
            for k, a in locals_.items()}
     return freeze_inactive(flatbuf.unpack(out, layout), params, mask)
 
 
 @_scope("repro.exchange.blocking")
-def blocking_sync(params, *, compress: bool = True, wire_format=None,
-                  impl: str = "fused", int8_block: int = 256,
-                  use_kernels: bool = False, mask=None,
-                  deterministic: bool = False, device_local: bool = False):
-    """Synchronous global average (warm-up / cool-down), with the paper's
-    16-bit transfer compression (or the tier in `wire_format`). `mask`
-    restricts the average to active replicas and freezes dropped rows;
-    `device_local` as in `replica_mean`."""
-    wf = wire_format or ("bf16" if compress else "f32")
-    synced = replica_mean(params, wire_format=wf, impl=impl,
-                          int8_block=int8_block, use_kernels=use_kernels,
-                          mask=mask, deterministic=deterministic,
-                          device_local=device_local)
-    return freeze_inactive(synced, params, mask)
+def blocking_sync(params, *, ex: Exchange):
+    """Synchronous global average (warm-up / cool-down) at the blocking
+    tier `ex.blocking_wire` — by default the paper's 16-bit transfer
+    compression — over the active replicas, dropped rows frozen."""
+    synced = replica_mean(params, ex=ex, wire=ex.blocking_wire)
+    return freeze_inactive(synced, params, ex.mask)
 
 
 # -- assembled train step ------------------------------------------------------
@@ -658,8 +620,7 @@ MODES = ("local", "send", "receive", "send_receive", "blocking", "hard_avg")
 OV_MODES = ("local", "ov_start", "ov_sync", "blocking")
 
 
-def _cross_replica_loss(cfg: DasoConfig, mask, n_active: int,
-                        loss_r, *, axis: int = 0):
+def _cross_replica_loss(ex: Exchange, loss_r, *, axis: int = 0):
     """The scalar training loss the plateau controller consumes: the mean
     of the per-replica losses over the ACTIVE replicas, reduced along
     `axis` (the replica axis). Shared by the in-step metric block of
@@ -669,26 +630,78 @@ def _cross_replica_loss(cfg: DasoConfig, mask, n_active: int,
     requires the compute program to be collective-free). Deterministic
     mode uses the same order-fixed chain adds in both places, so deferring
     the reduction is bit-exact."""
-    det = cfg.deterministic_reduce
-    w_l = (jnp.ones((cfg.n_replicas,), loss_r.dtype) if mask is None
+    r, mask = loss_r.shape[axis], ex.mask
+    w_l = (jnp.ones((r,), loss_r.dtype) if mask is None
            else jnp.asarray(mask, loss_r.dtype))
     if axis != 0:
         loss_r = jnp.moveaxis(loss_r, axis, 0)
-    shape = (cfg.n_replicas,) + (1,) * (loss_r.ndim - 1)
+    shape = (r,) + (1,) * (loss_r.ndim - 1)
     weighted = loss_r * w_l.reshape(shape)
-    if det:
-        return flatbuf.chain_axis0_sum(weighted) / n_active
+    if ex.deterministic:
+        return flatbuf.chain_axis0_sum(weighted) / ex.n_active
     if mask is None:
         return jnp.mean(loss_r, axis=0)
-    return jnp.sum(weighted, axis=0) / n_active
+    return jnp.sum(weighted, axis=0) / ex.n_active
+
+
+def _step_metrics(ex: Exchange, loss_r, aux_r):
+    """The step's metrics: the cross-replica loss, the per-replica losses,
+    and each scalar or vector aux metric averaged over the replicas — the
+    active ones where it has one entry per replica."""
+    metrics = {"loss": _cross_replica_loss(ex, loss_r),
+               "loss_per_replica": loss_r}
+    mask = ex.mask
+    for k, v in aux_r.items():
+        if isinstance(v, jnp.ndarray) and v.ndim <= 1:
+            if (mask is not None and v.ndim == 1
+                    and v.shape[0] == len(mask)):
+                metrics[k] = jnp.sum(
+                    v * jnp.asarray(mask, v.dtype)) / ex.n_active
+            else:
+                metrics[k] = jnp.mean(v)
+    return metrics
+
+
+def _member_step(loss_fn: Callable, optimizer: Optimizer, ex: Exchange, *,
+                spmd_axis_name: Optional[str] = None, n_micro: int = 1):
+    """`local_step` with dropped replicas' rows (`ex.mask`) frozen: the
+    local half of every replica-axis step variant."""
+    lstep = local_step(loss_fn, optimizer, spmd_axis_name=spmd_axis_name,
+                       n_micro=n_micro)
+    if ex.mask is None:
+        return lstep
+
+    def step(params, opt_state, batch, lr):
+        new_p, new_o, loss_r, aux_r = lstep(params, opt_state, batch, lr)
+        return (freeze_inactive(new_p, params, ex.mask),
+                freeze_inactive(new_o, opt_state, ex.mask), loss_r, aux_r)
+
+    return step
+
+
+def _inner_level_syncs(inner_syncs: Tuple[Tuple[str, int], ...],
+                       n_replicas: int, ex: Exchange):
+    """params -> params after the synchronous group mean of every inner
+    level in `inner_syncs`, innermost first, dropped rows frozen."""
+    for name, g in inner_syncs:
+        if not 1 < g <= n_replicas:
+            raise ValueError(f"inner sync {name!r}: group size {g} outside "
+                             f"2..{n_replicas}")
+
+    def sync(params):
+        for _name, g in inner_syncs:
+            params = freeze_inactive(level_group_mean(params, g, ex=ex),
+                                     params, ex.mask)
+        return params
+
+    return sync
 
 
 def daso_train_step(loss_fn: Callable, optimizer: Optimizer, cfg: DasoConfig,
                     *, mode: str, staleness: int = 1,
                     spmd_axis_name: Optional[str] = None, n_micro: int = 1,
-                    membership=None,
-                    inner_syncs: Tuple[Tuple[str, int], ...] = (),
-                    group_perm=None, device_local: bool = False):
+                    ex: Optional[Exchange] = None,
+                    inner_syncs: Tuple[Tuple[str, int], ...] = ()):
     """Build one statically-specialized DASO step function.
 
     step(params_R, opt_R, inflight, batch_R, lr)
@@ -704,87 +717,39 @@ def daso_train_step(loss_fn: Callable, optimizer: Optimizer, cfg: DasoConfig,
     always for 2-level topologies) adds nothing: the compiled graph is the
     pre-topology one.
 
-    `membership` (optional 0/1 mask over the R replicas) bakes elastic
-    membership into the compiled step: exchanges at every level become
-    membership-weighted means over the active set, Eq. (1) runs with the
-    effective world size P_eff = P * n_active / R, dropped replicas' rows
-    are frozen, and the reported loss averages active replicas only. The
-    mask is a *static* constant — a membership change compiles new step
-    variants (the executor invalidates its cycle cache, see
-    resilience/supervisor.py), which keeps the fixed-membership HLO
-    bit-identical to the non-elastic build.
-
-    `group_perm` (normalize_group_perm) statically regroups the replicas
-    for every inner-level sync — the straggler-aware reshuffle. Like the
-    membership mask it is baked into the compiled step; changing it means
-    new variants (DasoStrategy.set_group_permutation).
-
-    `device_local` (static, `replica_mean`) says the replica axis lies in
-    this program on one device: the outermost replica means (send,
-    blocking, hard_avg) are then taken leaf by leaf instead of over the
-    packed arena (DasoStrategy.set_device_local)."""
+    `ex` (default `Exchange.of(cfg)`) is baked into the compiled step. Its
+    membership mask makes exchanges at every level membership-weighted
+    means over the active set, Eq. (1) runs with the effective world size
+    P_eff = P * n_active / R, dropped replicas' rows are frozen, and the
+    reported loss averages active replicas only; its permutation regroups
+    the replicas of every inner-level sync; its layout says whether the
+    outermost means go leaf by leaf. A new exchange compiles new step
+    variants (the strategy drops its step cache and the executor
+    invalidates its cycle cache, see resilience/supervisor.py), which
+    keeps the fixed-membership HLO bit-identical to the non-elastic
+    build."""
     assert mode in MODES, mode
-    lstep = local_step(loss_fn, optimizer, spmd_axis_name=spmd_axis_name,
-                       n_micro=n_micro)
-
-    impl, kern, blk = (cfg.exchange_impl, cfg.exchange_kernels,
-                       cfg.int8_block)
-    det = cfg.deterministic_reduce
-    perm = normalize_group_perm(group_perm, cfg.n_replicas)
-    mask = flatbuf.normalize_membership(membership, cfg.n_replicas)
-    n_active = cfg.n_replicas if mask is None else int(sum(mask))
-    p_eff = (cfg.global_world if mask is None
-             else cfg.global_world * n_active / cfg.n_replicas)
-    for _name, g in inner_syncs:
-        if not 1 < g <= cfg.n_replicas:
-            raise ValueError(f"inner sync {_name!r}: group size {g} outside "
-                             f"2..{cfg.n_replicas}")
+    ex = Exchange.of(cfg) if ex is None else ex
+    lstep = _member_step(loss_fn, optimizer, ex,
+                        spmd_axis_name=spmd_axis_name, n_micro=n_micro)
+    sync_inner = _inner_level_syncs(inner_syncs, cfg.n_replicas, ex)
 
     def step(params, opt_state, inflight, batch, lr):
         if mode in ("receive", "send_receive"):
-            params = global_receive(params, inflight,
-                                    staleness=staleness,
-                                    global_world=p_eff,
-                                    impl=impl, use_kernels=kern, mask=mask)
-        new_p, new_o, loss_r, aux_r = lstep(params, opt_state, batch, lr)
-        if mask is not None:
-            new_p = freeze_inactive(new_p, params, mask)
-            new_o = freeze_inactive(new_o, opt_state, mask)
-        params, opt_state = new_p, new_o
-        for _name, g in inner_syncs:
-            params = freeze_inactive(
-                level_group_mean(params, g, use_kernels=kern, mask=mask,
-                                 deterministic=det, perm=perm),
-                params, mask)
+            params = global_receive(params, inflight, ex=ex,
+                                    staleness=staleness)
+        params, opt_state, loss_r, aux_r = lstep(params, opt_state, batch,
+                                                 lr)
+        params = sync_inner(params)
         if mode in ("send", "send_receive"):
-            inflight = global_send(
-                params, wire_format=cfg.wire_format_for(blocking=False),
-                impl=impl, int8_block=blk, use_kernels=kern, mask=mask,
-                deterministic=det, device_local=device_local)
+            inflight = global_send(params, ex=ex)
         elif mode == "blocking":
-            params = blocking_sync(
-                params, wire_format=cfg.wire_format_for(blocking=True),
-                impl=impl, int8_block=blk, use_kernels=kern, mask=mask,
-                deterministic=det, device_local=device_local)
+            params = blocking_sync(params, ex=ex)
         elif mode == "hard_avg":
-            with jax.named_scope("repro.exchange.blocking"):
-                params = freeze_inactive(
-                    replica_mean(params, impl=impl, mask=mask,
-                                 deterministic=det,
-                                 device_local=device_local), params, mask)
+            params = blocking_sync(params, ex=ex.plain())
         # the reported loss feeds the plateau controller on the host, so
         # it needs the same transport invariance as the exchanges
-        loss = _cross_replica_loss(cfg, mask, n_active, loss_r)
-        metrics = {"loss": loss, "loss_per_replica": loss_r}
-        for k, v in aux_r.items():
-            if isinstance(v, jnp.ndarray) and v.ndim <= 1:
-                if (mask is not None and v.ndim == 1
-                        and v.shape[0] == cfg.n_replicas):
-                    metrics[k] = jnp.sum(
-                        v * jnp.asarray(mask, v.dtype)) / n_active
-                else:
-                    metrics[k] = jnp.mean(v)
-        return params, opt_state, inflight, metrics
+        return params, opt_state, inflight, _step_metrics(ex, loss_r, aux_r)
 
     return step
 
@@ -793,9 +758,8 @@ def daso_overlap_step(loss_fn: Callable, optimizer: Optimizer,
                       cfg: DasoConfig, *, mode: str, staleness: int = 1,
                       extra_staleness: int = 0,
                       spmd_axis_name: Optional[str] = None, n_micro: int = 1,
-                      membership=None,
-                      inner_syncs: Tuple[Tuple[str, int], ...] = (),
-                      group_perm=None, device_local: bool = False):
+                      ex: Optional[Exchange] = None,
+                      inner_syncs: Tuple[Tuple[str, int], ...] = ()):
     """Build one step variant of the double-buffered overlap schedule
     (DasoConfig.overlap == "one_cycle"). The carry grows a fourth slot —
     the `pending` snapshot arena awaiting its exchange:
@@ -824,63 +788,29 @@ def daso_overlap_step(loss_fn: Callable, optimizer: Optimizer,
     The merge lands AFTER the step's local update (off-mode `receive`
     merges before it): the exchange result arrives at the cycle boundary,
     which is exactly when the macro executor joins the in-flight
-    collective with the computed params. `device_local` as in
-    `daso_train_step`."""
+    collective with the computed params. `ex` as in `daso_train_step`."""
     assert mode in OV_MODES, mode
-    lstep = local_step(loss_fn, optimizer, spmd_axis_name=spmd_axis_name,
-                       n_micro=n_micro)
-    impl, kern, blk = (cfg.exchange_impl, cfg.exchange_kernels,
-                       cfg.int8_block)
-    det = cfg.deterministic_reduce
-    perm = normalize_group_perm(group_perm, cfg.n_replicas)
-    mask = flatbuf.normalize_membership(membership, cfg.n_replicas)
-    n_active = cfg.n_replicas if mask is None else int(sum(mask))
-    p_eff = (cfg.global_world if mask is None
-             else cfg.global_world * n_active / cfg.n_replicas)
-    for _name, g in inner_syncs:
-        if not 1 < g <= cfg.n_replicas:
-            raise ValueError(f"inner sync {_name!r}: group size {g} outside "
-                             f"2..{cfg.n_replicas}")
+    ex = Exchange.of(cfg) if ex is None else ex
+    lstep = _member_step(loss_fn, optimizer, ex,
+                        spmd_axis_name=spmd_axis_name, n_micro=n_micro)
+    sync_inner = _inner_level_syncs(inner_syncs, cfg.n_replicas, ex)
 
     def step(params, opt_state, inflight, pending, batch, lr):
-        new_p, new_o, loss_r, aux_r = lstep(params, opt_state, batch, lr)
-        if mask is not None:
-            new_p = freeze_inactive(new_p, params, mask)
-            new_o = freeze_inactive(new_o, opt_state, mask)
-        params, opt_state = new_p, new_o
-        for _name, g in inner_syncs:
-            params = freeze_inactive(
-                level_group_mean(params, g, use_kernels=kern, mask=mask,
-                                 deterministic=det, perm=perm),
-                params, mask)
+        params, opt_state, loss_r, aux_r = lstep(params, opt_state, batch,
+                                                 lr)
+        params = sync_inner(params)
         if mode == "ov_start":
             pending = params
         elif mode == "ov_sync":
-            inflight = global_send(
-                pending, wire_format=cfg.wire_format_for(blocking=False),
-                impl=impl, int8_block=blk, use_kernels=kern, mask=mask,
-                deterministic=det, device_local=device_local)
-            params = global_receive(params, inflight, staleness=staleness,
-                                    extra_staleness=extra_staleness,
-                                    global_world=p_eff, impl=impl,
-                                    use_kernels=kern, mask=mask)
+            inflight = global_send(pending, ex=ex)
+            params = global_receive(params, inflight, ex=ex,
+                                    staleness=staleness,
+                                    extra_staleness=extra_staleness)
             pending = params
         elif mode == "blocking":
-            params = blocking_sync(
-                params, wire_format=cfg.wire_format_for(blocking=True),
-                impl=impl, int8_block=blk, use_kernels=kern, mask=mask,
-                deterministic=det, device_local=device_local)
-        loss = _cross_replica_loss(cfg, mask, n_active, loss_r)
-        metrics = {"loss": loss, "loss_per_replica": loss_r}
-        for k, v in aux_r.items():
-            if isinstance(v, jnp.ndarray) and v.ndim <= 1:
-                if (mask is not None and v.ndim == 1
-                        and v.shape[0] == cfg.n_replicas):
-                    metrics[k] = jnp.sum(
-                        v * jnp.asarray(mask, v.dtype)) / n_active
-                else:
-                    metrics[k] = jnp.mean(v)
-        return params, opt_state, inflight, pending, metrics
+            params = blocking_sync(params, ex=ex)
+        return (params, opt_state, inflight, pending,
+                _step_metrics(ex, loss_r, aux_r))
 
     return step
 
@@ -888,10 +818,10 @@ def daso_overlap_step(loss_fn: Callable, optimizer: Optimizer,
 def daso_overlap_compute_step(loss_fn: Callable, optimizer: Optimizer,
                               cfg: DasoConfig, *,
                               spmd_axis_name: Optional[str] = None,
-                              n_micro: int = 1, membership=None,
+                              n_micro: int = 1,
+                              ex: Optional[Exchange] = None,
                               inner_syncs: Tuple[Tuple[str, int],
-                                                 ...] = (),
-                              group_perm=None):
+                                                 ...] = ()):
     """The compute-program half of one overlap-dispatched macro-cycle:
 
     step(params_R, opt_R, batch_R, lr) -> (params_R, opt_R, metrics)
@@ -910,25 +840,15 @@ def daso_overlap_compute_step(loss_fn: Callable, optimizer: Optimizer,
     axis). Inner-level syncs stay: the overlap dispatch validator requires
     them to be process-local (launch.distributed.check_overlap_topology),
     where they lower to in-process collectives gloo never sees."""
-    lstep = local_step(loss_fn, optimizer, spmd_axis_name=spmd_axis_name,
-                       n_micro=n_micro)
-    kern = cfg.exchange_kernels
-    det = cfg.deterministic_reduce
-    perm = normalize_group_perm(group_perm, cfg.n_replicas)
-    mask = flatbuf.normalize_membership(membership, cfg.n_replicas)
+    ex = Exchange.of(cfg) if ex is None else ex
+    lstep = _member_step(loss_fn, optimizer, ex,
+                        spmd_axis_name=spmd_axis_name, n_micro=n_micro)
+    sync_inner = _inner_level_syncs(inner_syncs, cfg.n_replicas, ex)
 
     def step(params, opt_state, batch, lr):
-        new_p, new_o, loss_r, _aux_r = lstep(params, opt_state, batch, lr)
-        if mask is not None:
-            new_p = freeze_inactive(new_p, params, mask)
-            new_o = freeze_inactive(new_o, opt_state, mask)
-        params, opt_state = new_p, new_o
-        for _name, g in inner_syncs:
-            params = freeze_inactive(
-                level_group_mean(params, g, use_kernels=kern, mask=mask,
-                                 deterministic=det, perm=perm),
-                params, mask)
-        return params, opt_state, {"loss_per_replica": loss_r}
+        params, opt_state, loss_r, _aux_r = lstep(params, opt_state, batch,
+                                                  lr)
+        return sync_inner(params), opt_state, {"loss_per_replica": loss_r}
 
     return step
 

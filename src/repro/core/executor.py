@@ -46,11 +46,10 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import flatbuf
-from repro.core.daso import (DasoConfig, _cross_replica_loss,
+from repro.core.daso import (DasoConfig, Exchange, _cross_replica_loss,
                              daso_overlap_compute_step, daso_overlap_step,
                              daso_train_step, dereplicate_params,
-                             global_receive, global_send, leafwise_exchange,
-                             normalize_group_perm, replica_divergence,
+                             global_receive, global_send, replica_divergence,
                              replicate_params, sync_train_step)
 from repro.core.schedule import (DasoController, Mode, is_ov_mode, join_mode,
                                  split_mode, split_ov)
@@ -208,90 +207,84 @@ class DasoStrategy(Strategy):
     """Paper strategy: replica-axis carry (params, opt_state, inflight),
     `DasoController`-planned cycles, step variants from core/daso.py.
 
-    The DasoConfig carries the fused-exchange knobs (`wire_format`,
-    `exchange_impl`, `int8_block`, `exchange_kernels`): every step variant
-    this strategy builds runs its global exchange over the flat-buffer
-    arena (one cross-replica collective per sync regardless of leaf
-    count), so each compiled macro-cycle contains exactly one fused
-    exchange program per sync step in its shape."""
+    Every step variant this strategy builds closes over one `Exchange`
+    (`self.exchange`), built from the DasoConfig and from what the
+    strategy was told: the membership, the inner-level regrouping and
+    whether the replica axis stays on one device. Where it does (the
+    executor without a placement) the outermost means go leaf by leaf;
+    elsewhere they run over the flat-buffer arena, one cross-replica
+    collective per sync regardless of leaf count."""
 
     def __init__(self, loss_fn, optimizer, cfg, *, membership=None, **kw):
         assert cfg is not None, "daso strategy requires a DasoConfig"
         super().__init__(loss_fn, optimizer, cfg, **kw)
-        self._membership = flatbuf.normalize_membership(
-            membership, cfg.n_replicas)
-        self._group_perm = None
-        self._device_local = False
+        self.exchange = Exchange.of(cfg, membership=membership)
+
+    def _set_exchange(self, **fact) -> bool:
+        """Rebuild the exchange with one fact changed. Each is baked
+        *statically* into every step variant, so a change drops the
+        strategy's step-fn cache; an executor holding compiled cycles over
+        the old variants must be `invalidate()`d by the caller. Returns
+        whether the exchange changed."""
+        ex = self.exchange
+        told = dict(membership=ex.mask, group_perm=ex.perm,
+                    device_local=ex.device_local)
+        new = Exchange.of(self.cfg, **{**told, **fact})
+        if new == ex:
+            return False
+        self.exchange = new
+        self._steps.clear()
+        return True
 
     # -- elastic membership ------------------------------------------------
     @property
     def membership(self):
         """Active-replica mask as a 0/1 tuple, or None when every replica
         is active (the non-elastic fast path)."""
-        return self._membership
+        return self.exchange.mask
 
     def n_active(self) -> int:
-        return (self.cfg.n_replicas if self._membership is None
-                else int(sum(self._membership)))
+        return self.exchange.n_active
 
     def set_membership(self, mask) -> None:
-        """Change the active-replica set. The mask is baked *statically*
-        into every step variant (membership-weighted exchange, frozen ghost
-        rows — core/daso.py), so this drops the strategy's step-fn cache;
-        an executor holding compiled cycles over the old variants must be
-        `invalidate()`d by the caller (resilience/supervisor.py does both).
-        Static baking keeps the steady-state HLO free of membership
-        arithmetic — faults are rare, recompiles at fault boundaries are
-        the right trade."""
-        self._membership = flatbuf.normalize_membership(
-            mask, self.cfg.n_replicas)
-        self._steps.clear()
+        """Change the active-replica set (membership-weighted exchange,
+        frozen ghost rows — core/daso.py); resilience/supervisor.py also
+        invalidates the executor. Static baking keeps the steady-state HLO
+        free of membership arithmetic — faults are rare, recompiles at
+        fault boundaries are the right trade."""
+        self._set_exchange(membership=mask)
 
     # -- straggler-aware reshuffle -----------------------------------------
     @property
     def group_perm(self):
         """Replica regrouping permutation for inner-level syncs (None =
         contiguous identity grouping, the non-reshuffled fast path)."""
-        return self._group_perm
+        return self.exchange.perm
 
     def set_group_permutation(self, perm) -> None:
         """Rotate which replicas share an inner group: slot i of the new
         grouping holds replica `perm[i]` (repro.core.daso.
-        normalize_group_perm). Same contract as `set_membership` — the
-        permutation is baked statically into every step variant, so this
-        drops the step-fn cache and the caller must `invalidate()` any
-        executor holding compiled cycles over the old variants (the
-        resilience supervisor's autotune path does both). Driven by
-        per-replica cycle-time skew: `repro.topo.probe.skew_permutation`
-        packs similar-speed replicas into the same group so a straggler
-        delays only its own group's inner syncs."""
-        self._group_perm = normalize_group_perm(perm, self.cfg.n_replicas)
-        self._steps.clear()
+        normalize_group_perm); the resilience supervisor's autotune path
+        also invalidates the executor. Driven by per-replica cycle-time
+        skew: `repro.topo.probe.skew_permutation` packs similar-speed
+        replicas into the same group so a straggler delays only its own
+        group's inner syncs."""
+        self._set_exchange(group_perm=perm)
 
     # -- where the replica axis lives --------------------------------------
     def set_device_local(self, local: bool) -> bool:
         """Say whether the replica axis lies in one program on one device.
         The executor says it, since it holds the placement: without one
         there is no collective to coalesce, and the replica means are taken
-        leaf by leaf (core/daso.py `replica_mean`); a strategy never told
-        keeps the packed arena, whose one collective per sync a replica
-        axis across devices needs. Same contract as `set_membership`: this
-        drops the step-fn cache. Returns whether the setting changed, so
-        the caller knows to `invalidate()` cycles compiled before."""
-        local = bool(local)
-        if local == self._device_local:
-            return False
-        self._device_local = local
-        self._steps.clear()
-        return True
+        leaf by leaf (`Exchange.leafwise`); a strategy never told keeps the
+        packed arena, whose one collective per sync a replica axis across
+        devices needs. Returns whether the exchange changed, so the caller
+        knows to `invalidate()` cycles compiled before."""
+        return self._set_exchange(device_local=local)
 
     @property
     def exchange_layout(self) -> str:
-        cfg = self.cfg
-        return "leafwise" if leafwise_exchange(
-            cfg.wire_format, impl=cfg.exchange_impl,
-            use_kernels=cfg.exchange_kernels,
-            device_local=self._device_local) else "arena"
+        return "leafwise" if self.exchange.leafwise else "arena"
 
     @property
     def overlap(self) -> bool:
@@ -317,8 +310,8 @@ class DasoStrategy(Strategy):
     def finalize_params(self, carry):
         # under elastic membership row 0 may be a dead replica's frozen
         # ghost — report the first ACTIVE replica's params instead
-        idx = (0 if self._membership is None
-               else self._membership.index(1.0))
+        mask = self.exchange.mask
+        idx = 0 if mask is None else mask.index(1.0)
         return dereplicate_params(carry[0], index=idx)
 
     def _inner_syncs_of(self, inner: Tuple[str, ...]):
@@ -338,11 +331,8 @@ class DasoStrategy(Strategy):
         outer, inner = split_mode(mode)
         return daso_train_step(self.loss_fn, self.optimizer, self.cfg,
                                mode=outer, staleness=staleness,
-                               n_micro=self.n_micro,
-                               membership=self._membership,
-                               inner_syncs=self._inner_syncs_of(inner),
-                               group_perm=self._group_perm,
-                               device_local=self._device_local)
+                               n_micro=self.n_micro, ex=self.exchange,
+                               inner_syncs=self._inner_syncs_of(inner))
 
     def _build_raw_overlap(self, mode, staleness):
         """Overlap counterpart of `_build_raw`: 4-slot carry, OV_* tokens,
@@ -352,11 +342,8 @@ class DasoStrategy(Strategy):
         return daso_overlap_step(self.loss_fn, self.optimizer, self.cfg,
                                  mode=base, staleness=staleness,
                                  extra_staleness=extra,
-                                 n_micro=self.n_micro,
-                                 membership=self._membership,
-                                 inner_syncs=self._inner_syncs_of(inner),
-                                 group_perm=self._group_perm,
-                                 device_local=self._device_local)
+                                 n_micro=self.n_micro, ex=self.exchange,
+                                 inner_syncs=self._inner_syncs_of(inner))
 
     def build_step(self, mode, staleness):
         if mode.startswith(OVERLAP_COMPUTE_PREFIX):
@@ -365,9 +352,8 @@ class DasoStrategy(Strategy):
             _, inner = split_mode(mode[len(OVERLAP_COMPUTE_PREFIX):])
             raw = daso_overlap_compute_step(
                 self.loss_fn, self.optimizer, self.cfg,
-                n_micro=self.n_micro, membership=self._membership,
-                inner_syncs=self._inner_syncs_of(inner),
-                group_perm=self._group_perm)
+                n_micro=self.n_micro, ex=self.exchange,
+                inner_syncs=self._inner_syncs_of(inner))
 
             def cstep(carry, batch, lr):
                 params, opt_state = carry
@@ -426,14 +412,10 @@ class DasoStrategy(Strategy):
         """pending -> inflight: the ONE outer-level collective of an
         overlap cycle, compiled as its own program so the executor can put
         it in flight before the compute program."""
-        cfg, mask, local = self.cfg, self._membership, self._device_local
+        ex = self.exchange
 
         def exchange(pending):
-            return global_send(
-                pending, wire_format=cfg.wire_format_for(blocking=False),
-                impl=cfg.exchange_impl, int8_block=cfg.int8_block,
-                use_kernels=cfg.exchange_kernels, mask=mask,
-                deterministic=cfg.deterministic_reduce, device_local=local)
+            return global_send(pending, ex=ex)
 
         return exchange
 
@@ -444,20 +426,13 @@ class DasoStrategy(Strategy):
         cross-replica loss reduction the compute program deferred (same
         chained order as the per-step path — bit-exact under
         deterministic_reduce)."""
-        cfg, mask = self.cfg, self._membership
-        n_active = self.n_active()
-        p_eff = (cfg.global_world if mask is None
-                 else cfg.global_world * n_active / cfg.n_replicas)
+        ex = self.exchange
 
         def merge(params, inflight, loss_r):
-            params = global_receive(params, inflight, staleness=staleness,
-                                    extra_staleness=extra_staleness,
-                                    global_world=p_eff,
-                                    impl=cfg.exchange_impl,
-                                    use_kernels=cfg.exchange_kernels,
-                                    mask=mask)
-            loss = _cross_replica_loss(cfg, mask, n_active, loss_r, axis=1)
-            return params, loss
+            params = global_receive(params, inflight, ex=ex,
+                                    staleness=staleness,
+                                    extra_staleness=extra_staleness)
+            return params, _cross_replica_loss(ex, loss_r, axis=1)
 
         return merge
 

@@ -95,8 +95,7 @@ def _record(lowered, compiled, t_lower, t_compile, mesh, extra):
 
 def build_train_lowering(cfg, mesh, *, daso: bool, unroll_groups: int = 0,
                          fsdp=None, remat: bool = True, q_chunk: int = 1024,
-                         vocab_chunk: int = 0, n_micro: int = 1,
-                         compress_nonblocking: bool = False):
+                         vocab_chunk: int = 0, n_micro: int = 1):
     """Returns a jax .lower()-ed sync train step (or DASO cycle)."""
     params = params_struct(cfg)
     pb = param_bytes(params)
@@ -129,8 +128,7 @@ def build_train_lowering(cfg, mesh, *, daso: bool, unroll_groups: int = 0,
                 lambda x: jnp.zeros(x.shape, jnp.float32), p)}, params)
         inflight = params
         dcfg = DasoConfig(n_replicas=R,
-                          global_world=R * mesh.shape["data"], b_max=4,
-                          compress_nonblocking=compress_nonblocking)
+                          global_world=R * mesh.shape["data"], b_max=4)
         steps = [daso_train_step(loss_fn, optimizer, dcfg, mode=m,
                                  staleness=1, spmd_axis_name="pod",
                                  n_micro=n_micro)

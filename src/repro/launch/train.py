@@ -64,13 +64,9 @@ def build_parser() -> argparse.ArgumentParser:
     ap.add_argument("--max-cycle-len", type=int, default=32)
     ap.add_argument("--wire-format", default=None,
                     choices=["f32", "bf16", "int8"],
-                    help="wire tier of the global exchange; default derives "
-                         "bf16/f32 from the DASO compress flags, int8 is "
+                    help="wire tier of the global exchange; default is "
+                         "the paper's bf16 blocking / f32 cycling, int8 is "
                          "the beyond-paper block-scaled tier")
-    ap.add_argument("--exchange-impl", default="fused",
-                    choices=["fused", "per_leaf"],
-                    help="fused = one flat-buffer collective per exchange; "
-                         "per_leaf = legacy reference path")
     ap.add_argument("--overlap", default="off",
                     choices=["off", "one_cycle"],
                     help="double-buffered compute/communication overlap: "
@@ -239,8 +235,7 @@ def build_job(args, cfg) -> Job:
         # even if --topology named a file that changes under us
         topology=spec.to_str() if spec is not None else None, lr=args.lr,
         executor=args.executor, max_cycle_len=args.max_cycle_len,
-        wire_format=args.wire_format, exchange_impl=args.exchange_impl,
-        overlap=args.overlap,
+        wire_format=args.wire_format, overlap=args.overlap,
         overlap_serial_exchange=args.overlap_serial_exchange,
         ckpt_every=args.ckpt_every, ckpt_dir=args.ckpt,
         resume_from=args.resume, distributed=args.distributed,
@@ -382,8 +377,8 @@ def main():
             n_replicas=R, local_world=args.local_world,
             b_max=(spec.outer.period if spec is not None
                    and spec.outer.period is not None else args.b_max),
-            wire_format=args.wire_format, exchange_impl=args.exchange_impl,
-            overlap=args.overlap, param_bytes=param_bytes,
+            wire_format=args.wire_format, overlap=args.overlap,
+            param_bytes=param_bytes,
             procs=dist.num_processes if args.distributed else 1,
             seed=args.seed, tiny=bool(args.tiny))
 

@@ -18,8 +18,8 @@ Two honesty checks keep the meters from drifting from reality:
     wire; the HLO is the wire.
   * `outer_sync_split` separates blocking-phase from cycling-phase outer
     syncs, because the two cross at different wire tiers when
-    `DasoConfig.wire_format` is unset (compress_blocking=bf16 default vs
-    f32 non-blocking sends).
+    `DasoConfig.wire_format` is unset (bf16 blocking syncs vs f32
+    non-blocking sends, `Exchange.of`).
 """
 from __future__ import annotations
 
@@ -27,6 +27,7 @@ from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Sequence
 
 from repro.core import compression
+from repro.core.daso import Exchange
 from repro.core.schedule import Mode, split_mode, split_ov
 
 #: outer-mode tokens that cross the wire while training blocks on them
@@ -36,7 +37,7 @@ _BLOCKING_OUTER = (Mode.BLOCKING, Mode.HARD_AVG)
 #: (paper send family, the overlap merge, and the baseline-family
 #: exchanges of core/baselines.py — gossip partner copies, the EASGD
 #: center pull, DOWNPOUR delta pushes — which all price their payload at
-#: `wire_format_for(blocking=False)`)
+#: the cycling tier, `Exchange.send_wire`)
 _ASYNC_OUTER = (Mode.SEND, Mode.SEND_RECEIVE, Mode.OV_SYNC,
                 Mode.GOSSIP, Mode.ELASTIC, Mode.PUSH)
 
@@ -78,8 +79,8 @@ class LevelMeter:
 def outer_sync_split(history: Sequence) -> Dict[str, int]:
     """Split the outer-level syncs of a controller `history` (entries
     ``(step, mode, b, w)``) into blocking vs non-blocking counts — the two
-    families cross at different wire tiers under the default per-phase
-    compression flags."""
+    families cross at different wire tiers when no wire format is
+    forced."""
     out = {"blocking": 0, "nonblocking": 0}
     for (_, mode, _, _) in history:
         base, _ = split_ov(split_mode(mode)[0])
@@ -92,33 +93,30 @@ def outer_sync_split(history: Sequence) -> Dict[str, int]:
 
 def level_bytes_report(params, counts: Dict[str, int], cfg, *,
                        topo=None,
-                       outer_split: Optional[Dict[str, int]] = None,
-                       inner_wire: str = "f32") -> List[LevelMeter]:
+                       outer_split: Optional[Dict[str, int]] = None
+                       ) -> List[LevelMeter]:
     """Per-level meters for a run.
 
     `params` is the UNREPLICATED parameter template (one replica's tree —
     what one exchange actually ships); `counts` is
-    `controller.level_sync_counts()`; `cfg` is the `DasoConfig` (wire
-    tiers + int8 block); `topo` the `TopologySpec` when hierarchical
+    `controller.level_sync_counts()`; `cfg` is the `DasoConfig` (its wire
+    tiers); `topo` the `TopologySpec` when hierarchical
     (group sizes, and levels with zero syncs so the report always covers
     every sync level); `outer_split` from `outer_sync_split(history)`
     splits the outer row by wire tier when the two phases differ.
 
-    Inner levels cross at `inner_wire` — `daso.level_group_mean` supports
-    f32/bf16 and the hierarchy lowers to f32 by default."""
-    int8_block = getattr(cfg, "int8_block", 256)
+    Inner levels cross at f32, as `daso.level_group_mean` runs them."""
 
     def payload(wire: str) -> int:
-        return compression.transfer_bytes(params, wire_format=wire,
-                                          int8_block=int8_block)
+        return compression.transfer_bytes(params, wire_format=wire)
 
     rows: List[LevelMeter] = []
     n_replicas = topo.n_replicas if topo is not None else 2
 
     # outer level: one row per wire tier actually used
     outer_total = counts.get("_outer", 0)
-    wf_block = cfg.wire_format_for(blocking=True)
-    wf_async = cfg.wire_format_for(blocking=False)
+    ex = Exchange.of(cfg)
+    wf_block, wf_async = ex.blocking_wire, ex.send_wire
     if outer_split is not None and wf_block != wf_async:
         n_b = min(outer_split.get("blocking", 0), outer_total)
         n_a = outer_total - n_b
@@ -134,14 +132,13 @@ def level_bytes_report(params, counts: Dict[str, int], cfg, *,
 
     inner_names = tuple(topo.inner_names()) if topo is not None else ()
     for name in inner_names:
-        rows.append(LevelMeter(name, counts.get(name, 0), inner_wire,
-                               topo.group_size(name), payload(inner_wire)))
+        rows.append(LevelMeter(name, counts.get(name, 0), "f32",
+                               topo.group_size(name), payload("f32")))
     # inner levels the history saw but the spec no longer names (regroup
     # shrank the topology mid-run): still account them
     for name, n in counts.items():
         if name != "_outer" and name not in inner_names:
-            rows.append(LevelMeter(name, n, inner_wire, 0,
-                                   payload(inner_wire)))
+            rows.append(LevelMeter(name, n, "f32", 0, payload("f32")))
     return rows
 
 

@@ -148,9 +148,12 @@ def active_probe(spec: TopologySpec, *, n_values: int = 1 << 12,
     import jax
     import jax.numpy as jnp
 
-    from repro.core.daso import level_group_mean
+    from repro.core.daso import DasoConfig, Exchange, level_group_mean
 
     r = spec.n_replicas
+    ex = Exchange.of(DasoConfig(n_replicas=r, global_world=spec.world,
+                                deterministic_reduce=deterministic),
+                     membership=mask)
     arena = (jnp.arange(r * n_values, dtype=jnp.float32)
              .reshape(r, n_values) / float(r * n_values))
     tree = {"probe": arena}
@@ -162,8 +165,7 @@ def active_probe(spec: TopologySpec, *, n_values: int = 1 << 12,
     costs: Dict[str, float] = {}
     checksums: Dict[str, float] = {}
     for name, g in targets:
-        fn = jax.jit(lambda t, g=g: level_group_mean(
-            t, g, mask=mask, deterministic=deterministic))
+        fn = jax.jit(lambda t, g=g: level_group_mean(t, g, ex=ex))
         out = jax.block_until_ready(fn(tree))  # compile outside the timing
         checksums[name] = float(jnp.sum(out["probe"]))
         best = float("inf")

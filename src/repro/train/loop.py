@@ -54,12 +54,10 @@ class TrainLoopConfig:
     log_every: int = 50
     executor: str = "macro"           # macro | per_step
     max_cycle_len: int = 32           # cap on compiled macro-cycle length
-    # fused flat-buffer exchange knobs (core/flatbuf.py): wire_format None
-    # derives bf16/f32 from the DasoConfig compress_* flags; "f32" | "bf16"
-    # | "int8" forces one tier. exchange_impl "per_leaf" selects the legacy
-    # one-collective-per-leaf reference path.
+    # wire tier of the global exchange (DasoConfig.wire_format): None is
+    # the paper's bf16 blocking / f32 cycling; "f32" | "bf16" | "int8"
+    # forces one tier.
     wire_format: Optional[str] = None
-    exchange_impl: str = "fused"
     # double-buffered compute/communication overlap (core/daso.py
     # OVERLAP_MODES): "off" = the blocking schedule, bit-exact with
     # pre-overlap runs; "one_cycle" = each global exchange runs on the
@@ -150,7 +148,6 @@ def build_strategy(loss_fn: Callable, cfg: TrainLoopConfig,
         cooldown_steps=int(cfg.cooldown_frac * cfg.n_steps),
         total_steps=cfg.n_steps,
         wire_format=cfg.wire_format,
-        exchange_impl=cfg.exchange_impl,
         overlap=cfg.overlap,
         # distributed runs pin every cross-replica reduction to the
         # order-fixed chain formulation so the result is independent of
@@ -322,7 +319,7 @@ def run_training(loss_fn: Callable, params0, data_fn: Callable,
         stats = result.executor_stats
         disp = (f" dispatches={stats.dispatches}/{cfg.n_steps}"
                 if stats is not None else "")
-        wire = (f" wire={cfg.wire_format or 'auto'}/{cfg.exchange_impl}"
+        wire = (f" wire={cfg.wire_format or 'auto'}"
                 f" exchange={strategy.exchange_layout}"
                 if cfg.strategy != "sync" else "")
         log(f"[train] strategy={cfg.strategy} steps={cfg.n_steps} "

@@ -52,6 +52,20 @@ def make_mlp_problem(key, R=2, per=16, d=8):
     return params0, loss_fn, daso_data, sync_data
 
 
+def make_exchange(n_replicas, global_world=None, *, membership=None,
+                  group_perm=None, device_local=False, **cfg):
+    """The `Exchange` of a DasoConfig over `n_replicas` replicas and
+    `global_world` devices (default: one per replica) with the other
+    DasoConfig fields in `cfg`, for tests that call the exchange
+    primitives directly."""
+    from repro.core.daso import DasoConfig, Exchange
+
+    dcfg = DasoConfig(n_replicas=n_replicas,
+                      global_world=global_world or n_replicas, **cfg)
+    return Exchange.of(dcfg, membership=membership, group_perm=group_perm,
+                       device_local=device_local)
+
+
 # NOTE: XLA_FLAGS / device-count overrides are intentionally NOT set here —
 # smoke tests must see the real single CPU device. Multi-device distributed
 # tests spawn subprocesses through the helpers below, which build the JAX

@@ -4,6 +4,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from conftest import make_exchange
 from hypothesis import given, settings, strategies as st
 
 from repro.core.daso import (DasoConfig, blocking_sync, daso_train_step,
@@ -38,7 +39,8 @@ def test_eq1_is_convex_combination(S, P):
     """Eq (1) weights: 2S/(2S+P) on local, P/(2S+P) on global — sum to 1."""
     local = {"w": jnp.full((2, 3), 2.0)}
     glob = {"w": jnp.full((2, 3), -1.0)}
-    merged = global_receive(local, glob, staleness=S, global_world=P)
+    merged = global_receive(local, glob, ex=make_exchange(2, P),
+                            staleness=S)
     expect = (2 * S * 2.0 + P * (-1.0)) / (2 * S + P)
     np.testing.assert_allclose(np.asarray(merged["w"]), expect, rtol=1e-6)
     # convexity: merged between min and max
@@ -49,8 +51,8 @@ def test_eq1_staleness_monotonicity():
     """More staleness -> more weight on local params (paper's rationale)."""
     local = {"w": jnp.ones((1,))}
     glob = {"w": jnp.zeros((1,))}
-    vals = [float(global_receive(local, glob, staleness=s,
-                                 global_world=16)["w"][0])
+    vals = [float(global_receive(local, glob, ex=make_exchange(1, 16),
+                                 staleness=s)["w"][0])
             for s in (1, 2, 4, 8)]
     assert all(b > a for a, b in zip(vals, vals[1:]))
 
@@ -58,7 +60,7 @@ def test_eq1_staleness_monotonicity():
 def test_send_is_replica_mean():
     params = replicate_params({"w": jnp.zeros((2,))}, 4)
     params = {"w": params["w"].at[:, 0].set(jnp.arange(4.0))}
-    inflight = global_send(params)
+    inflight = global_send(params, ex=make_exchange(4))
     np.testing.assert_allclose(np.asarray(inflight["w"][:, 0]), 1.5)
     # every replica holds the same buffer
     assert float(jnp.max(jnp.abs(inflight["w"] - inflight["w"][0]))) == 0.0
@@ -66,7 +68,7 @@ def test_send_is_replica_mean():
 
 def test_blocking_sync_bf16_compression_roundtrip():
     params = replicate_params({"w": jnp.array([1.0 + 1e-5, 2.0])}, 2)
-    out = blocking_sync(params, compress=True)
+    out = blocking_sync(params, ex=make_exchange(2, wire_format="bf16"))
     # values pass through bf16: small perturbations are quantized away
     assert out["w"].dtype == params["w"].dtype
     assert abs(float(out["w"][0, 0]) - 1.0) < 1e-2
@@ -81,7 +83,7 @@ def test_blocking_daso_equals_sync():
     _, data_fn = _make_problem(key)
     params0 = {"w": jnp.zeros((16, 1))}
     opt = sgd(momentum=0.9, weight_decay=0.0)
-    cfg = DasoConfig(n_replicas=4, global_world=16, compress_blocking=False)
+    cfg = DasoConfig(n_replicas=4, global_world=16, wire_format="f32")
     step = jax.jit(daso_train_step(_quadratic_loss, opt, cfg,
                                    mode="blocking"))
     sstep = jax.jit(sync_train_step(_quadratic_loss, opt))

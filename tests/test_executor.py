@@ -7,7 +7,7 @@ import numpy as np
 import pytest
 from conftest import make_mlp_problem as _mlp_problem
 
-from repro.core.daso import DasoConfig
+from repro.core.daso import DasoConfig, Exchange
 from repro.core.executor import (CyclePlan, MacroCycleExecutor, _group_runs,
                                  get_strategy, list_strategies, make_strategy,
                                  run_compiled_training)
@@ -88,47 +88,12 @@ def _multi_leaf_problem(key, R=2, per=8, d=6):
 
 
 @pytest.mark.parametrize("wire_format", [None, "f32", "bf16"])
-def test_fused_arena_training_matches_per_leaf(wire_format):
-    """Acceptance: fused flat-buffer DASO training == the legacy per-leaf
-    exchange path, allclose at f32, on a multi-leaf model (the arena
-    coalesces 5 leaves into one buffer; numerics must not move)."""
-    key = jax.random.PRNGKey(7)
-    params0, loss_fn, daso_data = _multi_leaf_problem(key)
-    opt = sgd(momentum=0.9, weight_decay=1e-4)
-    n_steps = 40
-
-    def run(exchange_impl):
-        dcfg = DasoConfig(n_replicas=2, global_world=8, b_max=4,
-                          warmup_steps=4, cooldown_steps=4,
-                          total_steps=n_steps, wire_format=wire_format,
-                          exchange_impl=exchange_impl)
-        strat = make_strategy("daso", loss_fn, opt, dcfg,
-                              controller=DasoController(dcfg,
-                                                        loss_window=10))
-        ex = MacroCycleExecutor(strat)
-        # without a placement the executor makes the exchange leaf-wise;
-        # the fused side is the packed arena, as a mesh placement has it
-        strat.set_device_local(False)
-        assert strat.exchange_layout == ("arena" if exchange_impl == "fused"
-                                         else "leafwise")
-        return run_compiled_training(strat, params0, daso_data,
-                                     constant_lr(0.1), n_steps, executor=ex)
-
-    fused, per_leaf = run("fused"), run("per_leaf")
-    np.testing.assert_allclose(np.asarray(fused.losses, np.float32),
-                               np.asarray(per_leaf.losses, np.float32),
-                               rtol=1e-5, atol=1e-6)
-    for a, b in zip(jax.tree.leaves(fused.params),
-                    jax.tree.leaves(per_leaf.params)):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                   rtol=2e-5, atol=1e-6)
-
-
-@pytest.mark.parametrize("wire_format", ["f32", "bf16"])
 def test_leafwise_training_is_bit_identical_to_arena(wire_format):
     """An executor without a placement (the replica axis on one device)
     trains with the leaf-wise replica mean, and every loss and parameter
-    is the same bits as with the packed arena."""
+    is the same bits as with the packed arena, which coalesces the 5
+    leaves of a multi-leaf model into one buffer. With no wire format the
+    blocking phases cross at bf16 and the cycling sends at f32."""
     key = jax.random.PRNGKey(7)
     params0, loss_fn, daso_data = _multi_leaf_problem(key)
     opt = sgd(momentum=0.9, weight_decay=1e-4)
@@ -153,6 +118,67 @@ def test_leafwise_training_is_bit_identical_to_arena(wire_format):
     for a, b in zip(jax.tree.leaves(arena.params),
                     jax.tree.leaves(leafwise.params)):
         assert np.asarray(a).tobytes() == np.asarray(b).tobytes()
+
+
+@pytest.mark.parametrize("wire_format", [None, "f32", "bf16", "int8"])
+def test_exchange_of_resolves_the_config(wire_format):
+    """`Exchange.of` reads the config once: the cycling and blocking wire
+    tiers (None is the paper's f32 cycling / bf16 blocking), and the
+    layout, leaf-wise only where the replica axis stays on one device
+    and neither the int8 wire nor the kernels run over the arena."""
+    for kernels, local in [(False, False), (False, True), (True, False),
+                           (True, True)]:
+        dcfg = DasoConfig(n_replicas=4, global_world=8,
+                          wire_format=wire_format, exchange_kernels=kernels)
+        ex = Exchange.of(dcfg, membership=[1, 0, 1, 1],
+                         group_perm=[1, 0, 2, 3], device_local=local)
+        assert ex.send_wire == (wire_format or "f32")
+        assert ex.blocking_wire == (wire_format or "bf16")
+        assert ex.leafwise == (local and not kernels
+                               and wire_format != "int8")
+        assert (ex.kernels, ex.deterministic) == (kernels, False)
+        assert ex.mask == (1.0, 0.0, 1.0, 1.0) and ex.perm == (1, 0, 2, 3)
+        assert (ex.n_active, ex.p_eff) == (3, 6.0)
+        # hashable: step variants close over it
+        assert {ex: 1}[Exchange.of(dcfg, membership=ex.mask,
+                                   group_perm=ex.perm,
+                                   device_local=local)] == 1
+        # the naive average is f32 without the kernels, laid out by the
+        # same rule
+        assert ex.plain().leafwise == local
+    full = Exchange.of(DasoConfig(n_replicas=4, global_world=8),
+                       membership=[1] * 4, group_perm=range(4))
+    assert (full.mask, full.perm, full.n_active, full.p_eff) == (None, None,
+                                                                 4, 8)
+
+
+def test_setters_rebuild_the_exchange_only_on_a_change():
+    """Each setter rebuilds the strategy's one `Exchange`, and drops the
+    step-fn cache only when the value changed."""
+    _, loss_fn, _ = _multi_leaf_problem(jax.random.PRNGKey(0))
+    dcfg = DasoConfig(n_replicas=4, global_world=8, b_max=4)
+    strat = make_strategy("daso", loss_fn, sgd(momentum=0.9), dcfg)
+    changes = [
+        (lambda: strat.set_membership([1, 1, 0, 1]), "mask",
+         (1.0, 1.0, 0.0, 1.0)),
+        (lambda: strat.set_group_permutation([0, 2, 1, 3]), "perm",
+         (0, 2, 1, 3)),
+        (lambda: strat.set_device_local(True), "device_local", True),
+    ]
+    for tell, field, value in changes:
+        strat.step_fn(Mode.SEND, 1)
+        before = strat.exchange
+        tell()
+        assert getattr(strat.exchange, field) == value
+        assert strat.exchange != before and not strat._steps
+        strat.step_fn(Mode.SEND, 1)
+        kept = strat.exchange
+        tell()
+        assert strat.exchange is kept and len(strat._steps) == 1
+    assert strat.set_device_local(True) is False
+    assert strat.set_device_local(False) is True and not strat._steps
+    assert (strat.membership, strat.group_perm, strat.n_active()) == (
+        (1.0, 1.0, 0.0, 1.0), (0, 2, 1, 3), 3)
 
 
 def _packs_an_arena(strat) -> bool:

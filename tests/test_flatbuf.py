@@ -12,6 +12,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from conftest import make_exchange
 from hypothesis import given, settings, strategies as st
 
 from repro.core import flatbuf
@@ -74,19 +75,21 @@ _MEAN_LEAF_SPECS = [("float32", (3, 4)), ("float32", (7,)),
 @settings(max_examples=20, deadline=None)
 def test_leafwise_mean_is_bit_identical_to_arena_property(
         specs, r, wire_format, mask_bits, deterministic):
-    """The replica mean taken leaf by leaf (`device_local`, the one-device
-    program) gives the same bits as the packed-arena mean: f32 and bf16
-    leaves plus an int32 leaf, f32 and bf16 wire, with and without a
-    membership mask, both reduction tiers."""
+    """The replica mean taken leaf by leaf (`Exchange.leafwise`, the
+    one-device program) gives the same bits as the packed-arena mean: f32
+    and bf16 leaves plus an int32 leaf, f32 and bf16 wire, with and
+    without a membership mask, both reduction tiers."""
     from repro.core.daso import replica_mean
     tree = _make_tree(list(specs) + [("int32", (6,))], batch_shape=(r,))
     mask = tuple(float(mask_bits >> i & 1) for i in range(r))
     mask = mask if 0 < sum(mask) < r else None
 
     def mean(device_local):
+        ex = make_exchange(r, membership=mask, device_local=device_local,
+                           deterministic_reduce=deterministic)
+        assert ex.leafwise == device_local
         return jax.jit(lambda t: replica_mean(
-            t, wire_format=wire_format, mask=mask,
-            deterministic=deterministic, device_local=device_local))(tree)
+            t, ex=ex, wire=wire_format))(tree)
 
     arena, leafwise = mean(False), mean(True)
     for k, x in tree.items():
@@ -283,7 +286,8 @@ def test_one_exchange_is_one_all_reduce_any_leaf_count():
     script = """
         import jax, jax.numpy as jnp
         from jax.sharding import NamedSharding, PartitionSpec as P
-        from repro.core.daso import blocking_sync, replica_mean_per_leaf
+        from repro.core.daso import (DasoConfig, Exchange, blocking_sync,
+                                     replica_mean_per_leaf)
         from repro.launch.hlo_stats import collective_stats
 
         from repro.launch.mesh import make_mesh
@@ -303,11 +307,13 @@ def test_one_exchange_is_one_all_reduce_any_leaf_count():
                                                   jnp.float32)
                     for i in range(n_leaves)}
             for wf in ("f32", "bf16", "int8"):
+                ex = Exchange.of(DasoConfig(n_replicas=2, global_world=2,
+                                            wire_format=wf))
                 n = n_all_reduce(
-                    lambda t, wf=wf: blocking_sync(t, wire_format=wf), tree)
+                    lambda t, ex=ex: blocking_sync(t, ex=ex), tree)
                 assert n == 1, (wf, n_leaves, n)
             n = n_all_reduce(
-                lambda t: replica_mean_per_leaf(t, jnp.bfloat16), tree)
+                lambda t: replica_mean_per_leaf(t, "bf16"), tree)
             assert 1 <= n <= n_leaves, (n_leaves, n)
         print("ONE COLLECTIVE OK")
     """

@@ -26,7 +26,7 @@ import pytest
 from hypothesis import given, settings
 from hypothesis import strategies as st
 
-from conftest import make_mlp_problem
+from conftest import make_exchange, make_mlp_problem
 
 from repro.core.daso import (DasoConfig, daso_train_step,
                              global_receive, global_receive_per_leaf)
@@ -89,17 +89,19 @@ def test_extra_staleness_equals_shifted_staleness(staleness, extra, world):
        st.sampled_from(["float32", "bfloat16"]))
 def test_global_receive_impls_agree_with_extra(staleness, extra, dtype):
     """per_leaf / fused-ref / Pallas-kernel merges agree for every extra
-    age and dtype (the kernel runs interpret=True on CPU)."""
+    age and dtype (the kernel runs interpret=True on CPU): the oracle
+    `global_receive_per_leaf`, then the exchange without and with the
+    kernels."""
     k = jax.random.PRNGKey(staleness + 10 * extra)
     tree = {"a": jax.random.normal(k, (2, 5, 3)).astype(dtype),
             "b": jax.random.normal(jax.random.fold_in(k, 1), (2, 7))}
     stale = jax.tree.map(lambda x: x + 0.25, tree)
-    kw = dict(staleness=staleness, global_world=8, extra_staleness=extra)
-    out = {name: global_receive(tree, stale, impl=impl,
-                                use_kernels=kern, **kw)
-           for name, impl, kern in [("per_leaf", "per_leaf", False),
-                                    ("ref", "fused", False),
-                                    ("kernel", "fused", True)]}
+    kw = dict(staleness=staleness, extra_staleness=extra)
+    out = {"per_leaf": global_receive_per_leaf(tree, stale, global_world=8,
+                                               **kw)}
+    for name, kern in [("ref", False), ("kernel", True)]:
+        out[name] = global_receive(
+            tree, stale, ex=make_exchange(2, 8, exchange_kernels=kern), **kw)
     for name in ("ref", "kernel"):
         for la, lb in zip(jax.tree.leaves(out["per_leaf"]),
                           jax.tree.leaves(out[name])):

@@ -12,11 +12,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from conftest import make_exchange
 from conftest import make_mlp_problem as _mlp_problem
 
 from repro.core import flatbuf
-from repro.core.daso import (DasoConfig, daso_train_step, freeze_inactive,
-                             global_receive, replica_mean,
+from repro.core.daso import (DasoConfig, Exchange, daso_train_step,
+                             freeze_inactive, global_receive, replica_mean,
                              replica_mean_per_leaf)
 from repro.core.executor import MacroCycleExecutor, make_strategy
 from repro.core.schedule import DasoController
@@ -47,7 +48,8 @@ def test_masked_fused_mean_matches_survivor_oracle(wire_format, mask):
     """Acceptance: the membership-weighted fused exchange equals a pure-jnp
     mean computed over the surviving replicas only, broadcast to every row."""
     tree = _tree(jax.random.PRNGKey(0))
-    got = replica_mean(tree, wire_format=wire_format, mask=mask)
+    got = replica_mean(tree, ex=make_exchange(4, membership=mask),
+                       wire=wire_format)
     alive = [i for i, m in enumerate(mask) if m]
 
     def oracle(x):
@@ -71,8 +73,9 @@ def test_masked_per_leaf_matches_fused():
     """The legacy per-leaf path applies the identical membership weighting."""
     tree = _tree(jax.random.PRNGKey(1))
     mask = (1.0, 0.0, 1.0, 1.0)
-    fused = replica_mean(tree, wire_format="f32", mask=mask)
-    per_leaf = replica_mean_per_leaf(tree, None, mask=mask)
+    fused = replica_mean(tree, ex=make_exchange(4, membership=mask),
+                         wire="f32")
+    per_leaf = replica_mean_per_leaf(tree, "f32", mask=mask)
     for a, b in zip(jax.tree.leaves(fused), jax.tree.leaves(per_leaf)):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    rtol=1e-6, atol=1e-7)
@@ -83,8 +86,9 @@ def test_masked_int8_mean_close_to_survivor_oracle():
     oracle under a mask."""
     tree = _tree(jax.random.PRNGKey(2))
     mask = (1.0, 1.0, 0.0, 1.0)
-    got = replica_mean(tree, wire_format="int8", mask=mask)
-    want = replica_mean(tree, wire_format="f32", mask=mask)
+    ex = make_exchange(4, membership=mask)
+    got = replica_mean(tree, ex=ex, wire="int8")
+    want = replica_mean(tree, ex=ex, wire="f32")
     for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    rtol=0, atol=0.05)
@@ -98,8 +102,9 @@ def test_dynamic_p_receive_matches_survivor_oracle():
     inflight = jax.tree.map(lambda x: x * 0.5, params)
     mask, R, P = (1.0, 0.0, 1.0, 1.0), 4, 16
     p_eff = P * 3 / R
-    got = global_receive(params, inflight, staleness=2, global_world=p_eff,
-                         mask=mask)
+    ex = make_exchange(R, P, membership=mask)
+    assert ex.p_eff == p_eff
+    got = global_receive(params, inflight, ex=ex, staleness=2)
 
     def oracle(x, s):
         merged = (4.0 * x + p_eff * s) / (4.0 + p_eff)
@@ -122,7 +127,7 @@ def test_one_collective_holds_under_membership_mask():
     script = """
         import jax, jax.numpy as jnp
         from jax.sharding import NamedSharding, PartitionSpec as P
-        from repro.core.daso import blocking_sync
+        from repro.core.daso import DasoConfig, Exchange, blocking_sync
         from repro.launch.hlo_stats import collective_stats
 
         from repro.launch.mesh import make_mesh
@@ -132,8 +137,9 @@ def test_one_collective_holds_under_membership_mask():
                 for i in range(6)}
         mask = (1.0, 0.0)
         for wf in ("f32", "bf16", "int8"):
-            fn = lambda t, wf=wf: blocking_sync(t, wire_format=wf,
-                                                mask=mask)
+            ex = Exchange.of(DasoConfig(n_replicas=2, global_world=2,
+                                        wire_format=wf), membership=mask)
+            fn = lambda t, ex=ex: blocking_sync(t, ex=ex)
             hlo = jax.jit(fn, in_shardings=({k: sh for k in tree},)).lower(
                 tree).compile().as_text()
             stats = collective_stats(hlo, {"pod": 2})
@@ -164,8 +170,9 @@ def test_elastic_step_freezes_dead_rows():
     inflight = jax.tree.map(jnp.array, params)
     batch = daso_data(0)
     for mode in ("local", "send", "receive", "blocking", "hard_avg"):
-        step = jax.jit(daso_train_step(loss_fn, opt, cfg, mode=mode,
-                                       staleness=1, membership=mask))
+        step = jax.jit(daso_train_step(
+            loss_fn, opt, cfg, mode=mode, staleness=1,
+            ex=Exchange.of(cfg, membership=mask)))
         p2, o2, _, m = step(params, opt_state, inflight, batch, 0.1)
         for a, b in zip(jax.tree.leaves(p2), jax.tree.leaves(params)):
             np.testing.assert_array_equal(np.asarray(a[2]), np.asarray(b[2]))

@@ -34,7 +34,7 @@ import pytest
 from hypothesis import given, settings
 from hypothesis import strategies as st
 
-from conftest import make_mlp_problem, subprocess_env
+from conftest import make_exchange, make_mlp_problem, subprocess_env
 from repro.core.baselines import gossip_mix
 from repro.core.daso import DasoConfig
 from repro.core.executor import (get_strategy, list_strategies,
@@ -371,7 +371,8 @@ def test_gossip_preserves_global_mean(r, n_rounds, seed):
     want = {k: np.mean(np.asarray(v, np.float64), axis=0)
             for k, v in tree.items()}
     for s in shifts:
-        tree = gossip_mix(tree, shift=int(s), wire_format="f32")
+        tree = gossip_mix(tree, ex=make_exchange(r, wire_format="f32"),
+                          shift=int(s))
     got = {k: np.mean(np.asarray(v, np.float64), axis=0)
            for k, v in tree.items()}
     for k in want:

@@ -13,7 +13,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from conftest import make_mlp_problem
+from conftest import make_exchange, make_mlp_problem
 from hypothesis import given, settings
 from hypothesis import strategies as st
 
@@ -201,7 +201,7 @@ def test_level_group_mean_matches_per_group_oracle(groups, per, seed):
     jnp mean for every leaf, any group structure."""
     R = groups * per
     tree = _tree(jax.random.PRNGKey(seed), R)
-    got = level_group_mean(tree, per)
+    got = level_group_mean(tree, per, ex=make_exchange(R))
 
     def oracle(x):
         xr = x.reshape((groups, per) + x.shape[1:])
@@ -227,7 +227,7 @@ def test_level_group_mean_membership_mask():
     R, g = 4, 2
     x = {"w": jnp.arange(R * 2, dtype=jnp.float32).reshape(R, 2)}
     mask = flatbuf.normalize_membership((1.0, 0.0, 1.0, 1.0), R)
-    got = level_group_mean(x, g, mask=mask)["w"]
+    got = level_group_mean(x, g, ex=make_exchange(R, membership=mask))["w"]
     # group 0 = rows {0,1}, only row 0 active -> mean = row0
     np.testing.assert_allclose(np.asarray(got[0]), np.asarray(x["w"][0]))
     np.testing.assert_allclose(np.asarray(got[1]), np.asarray(x["w"][0]))
@@ -236,13 +236,15 @@ def test_level_group_mean_membership_mask():
     np.testing.assert_allclose(np.asarray(got[2]), want)
     np.testing.assert_allclose(np.asarray(got[3]), want)
     # group size == R degenerates to the full replica mean
-    full = level_group_mean(x, R)["w"]
+    full = level_group_mean(x, R, ex=make_exchange(R))["w"]
     np.testing.assert_allclose(np.asarray(full[0]),
                                np.asarray(x["w"].mean(0)))
     with pytest.raises(ValueError):
-        level_group_mean(x, 3)  # R=4 not divisible
-    with pytest.raises(ValueError):
-        level_group_mean(x, 2, wire_format="int8")
+        level_group_mean(x, 3, ex=make_exchange(R))  # R=4 not divisible
+    # inner levels cross at f32 whatever the outermost wire tier
+    i8 = make_exchange(R, membership=mask, wire_format="int8")
+    np.testing.assert_array_equal(
+        np.asarray(level_group_mean(x, g, ex=i8)["w"]), np.asarray(got))
 
 
 # ----------------------------------------------- 2-level bit-exactness ------

@@ -18,6 +18,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from conftest import make_exchange
 from jax.sharding import SingleDeviceSharding
 
 from repro.configs import get_config
@@ -307,8 +308,9 @@ def test_leafwise_send_reads_half_the_arena_bytes(one_chip, model):
                       for x in jax.tree.leaves(tree))
 
     def compiled(device_local):
-        return jax.jit(functools.partial(
-            global_send, device_local=device_local)).lower(tree).compile()
+        ex = make_exchange(N_REPLICAS, device_local=device_local)
+        return jax.jit(functools.partial(global_send, ex=ex)).lower(
+            tree).compile()
 
     def bytes_accessed(c):
         cost = c.cost_analysis()
@@ -335,7 +337,9 @@ def test_leafwise_send_sums_the_stored_bf16_values(one_chip):
 
     def send(p, g):
         new = (p.astype(jnp.float32) - 0.003 * g).astype(jnp.bfloat16)
-        return new, replica_mean({"w": new}, jnp.float32, device_local=True)
+        return new, replica_mean(
+            {"w": new}, ex=make_exchange(N_REPLICAS, device_local=True),
+            wire="f32")
 
     assert "bitcast-convert(" in _compile_text(send, p, g)
 

@@ -10,7 +10,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from conftest import make_mlp_problem
+from conftest import make_exchange, make_mlp_problem
 from hypothesis import given, settings
 from hypothesis import strategies as st
 
@@ -142,9 +142,10 @@ def test_permuted_group_mean_preserves_global_mean(seed, group_size, masked):
     perm = tuple(int(i) for i in rng.permutation(R))
     tree = {"w": jnp.asarray(rng.normal(size=(R, 5)), jnp.float32)}
     mask = tuple(1.0 if (not masked or i != 3) else 0.0 for i in range(R))
-    out = level_group_mean(tree, group_size, mask=mask, deterministic=True,
-                           perm=perm)
-    ref = level_group_mean(tree, group_size, mask=mask, deterministic=True)
+    out = level_group_mean(tree, group_size, ex=make_exchange(
+        R, membership=mask, group_perm=perm, deterministic_reduce=True))
+    ref = level_group_mean(tree, group_size, ex=make_exchange(
+        R, membership=mask, deterministic_reduce=True))
     w_in = np.asarray(tree["w"], np.float64)
     m = np.asarray(mask, np.float64)[:, None]
     want = (w_in * m).sum(0) / m.sum()
@@ -161,7 +162,8 @@ def test_permuted_group_mean_matches_permute_then_mean_oracle():
     rng = np.random.default_rng(0)
     perm = (3, 0, 6, 1, 7, 2, 5, 4)
     x = jnp.asarray(rng.normal(size=(R, 4, 3)), jnp.float32)
-    out = level_group_mean({"w": x}, g, deterministic=True, perm=perm)["w"]
+    ex = make_exchange(R, group_perm=perm, deterministic_reduce=True)
+    out = level_group_mean({"w": x}, g, ex=ex)["w"]
     xp = np.asarray(x)[list(perm)]
     mp = xp.reshape(R // g, g, 4, 3).mean(1, keepdims=True)
     mp = np.broadcast_to(mp, (R // g, g, 4, 3)).reshape(R, 4, 3)
